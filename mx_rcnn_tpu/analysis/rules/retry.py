@@ -61,7 +61,7 @@ def check(ctx: FileContext) -> Iterator[Finding]:
             continue  # backoff evidence
         yield ctx.finding(
             NAME, node,
-            "retry loop sleeps with no deadline and no backoff — a relay "
+            "retry loop sleeps with no deadline and no backoff — an "
             "outage spins here forever at a fixed cadence; bound it with "
             "a clock check (or use resilience.backend.acquire_backend)")
 

@@ -23,7 +23,7 @@ graftprof (this layer's profiling/cost pass) adds:
                      (``obs.trace_at_step``; stall-armed) + a coarse
                      trace summarizer → ``trace`` events
 - ``ledger``:        ``python -m mx_rcnn_tpu.obs.ledger`` — append-only
-                     cross-run perf history (PERF_LEDGER.jsonl) with a
+                     cross-run perf history (bench_obs/history.jsonl) with a
                      regression-gating ``check`` subcommand
 
 Enable on any training entry point with config overrides::
@@ -79,15 +79,17 @@ def obs_from_config(cfg, default_dir: str = ""):
         raise ValueError(
             "obs.enabled=true needs obs.dir (or a caller-provided run "
             "directory) to place this process's events_p<k>.jsonl")
-    try:
-        # Coordination identity, not raw jax: under the graftquorum
-        # simulated-host tests each CPU process stamps (and names its
-        # JSONL after) the host index it is standing in for, so the
-        # report's per-host fold sees the fleet it would see on a pod.
-        from mx_rcnn_tpu.parallel.distributed import process_index as _pi
+    # Coordination identity, not raw jax: under the graftquorum
+    # simulated-host tests each CPU process stamps (and names its
+    # JSONL after) the host index it is standing in for, so the
+    # report's per-host fold sees the fleet it would see on a pod.
+    from mx_rcnn_tpu.parallel.distributed import process_index
 
-        process_index = _pi()
-    except (ImportError, RuntimeError):
-        process_index = 0
-    return open_event_log(directory, process_index=process_index,
+    try:
+        index = process_index()
+    except RuntimeError:
+        # The sink opens BEFORE backend acquisition (whose retries it
+        # records): a backend that is not up yet cannot say who we are.
+        index = 0
+    return open_event_log(directory, process_index=index,
                           flush_every=cfg.obs.flush_every)

@@ -19,7 +19,7 @@ listener is a two-comparison no-op.
 from __future__ import annotations
 
 import threading
-from typing import Any, Dict, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 from mx_rcnn_tpu.obs.events import EventLog
 
@@ -55,14 +55,28 @@ def shape_signature() -> Optional[Dict[str, Any]]:
 
 
 class CompileCounter:
-    """Tally of real XLA compiles observed while registered — graftprof's
-    per-bench-row compile accounting (``compile_s`` / ``n_executables``).
-    A persistent-cache hit fires no backend_compile event, so a warm row
-    honestly reports 0 executables built."""
+    """Tally of the executables built or fetched while registered —
+    graftprof's per-bench-row compile accounting (``compile_s`` /
+    ``n_executables``). jax 0.9 times ``compile_or_get_cached`` as a
+    whole, so a persistent-cache hit fires a backend_compile event too
+    (its duration is the retrieval). ``programs`` keeps each one's
+    jitted-function name and seconds, so a caller can count the program
+    it means (the train step is ``jit(step)``) rather than every
+    helper."""
 
     def __init__(self):
-        self.n = 0
-        self.seconds = 0.0
+        self.programs: List[Tuple[Optional[str], float]] = []
+
+    @property
+    def n(self) -> int:
+        return len(self.programs)
+
+    @property
+    def seconds(self) -> float:
+        return sum(s for _, s in self.programs)
+
+    def count_of(self, fun: str) -> int:
+        return sum(1 for f, _ in self.programs if f == fun)
 
 
 _counters: list = []
@@ -74,33 +88,29 @@ def _on_event_duration(event: str, duration_secs: float, **kwargs) -> None:
     phase = event.rsplit("/", 1)[-1]
     if phase.endswith(_COMPILE_SUFFIX):
         phase = phase[: -len(_COMPILE_SUFFIX)]
+    fun = kwargs.get("fun_name")
     if phase == "backend_compile" and _counters:
         with _lock:
             for c in _counters:
-                c.n += 1
-                c.seconds += duration_secs
+                c.programs.append((fun, duration_secs))
     log = _active
     if log is None:
         return
-    log.emit("compile", phase=phase, event=event,
+    log.emit("compile", phase=phase, event=event, fun=fun,
              duration_ms=round(duration_secs * 1e3, 3),
              shapes=shape_signature())
 
 
-def _ensure_installed() -> bool:
+def _ensure_installed() -> None:
     """Register the jax.monitoring listener once per process."""
     global _installed
     with _lock:
         if not _installed:
-            try:
-                import jax.monitoring
+            import jax.monitoring
 
-                jax.monitoring.register_event_duration_secs_listener(
-                    _on_event_duration)
-            except (ImportError, AttributeError):
-                return False
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_event_duration)
             _installed = True
-    return True
 
 
 def count() -> "_CountContext":
@@ -119,9 +129,9 @@ def count() -> "_CountContext":
 class _CountContext:
     def __enter__(self) -> CompileCounter:
         self.counter = CompileCounter()
-        if _ensure_installed():
-            with _lock:
-                _counters.append(self.counter)
+        _ensure_installed()
+        with _lock:
+            _counters.append(self.counter)
         return self.counter
 
     def __exit__(self, *exc):
@@ -131,15 +141,12 @@ class _CountContext:
         return False
 
 
-def activate(log: EventLog) -> bool:
-    """Route compile events to ``log``. Returns False when jax (or its
-    monitoring bus) is unavailable — telemetry degrades, never blocks."""
+def activate(log: EventLog) -> None:
+    """Route compile events to ``log``."""
     global _active
-    if not _ensure_installed():
-        return False
+    _ensure_installed()
     with _lock:
         _active = log
-    return True
 
 
 def deactivate() -> None:

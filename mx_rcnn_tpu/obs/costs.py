@@ -32,32 +32,37 @@ from typing import Any, Dict, Optional
 
 import numpy as np
 
-#: v5e per-chip peak FLOP/s by compute dtype (graftcast,
-#: train.compute_dtype): the MXU's bf16 peak is ~2x its f32 peak, so an
-#: MFU must divide by the peak of the dtype the step actually ran —
-#: grading a bf16 step against the f32 peak would read ~2x inflated,
-#: and an f32 step against the bf16 peak ~2x deflated. Keeping the
-#: table here keeps report folding jax-free: cost events carry the peak
-#: they were computed against.
-PEAK_FLOPS = {
-    "bfloat16": 197e12,
-    "float32": 98.5e12,
+#: Published per-chip peaks, ONE table keyed by jax's ``device_kind``.
+#: Source: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s in bf16,
+#: 16 GB of HBM at 819 GB/s. No f32 matmul peak is published for the
+#: chip, so an f32 step has no MFU here rather than a guessed one; a
+#: device that is not in the table is an error, never a default. Keeping
+#: the table here keeps report folding jax-free: cost events carry the
+#: peak they were computed against.
+PEAKS = {
+    "TPU v5 lite": {"flops": {"bfloat16": 197e12},
+                    "hbm_bytes_per_s": 819e9},
 }
-
-#: legacy alias — the bf16 peak, the only dtype the repo ran before
-#: graftcast (every pre-round-8 ledger/bench row is a bf16 row).
-V5E_PEAK_FLOPS = PEAK_FLOPS["bfloat16"]
+PEAKS["TPU v5e"] = PEAKS["TPU v5 lite"]  # the same chip's other spelling
 
 
-def peak_flops_for(compute_dtype: Optional[str]) -> float:
-    """Per-chip peak for a compute dtype name (canonical or the "f32"/
-    "bf16" short spellings); None/unknown falls back to the bf16 peak —
-    the pre-graftcast convention every historical row used."""
-    if not compute_dtype:
-        return V5E_PEAK_FLOPS
+class UnknownPeakError(LookupError):
+    """No published peak for this (device_kind, dtype)."""
+
+
+def peak_flops_for(device_kind: str, compute_dtype: str) -> float:
+    """Published per-chip peak FLOP/s of ``device_kind`` in
+    ``compute_dtype`` (canonical or the "f32"/"bf16" short spellings).
+    An MFU must divide by the peak of the dtype the step actually ran
+    on the chip it actually ran on, so anything not in PEAKS raises."""
     name = {"f32": "float32", "bf16": "bfloat16"}.get(
         str(compute_dtype), str(compute_dtype))
-    return PEAK_FLOPS.get(name, V5E_PEAK_FLOPS)
+    try:
+        return PEAKS[device_kind]["flops"][name]
+    except KeyError:
+        raise UnknownPeakError(
+            f"no published peak FLOP/s for device_kind={device_kind!r} "
+            f"dtype={name!r} (obs/costs.py::PEAKS)") from None
 
 
 def executable_costs(compiled) -> Dict[str, Any]:
@@ -74,8 +79,6 @@ def executable_costs(compiled) -> Dict[str, Any]:
     out: Dict[str, Any] = {}
     try:
         analysis = compiled.cost_analysis()
-        if isinstance(analysis, list):  # older jax: one dict per device
-            analysis = analysis[0] if analysis else {}
         if analysis:
             out["flops"] = float(analysis.get("flops", 0.0))
             out["bytes_accessed"] = float(
@@ -98,13 +101,13 @@ def executable_costs(compiled) -> Dict[str, Any]:
 
 
 def mfu_from(flops: Optional[float], steps_per_sec: float,
-             peak_flops: float = V5E_PEAK_FLOPS) -> Optional[float]:
+             peak_flops: Optional[float]) -> Optional[float]:
     """Computed MFU: analytic per-step FLOPs × measured step rate ÷ peak.
 
     ``cost_analysis()`` counts the per-device (SPMD-partitioned) program,
     so per-device flops × steps/sec ÷ per-chip peak IS the per-chip MFU
     — no extra device_count factor (the bench.py convention)."""
-    if not flops or steps_per_sec <= 0 or peak_flops <= 0:
+    if not flops or steps_per_sec <= 0 or not peak_flops:
         return None
     return (flops * steps_per_sec) / peak_flops
 
@@ -171,15 +174,17 @@ class CostTracker:
     def __init__(self, elog, label: str = "train_step",
                  peak_flops: Optional[float] = None,
                  compute_dtype: Optional[str] = None):
-        """``compute_dtype`` (graftcast policy, canonical name) selects
-        the dtype-correct peak when ``peak_flops`` is not given and is
-        stamped on every ``cost`` event so report/ledger folding can
-        split rows by dtype."""
+        """``peak_flops``: the published peak of the device and dtype the
+        step runs on (``peak_flops_for``), or None where there is none —
+        a CPU run, an f32 step — and the ``cost`` events then carry
+        FLOPs and HBM but nothing to compute an MFU from.
+        ``compute_dtype`` (graftcast policy, canonical name) is stamped
+        on every event so report/ledger folding can split rows by
+        dtype."""
         self.elog = elog
         self.label = label
         self.compute_dtype = compute_dtype
-        self.peak_flops = float(peak_flops if peak_flops is not None
-                                else peak_flops_for(compute_dtype))
+        self.peak_flops = peak_flops
         self._seen: set = set()
         self._disabled = False
 
@@ -218,5 +223,7 @@ class CostTracker:
         shapes = {k: list(getattr(v, "shape", ())) for k, v in batch.items()}
         extra = ({"compute_dtype": self.compute_dtype}
                  if self.compute_dtype else {})
+        if self.peak_flops:
+            extra["peak_flops"] = self.peak_flops
         self.elog.emit("cost", label=self.label, shapes=shapes,
-                       peak_flops=self.peak_flops, **extra, **costs)
+                       **extra, **costs)

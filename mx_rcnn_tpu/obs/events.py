@@ -296,19 +296,11 @@ def env_fingerprint() -> Dict[str, Any]:
     perf ledger is attributable to environment change — an upgraded
     jaxlib or an uncommitted local patch — not just the git sha. Stamped
     into ``run_meta`` and into every bench/ledger row (bench.py)."""
-    fields: Dict[str, Any] = {}
-    try:
-        import jax
+    import jax  # the module only: no backend is touched here
+    import jaxlib
 
-        fields["jax_version"] = jax.__version__
-    except ImportError:
-        pass  # jax-free caller — the fingerprint stays partial
-    try:
-        import jaxlib
-
-        fields["jaxlib_version"] = jaxlib.__version__
-    except (ImportError, AttributeError):
-        pass
+    fields: Dict[str, Any] = {"jax_version": jax.__version__,
+                              "jaxlib_version": jaxlib.__version__}
     dirty = _git_dirty(os.path.dirname(os.path.abspath(__file__)))
     if dirty is not None:
         fields["git_dirty"] = dirty
@@ -317,9 +309,12 @@ def env_fingerprint() -> Dict[str, Any]:
 
 def run_meta_fields(cfg=None, mesh=None, **extra) -> Dict[str, Any]:
     """The ``run_meta`` payload: config digest, mesh shape, jax/jaxlib
-    versions, git sha + dirtiness. ``cfg``/``mesh`` are optional so
-    jax-free tools (report) and config-free tools (bench across many
-    configs) can still stamp a run."""
+    versions, the device the process runs on, git sha + dirtiness
+    (both omitted where the checkout is not a git repository — the
+    chip tool's copy is not). ``cfg``/``mesh`` are optional so
+    config-free tools can still stamp a run. Touches the backend: only
+    a process that may own the chip calls this (bench.py's parent does
+    not)."""
     fields: Dict[str, Any] = {}
     if cfg is not None:
         # repr of the frozen dataclass tree is a stable, total rendering
@@ -332,13 +327,11 @@ def run_meta_fields(cfg=None, mesh=None, **extra) -> Dict[str, Any]:
         fields["mesh"] = dict(
             zip(mesh.axis_names, (int(s) for s in mesh.devices.shape)))
     fields.update(env_fingerprint())
-    try:
-        import jax
+    import jax
 
-        fields["backend"] = jax.default_backend()
-        fields["device_count"] = jax.device_count()
-    except (ImportError, RuntimeError):
-        pass  # jax-free caller (report tooling) — meta stays partial
+    fields["backend"] = jax.default_backend()
+    fields["device_kind"] = jax.devices()[0].device_kind
+    fields["device_count"] = jax.device_count()
     sha = _git_sha(os.path.dirname(os.path.abspath(__file__)))
     if sha:
         fields["git_sha"] = sha

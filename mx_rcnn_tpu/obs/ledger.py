@@ -1,24 +1,26 @@
 """graftprof perf ledger — append-only cross-run performance history.
 
     python -m mx_rcnn_tpu.obs.ledger add FILE [--round N]
-    python -m mx_rcnn_tpu.obs.ledger backfill BENCH_r01.json BENCH_r02.json ...
+    python -m mx_rcnn_tpu.obs.ledger backfill WRAPPER.json [WRAPPER.json ...]
     python -m mx_rcnn_tpu.obs.ledger show [--config NAME]
     python -m mx_rcnn_tpu.obs.ledger check [--candidate FILE] [--threshold 0.1]
 
-Every bench round so far lived in a loose ``BENCH_r0N.json`` — useful
-per round, invisible as a trajectory, and nothing ever FAILED when a
-number regressed (BENCH_r03's c4 drop vs r02 was prose, not a gate).
+A loose per-round bench artifact is useful per round, invisible as a
+trajectory, and nothing ever FAILS when a number regresses.
 The ledger is the tracked, diffable record: one JSONL row per measured
 config per round, keyed by (config, git sha, round), appended by
-``bench.py`` as each row completes and committed to the repo
-(``PERF_LEDGER.jsonl``; ``MX_RCNN_PERF_LEDGER`` overrides).
+``bench.py`` as each row completes (``bench_obs/history.jsonl`` under
+the checkout; ``MX_RCNN_PERF_LEDGER`` overrides). The root
+``PERF_LEDGER.jsonl`` is NOT this file: that name is the driver's record,
+which no code path of the repo writes.
 
 - ``add`` appends rows from any bench artifact: a ``partial.json``
   detail dict, the printed bench JSON line, or a driver
   ``BENCH_r0N.json`` wrapper — all three shapes are normalized.
-- ``backfill`` seeds history from the committed BENCH_r01–r05 wrappers
-  (rounds and rc are taken from the wrapper; r05's rc=124 lands as an
-  error row so the outage stays visible in the trajectory).
+- ``backfill`` seeds history from driver wrappers (rounds and rc are
+  taken from the wrapper; an rc=124 with no parsed output lands as an
+  error row so it stays visible in the trajectory). The rounds 1–5 rows
+  made this way live on as tests/fixtures/bench_history_seed.jsonl.
 - ``show`` renders the per-config trajectory (round, img/s, MFU,
   step ms, HBM, pad waste, compile cost).
 - ``check`` diffs candidate rows against the BEST prior row per config
@@ -48,9 +50,12 @@ _METRIC_FIELDS = (
     # environment-drift attribution (graftpulse satellite): a cross-run
     # regression should be pinnable to an env change — jaxlib upgrade,
     # uncommitted local patch — not just the git sha. bench.py stamps
-    # these into every live row (events.env_fingerprint); blob-level
+    # the versions into every live row (own_device); blob-level
     # values propagate to rows in rows_from_artifact.
     "jax_version", "jaxlib_version", "git_dirty",
+    # the device a row was measured on (bench.py::own_device): a number
+    # is never read without it
+    "platform", "device_kind", "device_count",
 )
 #: blob-level env fields copied down onto every row they wrap
 _ENV_FIELDS = ("jax_version", "jaxlib_version", "git_dirty")
@@ -66,14 +71,15 @@ def row_dtype(row: Dict[str, Any]) -> str:
 
 
 def default_path() -> str:
-    """MX_RCNN_PERF_LEDGER, else PERF_LEDGER.jsonl at the repo root
-    (resolved from this file — cwd-independent, like the lint settings)."""
+    """MX_RCNN_PERF_LEDGER, else bench_obs/history.jsonl under the repo
+    root (resolved from this file — cwd-independent, like the lint
+    settings). Never the root PERF_LEDGER.jsonl: that is the driver's."""
     env = os.environ.get("MX_RCNN_PERF_LEDGER")
     if env:
         return env
     root = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
-    return os.path.join(root, "PERF_LEDGER.jsonl")
+    return os.path.join(root, "bench_obs", "history.jsonl")
 
 
 def load_rows(path: str) -> List[Dict[str, Any]]:
@@ -310,7 +316,7 @@ def main(argv: Optional[List[str]] = None) -> int:
         description=__doc__.splitlines()[0])
     ap.add_argument("--ledger", default=None,
                     help="ledger path (default: MX_RCNN_PERF_LEDGER or "
-                         "PERF_LEDGER.jsonl at the repo root)")
+                         "bench_obs/history.jsonl under the repo root)")
     sub = ap.add_subparsers(dest="cmd", required=True)
     p_add = sub.add_parser("add", help="append rows from a bench artifact")
     p_add.add_argument("source", help="partial.json / printed bench line / "

@@ -15,9 +15,10 @@ iterations of an O(N) step inside `lax.fori_loop`. This matches the
 sequential-suppression semantics of the Cython/CUDA kernels exactly
 (including the strict `>` threshold comparison).
 
-A blockwise-bitmask Pallas kernel (the nms_kernel.cu formulation on MXU-sized
-tiles) is the planned fast path for the 12000-box training case; this jnp
-version is the reference implementation and the correctness oracle for it.
+The blockwise-bitmask Pallas kernel (ops/nms_pallas.py, the nms_kernel.cu
+formulation on MXU-sized tiles) is the TPU path; the jnp versions here are
+the reference implementation and its correctness oracle. ``nms_dispatch``
+at the bottom is the one place that decides which runs.
 """
 
 from __future__ import annotations
@@ -25,6 +26,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import PartitionSpec as P
 
 from mx_rcnn_tpu.ops.boxes import bbox_overlaps
 
@@ -154,7 +156,16 @@ def nms_dispatch(boxes, scores, valid, iou_threshold: float,
 
     boxes (B, N, 4), scores (B, N), valid (B, N) → (keep_idx (B, max_output),
     keep_valid (B, max_output)).
-    impl: "auto" | "pallas" | "xla".
+    impl: "auto" | "pallas" | "pallas_interpret" | "xla". "auto" never
+    resolves to the interpreter: a chip run gets the compiled kernel, and
+    only a caller that names "pallas_interpret" (the CPU tests) gets the
+    Pallas interpreter.
+
+    Under a mesh (train/step.py traces the step inside
+    ``use_abstract_mesh``) the Pallas path runs in a ``shard_map`` over
+    ``data``: Mosaic kernels cannot be partitioned by GSPMD, and per-image
+    NMS is independent along the batch. Every other mesh axis sees the
+    operands replicated, so each of its devices runs the same kernel.
     """
     from functools import partial
 
@@ -162,8 +173,16 @@ def nms_dispatch(boxes, scores, valid, iou_threshold: float,
 
     if impl == "auto":
         impl = "pallas" if jax.default_backend() == "tpu" else "xla"
-    if impl == "pallas":
-        return batched_nms(boxes, scores, valid, iou_threshold, max_output)
+    if impl in ("pallas", "pallas_interpret"):
+        run = partial(batched_nms, iou_threshold=iou_threshold,
+                      max_output=max_output,
+                      interpret=impl == "pallas_interpret")
+        mesh = jax.sharding.get_abstract_mesh()
+        if not mesh.empty and mesh.size > 1:
+            spec = P("data")
+            run = jax.shard_map(run, in_specs=spec, out_specs=spec,
+                                check_vma=False)
+        return run(boxes, scores, valid)
     if impl == "xla":
         nms_fn = (nms_bitmask if boxes.shape[1] <= BITMASK_NMS_MAX_BOXES
                   else nms)

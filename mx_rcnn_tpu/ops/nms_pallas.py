@@ -18,17 +18,18 @@ restructured for the TPU memory hierarchy instead of 64-thread warps:
 
 Semantics match ops/nms.py exactly (strict `>` threshold, +1 inclusive box
 widths, score-descending greedy order). This kernel is the production NMS for
-proposal generation on TPU (ops/proposal.py dispatches to ``batched_nms``
-when the backend is TPU); tests/test_nms.py::TestBatchedNMSPallas checks
-equivalence against both jnp oracles (interpret mode off-TPU).
+proposal generation on TPU (ops/nms.py::nms_dispatch decides the path);
+tests/test_nms.py::TestBatchedNMSPallas checks equivalence against both jnp
+oracles with ``interpret=True``, and tests/test_chip_compile.py compiles it
+for a described v5e at every N the presets reach.
 
 Mosaic lowering notes: dynamic_slice on computed VALUES is unsupported — all
 dynamic indexing here happens either through BlockSpec index maps (the
 per-block column views) or through `pl.ds` on refs (the in-block suppression
 matrix staged via VMEM scratch, the suppression-accumulator prefix).
 
-The kernel runs in interpreter mode off-TPU so the CPU test mesh exercises
-the same code path.
+``interpret`` is an argument, never a guess from the backend: nms_dispatch
+always asks for the compiled kernel, the CPU tests ask for the interpreter.
 """
 
 from __future__ import annotations
@@ -104,13 +105,37 @@ def _nms_kernel(rows_ref, cols_ref, cols_blk_ref, valid_ref, valid_blk_ref,
         preferred_element_type=jnp.float32)
 
 
+# Mosaic's default scoped-VMEM budget on v5e; a kernel that needs more has
+# to say so (v5e has 128 MiB of VMEM behind it).
+_DEFAULT_SCOPED_VMEM = 16 << 20
+
+
+def _vmem_limit(n_pad: int):
+    """The kernel's VMEM need at ``n_pad`` columns, or None while the
+    compiler's default budget covers it.
+
+    The two live (BLOCK, n_pad) f32 tiles (IoU and mask) dominate; the
+    (·, n_pad) inputs, the accumulator and their double buffers add about
+    an eighth of that again. The chip's compiler reports 12.28 MiB at
+    N = 12000 and 20.44 MiB at N = 20000 (tests/test_chip_compile.py keeps
+    both compiles), so only the alternate-training budget
+    (test.proposal_pre_nms_top_n = 20000) ever states a limit.
+    """
+    tile = BLOCK * n_pad * 4
+    need = 2 * tile + tile // 4 + (1 << 20)
+    return need if need > _DEFAULT_SCOPED_VMEM else None
+
+
 def nms_keep_sorted(boxes: jnp.ndarray, valid: jnp.ndarray,
-                    iou_threshold: float) -> jnp.ndarray:
+                    iou_threshold: float, interpret: bool = False
+                    ) -> jnp.ndarray:
     """Greedy-NMS survivor mask over score-DESC-sorted boxes.
 
     Args:
       boxes: (S, N, 4) float32, sorted by descending score within each set.
       valid: (S, N) bool.
+      interpret: run the kernel in the Pallas interpreter (what the CPU
+        tests ask for by name); False compiles it with Mosaic.
     Returns: keep (S, N) bool.
     """
     s, n = boxes.shape[0], boxes.shape[1]
@@ -145,13 +170,15 @@ def nms_keep_sorted(boxes: jnp.ndarray, valid: jnp.ndarray,
             pltpu.VMEM((1, n_pad), jnp.float32),
             pltpu.VMEM((BLOCK, BLOCK), jnp.float32),
         ],
-        interpret=jax.default_backend() != "tpu",
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_vmem_limit(n_pad)),
+        interpret=interpret,
     )(rows, cols, cols, vmask, vmask)
     return keep[:, 0, :n] > 0.0
 
 
 def batched_nms(boxes: jnp.ndarray, scores: jnp.ndarray, valid: jnp.ndarray,
-                iou_threshold: float, max_output: int):
+                iou_threshold: float, max_output: int,
+                interpret: bool = False):
     """Batched greedy NMS: sort → Pallas survivor mask → top-k selection.
 
     Args:
@@ -168,7 +195,7 @@ def batched_nms(boxes: jnp.ndarray, scores: jnp.ndarray, valid: jnp.ndarray,
     order = jnp.argsort(-neg, axis=1)  # stable: ties keep original order
     sboxes = jnp.take_along_axis(boxes, order[..., None], axis=1)
     svalid = jnp.take_along_axis(valid, order, axis=1)
-    keep = nms_keep_sorted(sboxes, svalid, iou_threshold)  # (S, N)
+    keep = nms_keep_sorted(sboxes, svalid, iou_threshold, interpret)  # (S, N)
 
     rank = jnp.cumsum(keep, axis=1) - 1
     take = keep & (rank < max_output)

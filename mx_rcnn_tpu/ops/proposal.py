@@ -17,9 +17,9 @@ NMS dispatch (``nms_impl``):
     call over all images.
   - "xla": the jnp formulations (ops/nms.py) — bitmask for small candidate
     sets, iterative otherwise — vmapped per image.
-  - "auto" (default): "pallas" on the TPU backend, "xla" elsewhere (the
-    Pallas kernel still runs off-TPU via the interpreter, but the XLA
-    formulations are much faster under CPU testing).
+  - "pallas_interpret": the same kernel in the Pallas interpreter — what
+    the CPU tests name; "auto" never picks it.
+  - "auto" (default): "pallas" on the TPU backend, "xla" elsewhere.
 
 The reference pads a short post-NMS set by *re-sampling kept rois*
 (proposal.py pads with random duplicates) so downstream shapes hold; we pad
@@ -70,7 +70,8 @@ def generate_proposals(
       anchors: (H*W*A, 4) from ops.anchors.anchor_grid (static const).
       min_size: min box side at the ORIGINAL scale; scaled by im_scale as in
         the reference (proposal.py: min_size * im_info[2]).
-      nms_impl: "auto" | "pallas" | "xla" (see module docstring).
+      nms_impl: "auto" | "pallas" | "pallas_interpret" | "xla" (see
+        module docstring).
       topk_impl: "exact" (lax.top_k) | "approx" (lax.approx_max_k,
         recall_target 0.95 — the TPU PartialReduce op; ~1.2 ms faster at
         the 245k-score C4 size, identical on backends without the op).

@@ -39,10 +39,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-try:  # jax >= 0.5 exports shard_map at top level
-    from jax import shard_map
-except ImportError:  # pragma: no cover - version-dependent import
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -70,15 +67,10 @@ def _block_attn_update(carry, kv, q, scale, key_mask=None):
 
 
 def _mark_varying(x, axes):
-    """Mark x as varying over the given mesh axes (shard_map manual-axes
-    type tracking). pvary is deprecated in favor of pcast in jax >= 0.9;
-    jax lines OLD enough to predate varying types (< 0.5, no pvary at
-    all) need no marking — their shard_map mixes the values freely."""
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, axes, to="varying")
-    if hasattr(lax, "pvary"):
-        return lax.pvary(x, axes)
-    return x
+    """Mark ``x`` as varying over the shard_map manual ``axes`` (varying-
+    type tracking: a carry that starts replicated and becomes per-shard
+    inside the loop must say so up front)."""
+    return lax.pcast(x, axes, to="varying")
 
 
 def _streaming_init(q, vary_axes=()):

@@ -10,6 +10,7 @@ from mx_rcnn_tpu.parallel.mesh import (
     batch_sharding,
     create_mesh,
     parse_mesh_shape,
+    place_replicated,
     replicated,
     shard_batch,
 )
@@ -26,6 +27,7 @@ __all__ = [
     "parse_mesh_shape",
     "batch_sharding",
     "replicated",
+    "place_replicated",
     "shard_batch",
     "TP_RULES",
     "tp_param_specs",

@@ -59,6 +59,21 @@ def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
 
 
+def place_replicated(tree, mesh: Mesh):
+    """Put a pytree (the train state) on the mesh, replicated, BEFORE the
+    first step. jax types an array by the mesh it lives on, so a step
+    first traced for host or one-device state is traced and compiled a
+    SECOND time when its own mesh-placed output comes back as the next
+    input — a whole extra compile of the train step (minutes on the
+    chip). Placed up front, every call sees the same types. Multi-host,
+    where the replicated sharding is not addressable from one process,
+    the jit's in_shardings keep doing the placement."""
+    sharding = replicated(mesh)
+    if not sharding.is_fully_addressable:
+        return tree
+    return jax.device_put(tree, sharding)
+
+
 def shard_batch(batch: dict, mesh: Mesh, stacked: bool = False) -> dict:
     """Place a host batch dict onto the mesh, sharded along the batch axis
     (axis 0, or axis 1 of a ``stacked`` multi-step batch).

@@ -32,20 +32,12 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.5 exports shard_map at top level
-    from jax import shard_map
-except ImportError:  # pragma: no cover - version-dependent import
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def _mark_varying(x, axes):
-    """shard_map manual-axes type tracking (see ops/ring_attention.py);
-    identity on jax lines without varying types (< 0.5)."""
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, axes, to="varying")
-    if hasattr(lax, "pvary"):
-        return lax.pvary(x, axes)
-    return x
+    """shard_map manual-axes type tracking (see ops/ring_attention.py)."""
+    return lax.pcast(x, axes, to="varying")
 
 
 def pipeline_apply(

@@ -2,14 +2,14 @@
 
 graftguard (resilience/backend.py, preempt.py) made *startup* fault-
 tolerant and preemption survivable; this module closes the remaining gap
-in the ROADMAP taxonomy: the backend dying **mid-step**. Before graftheal
-a step-time ``UNAVAILABLE`` (the TPU_OUTAGE_r5.log shape, hours into a
-run) was an uncaught RuntimeError — every step since the last checkpoint
+in the ROADMAP failure list: the backend dying **mid-step**. Before graftheal
+a step-time ``UNAVAILABLE`` (hours into a run) was an uncaught
+RuntimeError — every step since the last checkpoint
 lost, an operator required. Now the train loop's dispatch is wrapped in a
 recovery loop (tools/train.py::fit_detector):
 
 1. **Classify.** A step-time RuntimeError is classified with the PR 5
-   taxonomy (``classify_backend_error``): transient gRPC markers
+   classification (``classify_backend_error``): transient gRPC markers
    (UNAVAILABLE / DEADLINE_EXCEEDED / ABORTED) heal; anything else — a
    shape error, an INVALID_ARGUMENT — propagates untouched.
 2. **Capture.** An in-memory emergency capture of the last known-good
@@ -23,9 +23,12 @@ recovery loop (tools/train.py::fit_detector):
    f(seed, epoch), per-dispatch keys fold the global index), so the
    resumed trajectory is the one the uninterrupted run would have taken.
 3. **Re-acquire.** Tear the cached backend down (the clear used for the
-   silent-CPU-fallback path) and re-acquire through the classified
+   CPU-fallback path) and re-acquire through the classified
    retry-with-backoff of ``acquire_backend`` under the SAME
-   ``resilience.backend_deadline_s`` that guards startup.
+   ``resilience.backend_deadline_s`` that guards startup. What comes
+   back must be the platform the run started on: a run on the chip is
+   never healed onto a CPU, whatever ``resilience.backend_platform``
+   says.
 4. **Re-shard.** The backend may come back with a DIFFERENT device
    count (spot reclaim, partial slice): the caller rebuilds the mesh via
    ``parallel.partition.elastic_mesh_spec`` (model axis preserved, data
@@ -45,7 +48,7 @@ not an outage, and re-raising beats looping. Fault injection:
 ``MX_RCNN_CHAOS="device_lost_at_step=K"`` raises the loss signature
 before the dispatch that would complete optimizer step K;
 ``shrink_on_reacquire=N`` hands recovery only the first N devices
-(resilience/chaos.py). Runbook: OUTAGES.md "mid-run backend loss".
+(resilience/chaos.py).
 """
 
 from __future__ import annotations
@@ -123,6 +126,7 @@ class Healer:
         self._since_snapshot = 0
         self._n_devices: Optional[int] = None
         self._footprint: Optional[int] = None
+        self._platform: Optional[str] = None
         # graftquorum (resilience/quorum.py): multi-host runs install a
         # hook called with the re-acquired device list; it runs one
         # generation of the heal quorum (barrier, topology agreement,
@@ -137,14 +141,18 @@ class Healer:
 
     # -- bookkeeping the train loop drives ---------------------------------
 
-    def note_devices(self, n: int):
-        """Record the session's device count (the heal event's 'before').
-        The largest session ever seen is the run's FOOTPRINT — the cap
-        for reporting re-acquired capacity (a re-grow back toward it
-        after an earlier shrink is a real transition; spare devices
-        beyond it are not)."""
+    def note_devices(self, n: int, platform: Optional[str] = None):
+        """Record the session's device count (the heal event's 'before')
+        and the platform the run is on. The largest session ever seen is
+        the run's FOOTPRINT — the cap for reporting re-acquired capacity
+        (a re-grow back toward it after an earlier shrink is a real
+        transition; spare devices beyond it are not). The FIRST platform
+        noted is the run's platform for good: a heal re-acquires that
+        one or re-raises (``recover``)."""
         self._n_devices = int(n)
         self._footprint = max(self._footprint or 0, int(n))
+        if self._platform is None:
+            self._platform = platform
 
     def note_progress(self):
         """A dispatch completed — the backend is live again; re-arm the
@@ -172,7 +180,7 @@ class Healer:
 
     def healable(self, exc: BaseException) -> bool:
         """Should this step-time error be healed in-process? Transient by
-        the PR 5 taxonomy, under the consecutive cap, and heal enabled."""
+        the PR 5 classification, under the consecutive cap, and heal enabled."""
         if not getattr(self.rcfg, "heal", False):
             return False
         if not isinstance(exc, RuntimeError):
@@ -221,6 +229,14 @@ class Healer:
         _clear_backend_cache()
         devices = acquire_backend(self.rcfg, elog=self.elog)
         devices = chaos.site("backend_reacquire", devices=devices)
+        got = {getattr(d, "platform", None) for d in devices}
+        if self._platform is not None and self._platform not in got:
+            # A run that started on the chip is not "healed" onto
+            # whatever comes up once the backends were cleared.
+            raise RuntimeError(
+                f"graftheal: the run started on {self._platform!r} but "
+                f"the backend came back as {sorted(map(str, got))} — "
+                "not healing onto another platform") from exc
         # Multi-host: every surviving host reaches the heal quorum with
         # its re-acquired capacity and adopts the agreed topology; a
         # host that missed the deadline gets QuorumExcludedError here

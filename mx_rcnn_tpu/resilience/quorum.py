@@ -150,7 +150,7 @@ class JaxKVStore(KVStore):
     def get(self, key: str) -> Optional[str]:
         try:
             value = self._client.key_value_try_get(key)
-        except Exception:  # graftlint: disable=broad-except — the client maps NOT_FOUND to different exception types across jax versions; absent-key is the expected answer here
+        except Exception:  # graftlint: disable=broad-except — NOT_FOUND surfaces as a backend-specific runtime error; absent-key is the expected answer here
             return None
         return value if value else None
 

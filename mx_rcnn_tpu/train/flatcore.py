@@ -34,7 +34,7 @@ the update writes the masters in f32 (bit-exact vs the f32 policy given
 equal grads) and re-materializes the shadow with ONE ``convert`` per
 dtype buffer — a program output, so XLA cannot fold it away or
 re-duplicate it into consumer fusions (``optimization_barrier`` is
-dropped by the CPU pipeline and has no AD rule on jax 0.4.x). The
+dropped by the CPU pipeline). The
 forward's param views slice the shadow — except the f32 islands (norm
 statistics/affine, ``precision.is_island_param``), which stay views of
 the master — and the loss differentiates w.r.t. the (master, shadow)

@@ -21,8 +21,8 @@ policy, and flatcore (train/flatcore.py) makes the policy structural:
   (``FlatTrainState.compute``): the update writes the f32 masters and
   re-materializes the shadow with ONE ``convert`` per dtype buffer — a
   program output, so XLA cannot re-duplicate it into consumer fusions
-  (``optimization_barrier`` is dropped by the CPU pipeline and has no AD
-  rule on jax 0.4.x; an output is the only reliable pin). The param tree
+  (``optimization_barrier`` is dropped by the CPU pipeline; an output is
+  the only reliable pin). The param tree
   the forward sees is slice/reshape views of the shadow; the per-leaf
   cast tree is gone (gated in tests/test_precision.py).
 - **f32 islands.** The numerics that f16-family dtypes demonstrably

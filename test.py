@@ -67,8 +67,8 @@ def main():
 
     obs_log = obs_from_config(cfg, default_dir=f"{args.prefix}.obs")
     if cfg.resilience.backend_acquire:
-        # graftguard: ride out a transient relay outage instead of dying
-        # on first touch (resilience/backend.py; runbook OUTAGES.md).
+        # graftguard: ride out a transiently unavailable backend instead
+        # of dying on first touch (resilience/backend.py).
         from mx_rcnn_tpu.resilience import acquire_backend
 
         acquire_backend(cfg.resilience, elog=obs_log)

@@ -45,7 +45,7 @@ def sleepy_runner(label):
 
 
 def error_runner(label):
-    raise RuntimeError(f"relay dropped mid-measure ({label})")
+    raise RuntimeError(f"cell dropped mid-measure ({label})")
 
 
 # ---------------------------------------------------------------------------
@@ -218,7 +218,7 @@ def main(argv=None):
     jax.config.update("jax_platforms", "cpu")
     from mx_rcnn_tpu.utils.compile_cache import enable_persistent_cache
 
-    enable_persistent_cache()  # share tests/.jax_cache with the suite
+    enable_persistent_cache()  # the suite's cache (utils/compile_cache.py)
 
     if args.crash_save:
         _crash_save(args.crash_save, scale=args.scale)

@@ -1,0 +1,164 @@
+"""Compiles for the chip, without the chip.
+
+The TPU's compiler is installed here and compiles for a v5e that is
+DESCRIBED, not attached (on-chip-measurement guide §2.3) — so what only the
+chip's compiler can refuse is held by tier-1 at no chip time:
+
+- the Pallas NMS kernel (ops/nms_pallas.py) at every N the presets reach —
+  6000 / 12000 (C4 test / train), 20000 (alternate training's
+  ``test.proposal_pre_nms_top_n``, which needs more than Mosaic's default
+  16 MiB of scoped VMEM), the FPN per-level 1000 / 2000 and all-level
+  5000 / 10000 — at batch 1 and 2;
+- the same kernel under a 4-device ``(data, model)`` mesh, where GSPMD
+  refuses a bare Mosaic call ("cannot be automatically partitioned") and
+  ``nms_dispatch`` has to wrap it in a ``shard_map`` over ``data``;
+- a whole data-parallel train step on that mesh, tiny in width, built by
+  ``make_train_step`` exactly as ``fit_detector`` builds it.
+
+Each asserts ``tpu_custom_call`` in the compiled program: the kernel is in
+it, not its jnp stand-in. Nothing runs — a compile that passes is not a chip
+run. The interpret-mode parity tests are tests/test_nms.py.
+
+Ground rules of this file (guide §2): the topology is described inside a
+module-scoped fixture that skips when it cannot be, never while a module is
+imported; all of these tests stay in this ONE file (only one process at a
+time may load the TPU's library — a second file could land on another
+worker); no child processes; the compile cache is off around the compiles
+(an executable compiled for a described device cannot be read back).
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from mx_rcnn_tpu.ops import nms_pallas
+from mx_rcnn_tpu.ops.nms import nms_dispatch
+
+KERNEL = 'custom_call_target="tpu_custom_call"'
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs to /tmp
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001  # graftlint: disable=broad-except — whatever keeps libtpu from describing the chip becomes a skip that says so
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def data_mesh(topo):
+    return Mesh(np.asarray(topo.devices).reshape(4, 1), ("data", "model"))
+
+
+def _nms_args(batch, n, sharding):
+    return (jax.ShapeDtypeStruct((batch, n, 4), jnp.float32,
+                                 sharding=sharding),
+            jax.ShapeDtypeStruct((batch, n), jnp.float32, sharding=sharding),
+            jax.ShapeDtypeStruct((batch, n), jnp.bool_, sharding=sharding))
+
+
+@pytest.mark.parametrize("batch", [1, 2])
+@pytest.mark.parametrize("n", [
+    6000, 12000, 20000,        # C4 test / C4 train / alternate proposals
+    1000, 2000, 5000, 10000,   # FPN per level (test / train), all levels
+])
+def test_nms_kernel_compiles_at_every_preset_n(one_chip, n, batch):
+    boxes, _, valid = _nms_args(batch, n, one_chip)
+    compiled = jax.jit(
+        lambda b, v: nms_pallas.nms_keep_sorted(b, v, 0.7)
+    ).lower(boxes, valid).compile()
+    assert KERNEL in compiled.as_text()
+
+
+def test_only_the_alternate_budget_states_a_vmem_limit():
+    """Up to the C4 train budget the kernel runs in Mosaic's default 16 MiB
+    of scoped VMEM (the programs there are unchanged); past it the kernel
+    states its real need instead of the config lowering a reference
+    value."""
+    from mx_rcnn_tpu.config import generate_config
+
+    pad = lambda n: -(-n // nms_pallas.BLOCK) * nms_pallas.BLOCK
+    assert nms_pallas._vmem_limit(pad(12000)) is None
+    need = nms_pallas._vmem_limit(pad(20000))
+    assert 20.44 * 2 ** 20 < need < 32 * 2 ** 20  # the compiler asked 20.44M
+    cfg = generate_config("resnet101", "coco")
+    assert cfg.test.proposal_pre_nms_top_n == 20000
+    assert (cfg.train.rpn_pre_nms_top_n, cfg.train.rpn_post_nms_top_n,
+            cfg.test.rpn_pre_nms_top_n, cfg.test.rpn_post_nms_top_n) == (
+                12000, 2000, 6000, 300)
+
+
+def test_whole_nms_op_compiles_with_auto_dispatch(one_chip, monkeypatch):
+    """sort → kernel → top-k as the proposal op calls it, ``impl="auto"``:
+    on a TPU backend that is the compiled kernel, never the interpreter."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = jax.jit(
+        lambda b, s, v: nms_dispatch(b, s, v, 0.7, 2000)
+    ).lower(*_nms_args(1, 12000, one_chip)).compile()
+    assert KERNEL in compiled.as_text()
+
+
+def test_kernel_partitions_over_a_data_mesh(data_mesh):
+    """The smallest function that shards a batch over ``data`` and reaches
+    the kernel: GSPMD alone refuses it, the shard_map in nms_dispatch is
+    what lets a jit over the mesh hold a Mosaic call."""
+    sharded = NamedSharding(data_mesh, P("data"))
+
+    def proposals(boxes, scores, valid):
+        with jax.sharding.use_abstract_mesh(data_mesh.abstract_mesh):
+            return nms_dispatch(boxes, scores, valid, 0.7, 2000,
+                                impl="pallas")
+
+    compiled = jax.jit(proposals, in_shardings=sharded).lower(
+        *_nms_args(4, 12000, sharded)).compile()
+    assert KERNEL in compiled.as_text()
+
+    bare = jax.jit(
+        lambda b, s, v: nms_pallas.batched_nms(b, s, v, 0.7, 2000),
+        in_shardings=sharded)
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        bare.lower(*_nms_args(4, 12000, sharded)).compile()
+
+
+def test_data_parallel_train_step_compiles_with_the_kernel(data_mesh,
+                                                           monkeypatch):
+    """make_train_step over the 4-device mesh, as fit_detector builds it
+    (tiny widths — the R-101 step takes minutes and is chip_smoke.py's):
+    the Pallas NMS is in the partitioned program, and so is the gradient
+    all-reduce."""
+    from mx_rcnn_tpu.config import generate_config
+    from mx_rcnn_tpu.models.faster_rcnn import build_model
+    from mx_rcnn_tpu.train.step import abstract_step_inputs, make_train_step
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = generate_config("resnet50", "synthetic", **{
+        "train.rpn_pre_nms_top_n": 256, "train.rpn_post_nms_top_n": 64,
+        "train.batch_rois": 32, "train.max_gt_boxes": 8,
+        "network.anchor_scales": (2, 4, 8), "image.pad_shape": (128, 128)})
+    model = build_model(cfg)
+    compiled = make_train_step(model, cfg, mesh=data_mesh).lower(
+        *abstract_step_inputs(model, cfg, data_mesh, 4)).compile()
+    hlo = compiled.as_text()
+    assert KERNEL in hlo
+    assert "all-reduce" in hlo

@@ -368,12 +368,12 @@ def _entry_fusions(text):
 
 @pytest.mark.parametrize("opt", ["sgd", "adamw"])
 def test_flat_update_kernel_count_collapses(opt):
-    """The compiled flat update is O(1) kernels in the leaf count — ≤ 10
-    fused kernels at the program's top level and a few dozen arithmetic
-    instructions total — while the per-leaf path scales with the tree
-    (hundreds of instructions for a ~100-leaf tree). Same method as the
-    packed-RPN 5-conv→1-conv HLO count: structure of the COMPILED program
-    on the CPU backend, immune to TPU outages."""
+    """The compiled flat update is O(1) kernels in the leaf count — a
+    dozen or so fused kernels at the program's top level and a few dozen
+    arithmetic instructions total — while the per-leaf path scales with
+    the tree (hundreds of instructions for a ~100-leaf tree). Same method
+    as the packed-RPN 5-conv→1-conv HLO count: structure of the COMPILED
+    program on the CPU backend — a count, not a speed."""
     over = {"optimizer": opt}
     if opt == "adamw":
         over.update(lr=1e-4, clip_gradient=0.1)
@@ -393,7 +393,15 @@ def test_flat_update_kernel_count_collapses(opt):
 
     flat_arith = _module_arith(flat_txt)
     tree_arith = _module_arith(tree_txt)
-    assert _entry_fusions(flat_txt) <= 10, flat_txt[:2000]
+    # The absolute bound is what THIS XLA emits (jaxlib 0.9.0, CPU): 4
+    # entry fusions for flat SGD, 13 for flat AdamW (its clip-by-global-
+    # norm and bias-correction scalars are no longer folded into the
+    # update fusions) against 198 / 490 per-leaf. What it guards is the
+    # collapse — a flat update that is O(1) in the leaf count — so the
+    # ratio is asserted beside it.
+    flat_fus, tree_fus = _entry_fusions(flat_txt), _entry_fusions(tree_txt)
+    assert flat_fus <= 16, flat_txt[:2000]
+    assert tree_fus >= 10 * flat_fus, (tree_fus, flat_fus)
     assert flat_arith <= 40, f"flat update grew: {flat_arith} arith ops"
     assert tree_arith >= 200, f"per-leaf baseline changed: {tree_arith}"
     assert tree_arith >= 10 * flat_arith, (tree_arith, flat_arith)

@@ -320,12 +320,6 @@ def test_mask_inference_contract(rng):
     assert ((p >= 0) & (p <= 1)).all()
 
 
-@pytest.mark.xfail(
-    not hasattr(jax.lax, "pvary") and not hasattr(jax.lax, "pcast"),
-    reason="pre-varying-type jax (< 0.5): the old partitioner's bf16 "
-           "reduction order drifts the DP loss ~0.6% past the rtol "
-           "calibrated on newer XLA (see test_pipeline.py's marker)",
-    strict=False)
 def test_fpn_dp_parity(rng):
     """FPN train step: 2-way DP == single device on the same 2-image batch
     (the pattern of tests/test_train_step.py::test_dp_grads_match_single_device)."""

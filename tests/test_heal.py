@@ -175,16 +175,39 @@ def _hermetic_healer(monkeypatch, tmp_path=None, devices=("d0", "d1"),
     return Healer(rcfg, elog=elog), elog
 
 
-def test_healer_classifies_with_pr5_taxonomy(monkeypatch):
+def test_healer_classifies_with_pr5_classes(monkeypatch):
     healer, _ = _hermetic_healer(monkeypatch)
     assert healer.healable(RuntimeError("UNAVAILABLE: device lost"))
-    assert healer.healable(RuntimeError("ABORTED: relay restarting"))
+    assert healer.healable(RuntimeError("ABORTED: backend restarting"))
     assert not healer.healable(RuntimeError("INVALID_ARGUMENT: shapes"))
     assert not healer.healable(ValueError("UNAVAILABLE-looking non-RT"))
     assert not healer.healable(
         RuntimeError("XlaRuntimeError: something unclassified"))
     off, _ = _hermetic_healer(monkeypatch, heal=False)
     assert not off.healable(RuntimeError("UNAVAILABLE: device lost"))
+
+
+def test_healer_refuses_another_platform(monkeypatch):
+    """A run that started on the chip is never healed onto whatever
+    comes up once the backends were cleared: the re-acquired platform
+    must be the one the run started on, anything else re-raises."""
+    from types import SimpleNamespace
+
+    cpu = [SimpleNamespace(platform="cpu")]
+    tpu = [SimpleNamespace(platform="tpu")]
+    healer, _ = _hermetic_healer(monkeypatch, devices=cpu)
+    healer.note_devices(1, "tpu")
+    lost = RuntimeError("UNAVAILABLE: device lost")
+    with pytest.raises(RuntimeError, match="started on 'tpu'") as info:
+        healer.recover(lost, lambda: HealCarry(params={}))
+    assert info.value.__cause__ is lost and healer.heals == 0
+    # the same loss heals when the chip comes back — and a later
+    # session cannot re-define what the run started on
+    monkeypatch.setattr(heal_mod, "acquire_backend",
+                        lambda rcfg, elog=None: tpu)
+    healer.note_devices(1, "cpu")
+    healer.recover(lost, lambda: HealCarry(params={}))
+    assert healer.heals == 1
 
 
 def test_healer_live_capture_and_event(monkeypatch, tmp_path):

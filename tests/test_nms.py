@@ -6,7 +6,13 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from mx_rcnn_tpu.ops.nms import nms, nms_bitmask
+from functools import partial
+
+from mx_rcnn_tpu.ops import nms_pallas
+from mx_rcnn_tpu.ops.nms import nms, nms_bitmask, nms_dispatch
+
+# The CPU tests name the Pallas interpreter; nothing else ever gets it.
+batched_nms = partial(nms_pallas.batched_nms, interpret=True)
 
 
 def py_greedy_nms(dets, thresh):
@@ -102,14 +108,13 @@ class TestBatchedNMSPallas:
     """Differential tests for the Pallas blocked-bitmask kernel
     (ops/nms_pallas.py::batched_nms) against both jnp oracles.
 
-    Off-TPU these run the kernel in interpret mode — the same code path the
-    TPU lowering traces, minus Mosaic."""
+    These name the Pallas interpreter (``interpret=True``) — the same code
+    path the TPU lowering traces, minus Mosaic; tests/test_chip_compile.py
+    holds the Mosaic compiles."""
 
     @pytest.mark.parametrize("n", [40, 128, 200, 300])
     @pytest.mark.parametrize("thresh", [0.3, 0.7])
     def test_matches_oracles(self, rng, n, thresh):
-        from mx_rcnn_tpu.ops.nms_pallas import batched_nms
-
         boxes, scores = random_dets(rng, n)
         valid = np.ones(n, bool)
         ki, kv = batched_nms(
@@ -126,8 +131,6 @@ class TestBatchedNMSPallas:
 
     def test_multi_block(self, rng):
         """>1 block of 128 — exercises cross-block suppression propagation."""
-        from mx_rcnn_tpu.ops.nms_pallas import batched_nms
-
         n = 384  # 3 blocks
         boxes, scores = random_dets(rng, n)
         valid = np.ones(n, bool)
@@ -140,8 +143,6 @@ class TestBatchedNMSPallas:
 
     def test_batched(self, rng):
         """Independent per-set results in one batched call."""
-        from mx_rcnn_tpu.ops.nms_pallas import batched_nms
-
         sets = [random_dets(rng, 96) for _ in range(3)]
         boxes = np.stack([b for b, _ in sets])
         scores = np.stack([s for _, s in sets])
@@ -156,8 +157,6 @@ class TestBatchedNMSPallas:
     def test_ties_stable_by_original_index(self):
         """Equal-score duplicate boxes: the earlier index wins (stable sort),
         the duplicate is suppressed."""
-        from mx_rcnn_tpu.ops.nms_pallas import batched_nms
-
         boxes = np.array([[0, 0, 10, 10], [0, 0, 10, 10],
                           [50, 50, 60, 60]], np.float32)
         scores = np.array([0.9, 0.9, 0.8], np.float32)
@@ -169,8 +168,6 @@ class TestBatchedNMSPallas:
         assert got.tolist() == [0, 2]
 
     def test_validity_mask(self, rng):
-        from mx_rcnn_tpu.ops.nms_pallas import batched_nms
-
         boxes, scores = random_dets(rng, 64)
         valid = np.zeros(64, bool)
         valid[:20] = True
@@ -182,16 +179,12 @@ class TestBatchedNMSPallas:
         assert got.tolist() == list(want)
 
     def test_all_invalid(self):
-        from mx_rcnn_tpu.ops.nms_pallas import batched_nms
-
         ki, kv = batched_nms(
             jnp.zeros((1, 16, 4)), jnp.zeros((1, 16)),
             jnp.zeros((1, 16), bool), 0.5, 8)
         assert not np.asarray(kv).any()
 
     def test_jit_consistency(self, rng):
-        from mx_rcnn_tpu.ops.nms_pallas import batched_nms
-
         boxes, scores = random_dets(rng, 80)
         valid = np.ones(80, bool)
         args = (jnp.array(boxes)[None], jnp.array(scores)[None],
@@ -213,11 +206,38 @@ def test_generate_proposals_pallas_vs_xla(rng):
     deltas = jnp.asarray((rng.randn(2, h, w, 4 * a) * 0.1).astype(np.float32))
     im_info = jnp.asarray([[120.0, 120.0, 1.0], [100.0, 110.0, 1.0]])
     kw = dict(pre_nms_top_n=200, post_nms_top_n=50, nms_thresh=0.7, min_size=4)
-    r1 = generate_proposals(prob, deltas, im_info, anchors, nms_impl="pallas", **kw)
+    r1 = generate_proposals(prob, deltas, im_info, anchors, nms_impl="pallas_interpret", **kw)
     r2 = generate_proposals(prob, deltas, im_info, anchors, nms_impl="xla", **kw)
     np.testing.assert_allclose(r1[0], r2[0], rtol=1e-6)
     assert np.array_equal(r1[1], r2[1])
     np.testing.assert_allclose(r1[2], r2[2], rtol=1e-6)
+
+
+def test_dispatch_shards_kernel_over_data_mesh(rng):
+    """Under a (data, model) mesh the Pallas path runs inside a shard_map
+    over ``data`` (Mosaic kernels cannot be partitioned by GSPMD): same
+    survivors as the jnp path, output still sharded by image."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.asarray(jax.devices()[:4]).reshape(4, 1),
+                ("data", "model"))
+    sets = [random_dets(rng, 200) for _ in range(4)]
+    boxes = jnp.asarray(np.stack([b for b, _ in sets]))
+    scores = jnp.asarray(np.stack([s for _, s in sets]))
+    valid = jnp.ones((4, 200), bool)
+
+    def run(impl):
+        def f(b, s, v):
+            with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+                return nms_dispatch(b, s, v, 0.6, 50, impl=impl)
+        return jax.jit(f, in_shardings=NamedSharding(mesh, P("data")))
+
+    sharded = run("pallas_interpret")
+    assert "shard_map" in str(jax.make_jaxpr(sharded)(boxes, scores, valid))
+    ki, kv = sharded(boxes, scores, valid)
+    ki2, kv2 = run("xla")(boxes, scores, valid)
+    assert np.array_equal(ki, ki2) and np.array_equal(kv, kv2)
+    assert ki.sharding.spec == P("data")
 
 
 def test_generate_proposals_approx_topk(rng):

@@ -138,13 +138,6 @@ def _run_steps(cfg, params, batch, mesh=None, tp=False, n_steps=2):
     return losses, jax.device_get(state.params)
 
 
-@pytest.mark.xfail(
-    not hasattr(jax.lax, "pvary") and not hasattr(jax.lax, "pcast"),
-    reason="pre-varying-type jax (< 0.5): the old GSPMD partitioner's "
-           "bf16 reduction order drifts ~4e-4 on step 1 and AdamW "
-           "amplifies it on step 2, exceeding the rtol calibrated on "
-           "newer XLA (see the matching marker in test_pipeline.py)",
-    strict=False)
 def test_vitdet_tp_step_matches_replicated(rng):
     """DP×TP (2x2 mesh) reproduces the single-device step: same losses,
     same updated params — GSPMD collectives change only the schedule."""

@@ -140,14 +140,6 @@ def _batch(rng, b=4):
     return {k: np.repeat(v, b, axis=0) for k, v in one.items()}
 
 
-@pytest.mark.xfail(
-    not hasattr(jax.lax, "pvary") and not hasattr(jax.lax, "pcast"),
-    reason="pre-varying-type jax (< 0.5): the old partitioner's bf16 "
-           "reduction order drifts ~8e-4 on step 1 and AdamW amplifies "
-           "it on step 2, exceeding the rtol calibrated on newer XLA "
-           "(the ring/ulysses parity tests still pass at tight rtol, so "
-           "shard_map itself is numerically sound here)",
-    strict=False)
 def test_vitdet_pp_train_step_matches_sequential(rng):
     """Two DP x PP train steps reproduce the single-device staged run —
     the pipeline is a schedule, not a numerics change."""

@@ -207,16 +207,7 @@ def test_multichip_dp_step_runs():
 
 def test_dp_grads_match_single_device():
     """DP over 2 virtual devices == single device on the same 2-image batch
-    (the KVStore-allreduce correctness check the reference never had).
-
-    Tolerance is split by jax generation instead of xfail'ing: on
-    pre-varying-type jax (< 0.5) the old partitioner's bf16 reduction
-    order drifts the DP loss ~0.2% — within the borderline of the tight
-    rtol, so a non-strict xfail sometimes XPASSed, and the driver's
-    `^[.FEsx]+` dot grep drops uppercase-`X` lines (the dot count
-    flapped). A 1% gate on old jax still catches real allreduce breakage
-    (wrong psum semantics are order-1 errors) and the outcome is
-    deterministic; newer XLA keeps the calibrated tight gate."""
+    (the KVStore-allreduce correctness check the reference never had)."""
     cfg = tiny_cfg(batch_images=2)
     model = build_model(cfg)
     params = init_params(model, cfg, jax.random.PRNGKey(0))
@@ -233,10 +224,8 @@ def test_dp_grads_match_single_device():
     dp = make_train_step(model, cfg, mesh=mesh, donate=False)
     s2_new, m2 = dp(s2, shard_batch(batch, mesh), rng)
 
-    old_jax = (not hasattr(jax.lax, "pvary")
-               and not hasattr(jax.lax, "pcast"))
-    loss_rtol = 1e-2 if old_jax else 1e-4
-    param_rtol, param_atol = (1e-2, 2e-4) if old_jax else (2e-3, 2e-5)
+    loss_rtol = 1e-4
+    param_rtol, param_atol = 2e-3, 2e-5
     assert np.allclose(float(m1["TotalLoss"]), float(m2["TotalLoss"]),
                        rtol=loss_rtol)
     l1 = jax.tree.leaves(s1_new.params)
