@@ -30,6 +30,7 @@ from mx_rcnn_tpu.config import Config
 from mx_rcnn_tpu.models.backbones import ResNetC4, ResNetHead, VGGConv, VGGHead
 from mx_rcnn_tpu.models.losses import rcnn_losses, rpn_losses
 from mx_rcnn_tpu.models.rpn import RPNHead
+from mx_rcnn_tpu.obs.profile import stage
 from mx_rcnn_tpu.ops.anchors import anchor_grid
 from mx_rcnn_tpu.ops.boxes import bbox_pred, clip_boxes
 from mx_rcnn_tpu.ops.proposal import generate_proposals
@@ -156,26 +157,31 @@ def _pool_rois(feat, rois, roi_valid, pool_size, pool_type,
     clamps border samples to the image's own cells (ops/roi_align.py).
     """
     b, r = rois.shape[0], rois.shape[1]
-    ids = (jnp.arange(b, dtype=jnp.float32) if plane_of is None
-           else island(plane_of))
-    batch_idx = jnp.repeat(ids, r)[:, None]
-    flat = jnp.concatenate([batch_idx, rois.reshape(b * r, 4)], axis=1)
-    if pool_type == "align":
-        win = None if windows is None else jnp.repeat(windows, r, axis=0)
-        pooled = roi_align(feat, flat, pool_size, 1.0 / 16.0, windows=win)
-    else:
-        pooled = roi_pool(feat, flat, pool_size, 1.0 / 16.0)
-    # Zero padded slots so dead rois contribute nothing downstream.
-    return pooled * roi_valid.reshape(b * r, 1, 1, 1).astype(pooled.dtype)
+    with stage("roi_align"):
+        ids = (jnp.arange(b, dtype=jnp.float32) if plane_of is None
+               else island(plane_of))
+        batch_idx = jnp.repeat(ids, r)[:, None]
+        flat = jnp.concatenate([batch_idx, rois.reshape(b * r, 4)], axis=1)
+        if pool_type == "align":
+            win = None if windows is None else jnp.repeat(windows, r, axis=0)
+            pooled = roi_align(feat, flat, pool_size, 1.0 / 16.0,
+                               windows=win)
+        else:
+            pooled = roi_pool(feat, flat, pool_size, 1.0 / 16.0)
+        # Zero padded slots so dead rois contribute nothing downstream.
+        return pooled * roi_valid.reshape(b * r, 1, 1, 1).astype(
+            pooled.dtype)
 
 
 def _backbone_rpn(model: FasterRCNN, params, images: jnp.ndarray, cfg: Config,
                   masks=None):
     """Shared preamble: backbone features + RPN outputs + the anchor grid
     (compile-time const). Used by every forward variant."""
-    feat = model.apply(params, images, masks, method=FasterRCNN.extract)
-    rpn_cls_logits, rpn_bbox_deltas = model.apply(
-        params, feat, method=FasterRCNN.rpn_forward)
+    with stage("backbone"):
+        feat = model.apply(params, images, masks, method=FasterRCNN.extract)
+    with stage("rpn_head"):
+        rpn_cls_logits, rpn_bbox_deltas = model.apply(
+            params, feat, method=FasterRCNN.rpn_forward)
     anchors = jnp.asarray(anchor_grid(
         feat.shape[1], feat.shape[2],
         stride=cfg.network.rpn_feat_stride,
@@ -192,18 +198,19 @@ def _assign_anchors_batch(anchors, gt_boxes, gt_valid, im_info, rng,
     targets). Rows may be bucketed (im_info (B, 3)) or graftcanvas
     packed ((B, 5) placement rows in canvas coordinates)."""
     b = gt_boxes.shape[0]
-    return jax.vmap(
-        partial(
-            assign_anchor,
-            rpn_batch_size=cfg.train.rpn_batch_size,
-            rpn_fg_fraction=cfg.train.rpn_fg_fraction,
-            positive_overlap=cfg.train.rpn_positive_overlap,
-            negative_overlap=cfg.train.rpn_negative_overlap,
-            allowed_border=cfg.train.rpn_allowed_border,
-            clobber_positives=cfg.train.rpn_clobber_positives,
-        ),
-        in_axes=(None, 0, 0, 0, 0),
-    )(anchors, gt_boxes, gt_valid, im_info, jax.random.split(rng, b))
+    with stage("rpn_targets"):
+        return jax.vmap(
+            partial(
+                assign_anchor,
+                rpn_batch_size=cfg.train.rpn_batch_size,
+                rpn_fg_fraction=cfg.train.rpn_fg_fraction,
+                positive_overlap=cfg.train.rpn_positive_overlap,
+                negative_overlap=cfg.train.rpn_negative_overlap,
+                allowed_border=cfg.train.rpn_allowed_border,
+                clobber_positives=cfg.train.rpn_clobber_positives,
+            ),
+            in_axes=(None, 0, 0, 0, 0),
+        )(anchors, gt_boxes, gt_valid, im_info, jax.random.split(rng, b))
 
 
 def forward_train(
@@ -256,85 +263,92 @@ def forward_train(
     rpn_t = _assign_anchors_batch(anchors, gt["gt_boxes"], gt["gt_valid"],
                                   im_info, k_anchor, cfg)
 
-    rpn_logits_pairs = _pair_logits(rpn_cls_logits, a)
-    rpn_deltas_rows = rpn_bbox_deltas.reshape(rpn_bbox_deltas.shape[0], -1, 4)
-    if packed:
-        # Per-plane head outputs → per-image rows over the canvas grid.
-        rpn_logits_pairs = plane_take(rpn_logits_pairs, plane_of)
-        rpn_deltas_rows = plane_take(rpn_deltas_rows, plane_of)
-    rpn_l = rpn_losses(
-        rpn_logits_pairs,
-        rpn_deltas_rows,
-        rpn_t.labels,
-        rpn_t.bbox_targets,
-        rpn_t.bbox_weights,
-        cfg.train.rpn_batch_size,
-    )
+    with stage("rpn_loss"):
+        rpn_logits_pairs = _pair_logits(rpn_cls_logits, a)
+        rpn_deltas_rows = rpn_bbox_deltas.reshape(
+            rpn_bbox_deltas.shape[0], -1, 4)
+        if packed:
+            # Per-plane head outputs → per-image rows over the canvas grid.
+            rpn_logits_pairs = plane_take(rpn_logits_pairs, plane_of)
+            rpn_deltas_rows = plane_take(rpn_deltas_rows, plane_of)
+        rpn_l = rpn_losses(
+            rpn_logits_pairs,
+            rpn_deltas_rows,
+            rpn_t.labels,
+            rpn_t.bbox_targets,
+            rpn_t.bbox_weights,
+            cfg.train.rpn_batch_size,
+        )
 
     # --- Proposals (reference: Proposal op; gradients do not flow) ---
-    rpn_prob = _rpn_softmax(jax.lax.stop_gradient(rpn_cls_logits), a)
-    if packed:
-        p = rpn_prob.shape[0]
-        fg = rpn_prob[..., a:].reshape(p, -1)
-        rois, roi_valid, _ = generate_proposals_packed(
-            plane_take(fg, plane_of),
-            jax.lax.stop_gradient(rpn_deltas_rows),  # already per-image
-            im_info,
-            anchors,
-            pre_nms_top_n=cfg.train.rpn_pre_nms_top_n,
-            post_nms_top_n=cfg.train.rpn_post_nms_top_n,
-            nms_thresh=cfg.train.rpn_nms_thresh,
-            min_size=cfg.train.rpn_min_size,
-            topk_impl=cfg.network.proposal_topk,
-        )
-    else:
-        rois, roi_valid, _ = generate_proposals(
-            rpn_prob,
-            jax.lax.stop_gradient(rpn_bbox_deltas),
-            im_info,
-            anchors,
-            pre_nms_top_n=cfg.train.rpn_pre_nms_top_n,
-            post_nms_top_n=cfg.train.rpn_post_nms_top_n,
-            nms_thresh=cfg.train.rpn_nms_thresh,
-            min_size=cfg.train.rpn_min_size,
-            feat_stride=stride,
-            topk_impl=cfg.network.proposal_topk,
-        )
+    with stage("proposal"):
+        rpn_prob = _rpn_softmax(jax.lax.stop_gradient(rpn_cls_logits), a)
+        if packed:
+            p = rpn_prob.shape[0]
+            fg = rpn_prob[..., a:].reshape(p, -1)
+            rois, roi_valid, _ = generate_proposals_packed(
+                plane_take(fg, plane_of),
+                jax.lax.stop_gradient(rpn_deltas_rows),  # already per-image
+                im_info,
+                anchors,
+                pre_nms_top_n=cfg.train.rpn_pre_nms_top_n,
+                post_nms_top_n=cfg.train.rpn_post_nms_top_n,
+                nms_thresh=cfg.train.rpn_nms_thresh,
+                min_size=cfg.train.rpn_min_size,
+                topk_impl=cfg.network.proposal_topk,
+            )
+        else:
+            rois, roi_valid, _ = generate_proposals(
+                rpn_prob,
+                jax.lax.stop_gradient(rpn_bbox_deltas),
+                im_info,
+                anchors,
+                pre_nms_top_n=cfg.train.rpn_pre_nms_top_n,
+                post_nms_top_n=cfg.train.rpn_post_nms_top_n,
+                nms_thresh=cfg.train.rpn_nms_thresh,
+                min_size=cfg.train.rpn_min_size,
+                feat_stride=stride,
+                topk_impl=cfg.network.proposal_topk,
+            )
 
     # --- ROI sampling (reference: ProposalTarget op — host numpy there) ---
-    samples = jax.vmap(
-        partial(
-            sample_rois,
-            num_classes=model.num_classes,
-            batch_rois=cfg.train.batch_rois,
-            fg_fraction=cfg.train.fg_fraction,
-            fg_thresh=cfg.train.fg_thresh,
-            bg_thresh_hi=cfg.train.bg_thresh_hi,
-            bg_thresh_lo=cfg.train.bg_thresh_lo_value,
-            bbox_means=cfg.train.bbox_means,
-            bbox_stds=cfg.train.bbox_stds,
-        ),
-    )(rois, roi_valid, gt["gt_boxes"], gt["gt_classes"], gt["gt_valid"],
-      jax.random.split(k_sample, b))
+    with stage("roi_sample"):
+        samples = jax.vmap(
+            partial(
+                sample_rois,
+                num_classes=model.num_classes,
+                batch_rois=cfg.train.batch_rois,
+                fg_fraction=cfg.train.fg_fraction,
+                fg_thresh=cfg.train.fg_thresh,
+                bg_thresh_hi=cfg.train.bg_thresh_hi,
+                bg_thresh_lo=cfg.train.bg_thresh_lo_value,
+                bbox_means=cfg.train.bbox_means,
+                bbox_stds=cfg.train.bbox_stds,
+            ),
+        )(rois, roi_valid, gt["gt_boxes"], gt["gt_classes"], gt["gt_valid"],
+          jax.random.split(k_sample, b))
 
     r = cfg.train.batch_rois
     pooled = _pool_rois(feat, samples.rois, samples.valid,
                         model.roi_pool_size, model.roi_pool_type,
                         plane_of=plane_of, windows=windows)
-    cls_logits, bbox_deltas = model.apply(
-        params, pooled, False, method=FasterRCNN.box_head,
-        rngs={"dropout": k_drop})
+    with stage("box_head"):
+        cls_logits, bbox_deltas = model.apply(
+            params, pooled, False, method=FasterRCNN.box_head,
+            rngs={"dropout": k_drop})
 
-    labels = jnp.where(samples.valid.reshape(-1), samples.labels.reshape(-1), -1)
-    rcnn_l = rcnn_losses(
-        cls_logits,
-        bbox_deltas,
-        labels,
-        samples.bbox_targets.reshape(b * r, -1),
-        samples.bbox_weights.reshape(b * r, -1),
-        cfg.train.batch_rois,
-        b,
-    )
+    with stage("rcnn_loss"):
+        labels = jnp.where(samples.valid.reshape(-1),
+                           samples.labels.reshape(-1), -1)
+        rcnn_l = rcnn_losses(
+            cls_logits,
+            bbox_deltas,
+            labels,
+            samples.bbox_targets.reshape(b * r, -1),
+            samples.bbox_weights.reshape(b * r, -1),
+            cfg.train.batch_rois,
+            b,
+        )
 
     total = (rpn_l["rpn_cls_loss"] + rpn_l["rpn_bbox_loss"]
              + rcnn_l["rcnn_cls_loss"] + rcnn_l["rcnn_bbox_loss"])

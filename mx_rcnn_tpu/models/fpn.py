@@ -38,6 +38,7 @@ from mx_rcnn_tpu.config import Config
 from mx_rcnn_tpu.models.backbones import ResNetStages
 from mx_rcnn_tpu.models.losses import rcnn_losses, rpn_losses
 from mx_rcnn_tpu.models.rpn import RPNHead
+from mx_rcnn_tpu.obs.profile import stage
 from mx_rcnn_tpu.ops.anchors import anchor_grid
 from mx_rcnn_tpu.ops.boxes import bbox_pred, clip_boxes
 from mx_rcnn_tpu.ops.nms import nms_dispatch
@@ -201,7 +202,10 @@ class FPNFasterRCNN(nn.Module):
                 masks=None) -> Dict[int, jnp.ndarray]:
         """masks (graftcanvas): packed-canvas placement masks threaded
         through the backbone stages and the neck (see FPNNeck)."""
-        return self.neck(self.features(images, masks), masks)
+        with stage("backbone"):
+            feats = self.features(images, masks)
+        with stage("neck"):
+            return self.neck(feats, masks)
 
     def rpn_forward(self, pyramid: Dict[int, jnp.ndarray]):
         """Shared RPN over P2..P6 → per-level (cls_logits, bbox_deltas)."""
@@ -527,20 +531,21 @@ def pyramid_roi_align(
     image's own cells (ops/roi_align.py).
     """
     b, r = rois.shape[0], rois.shape[1]
-    ids = (jnp.arange(b, dtype=jnp.float32) if plane_of is None
-           else island(plane_of))
-    batch_idx = jnp.repeat(ids, r)[:, None]
-    flat = jnp.concatenate([batch_idx, rois.reshape(b * r, 4)], axis=1)
-    win = (None if windows is None
-           else jnp.repeat(windows, r, axis=0))  # (B·R, 4)
-    levels = roi_levels(rois.reshape(b * r, 4))
-    out = None
-    for lv in ROI_LEVELS:
-        pooled = roi_align(pyramid[lv], flat, pool_size, 1.0 / (2 ** lv),
-                           windows=win)
-        sel = (levels == lv)[:, None, None, None].astype(pooled.dtype)
-        out = pooled * sel if out is None else out + pooled * sel
-    return out * roi_valid.reshape(b * r, 1, 1, 1).astype(out.dtype)
+    with stage("roi_align"):
+        ids = (jnp.arange(b, dtype=jnp.float32) if plane_of is None
+               else island(plane_of))
+        batch_idx = jnp.repeat(ids, r)[:, None]
+        flat = jnp.concatenate([batch_idx, rois.reshape(b * r, 4)], axis=1)
+        win = (None if windows is None
+               else jnp.repeat(windows, r, axis=0))  # (B·R, 4)
+        levels = roi_levels(rois.reshape(b * r, 4))
+        out = None
+        for lv in ROI_LEVELS:
+            pooled = roi_align(pyramid[lv], flat, pool_size,
+                               1.0 / (2 ** lv), windows=win)
+            sel = (levels == lv)[:, None, None, None].astype(pooled.dtype)
+            out = pooled * sel if out is None else out + pooled * sel
+        return out * roi_valid.reshape(b * r, 1, 1, 1).astype(out.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -553,7 +558,8 @@ def _pyramid_rpn(model: FPNFasterRCNN, params, images, cfg: Config,
     pyramid = model.apply(params, images, masks, method="extract")
     rpn_method = ("rpn_forward_packed" if cfg.network.fpn_packed_rpn_head
                   else "rpn_forward")
-    rpn_out = model.apply(params, pyramid, method=rpn_method)
+    with stage("rpn_head"):
+        rpn_out = model.apply(params, pyramid, method=rpn_method)
     shapes = {lv: (pyramid[lv].shape[1], pyramid[lv].shape[2])
               for lv in RPN_LEVELS}
     anchors = pyramid_anchors(shapes, cfg)
@@ -623,68 +629,75 @@ def forward_train(
         np.concatenate([anchors[lv] for lv in RPN_LEVELS], axis=0))
 
     k_anchor, k_sample, k_dummy = jax.random.split(rng, 3)
-    rpn_t = jax.vmap(
-        partial(
-            assign_anchor,
-            rpn_batch_size=cfg.train.rpn_batch_size,
-            rpn_fg_fraction=cfg.train.rpn_fg_fraction,
-            positive_overlap=cfg.train.rpn_positive_overlap,
-            negative_overlap=cfg.train.rpn_negative_overlap,
-            allowed_border=cfg.train.rpn_allowed_border,
-            clobber_positives=cfg.train.rpn_clobber_positives,
-        ),
-        in_axes=(None, 0, 0, 0, 0),
-    )(anchors_cat, gt_boxes, gt_valid, im_info,
-      jax.random.split(k_anchor, b))
+    with stage("rpn_targets"):
+        rpn_t = jax.vmap(
+            partial(
+                assign_anchor,
+                rpn_batch_size=cfg.train.rpn_batch_size,
+                rpn_fg_fraction=cfg.train.rpn_fg_fraction,
+                positive_overlap=cfg.train.rpn_positive_overlap,
+                negative_overlap=cfg.train.rpn_negative_overlap,
+                allowed_border=cfg.train.rpn_allowed_border,
+                clobber_positives=cfg.train.rpn_clobber_positives,
+            ),
+            in_axes=(None, 0, 0, 0, 0),
+        )(anchors_cat, gt_boxes, gt_valid, im_info,
+          jax.random.split(k_anchor, b))
 
-    rpn_logits, rpn_deltas = _concat_level_outputs(rpn_out, a)
-    if packed:
-        # Per-plane head outputs → per-image rows: each image reads ITS
-        # plane's canvas grid; its labels ignore every out-of-rect anchor.
-        rpn_logits = plane_take(rpn_logits, plane_of)
-        rpn_deltas = plane_take(rpn_deltas, plane_of)
-    rpn_l = rpn_losses(rpn_logits, rpn_deltas, rpn_t.labels,
-                       rpn_t.bbox_targets, rpn_t.bbox_weights,
-                       cfg.train.rpn_batch_size)
+    with stage("rpn_loss"):
+        rpn_logits, rpn_deltas = _concat_level_outputs(rpn_out, a)
+        if packed:
+            # Per-plane head outputs → per-image rows: each image reads ITS
+            # plane's canvas grid; its labels ignore every out-of-rect
+            # anchor.
+            rpn_logits = plane_take(rpn_logits, plane_of)
+            rpn_deltas = plane_take(rpn_deltas, plane_of)
+        rpn_l = rpn_losses(rpn_logits, rpn_deltas, rpn_t.labels,
+                           rpn_t.bbox_targets, rpn_t.bbox_weights,
+                           cfg.train.rpn_batch_size)
 
-    rpn_sg = {lv: (jax.lax.stop_gradient(c), jax.lax.stop_gradient(d))
-              for lv, (c, d) in rpn_out.items()}
-    if packed:
-        rois, roi_valid, _ = fpn_proposals_packed(
-            rpn_sg, anchors, im_info, plane_of, cfg, train=True)
-    else:
-        rois, roi_valid, _ = fpn_proposals(rpn_sg, anchors, im_info, cfg,
-                                           train=True)
+    with stage("proposal"):
+        rpn_sg = {lv: (jax.lax.stop_gradient(c), jax.lax.stop_gradient(d))
+                  for lv, (c, d) in rpn_out.items()}
+        if packed:
+            rois, roi_valid, _ = fpn_proposals_packed(
+                rpn_sg, anchors, im_info, plane_of, cfg, train=True)
+        else:
+            rois, roi_valid, _ = fpn_proposals(rpn_sg, anchors, im_info,
+                                               cfg, train=True)
 
-    samples = jax.vmap(
-        partial(
-            sample_rois,
-            num_classes=model.num_classes,
-            batch_rois=cfg.train.batch_rois,
-            fg_fraction=cfg.train.fg_fraction,
-            fg_thresh=cfg.train.fg_thresh,
-            bg_thresh_hi=cfg.train.bg_thresh_hi,
-            bg_thresh_lo=cfg.train.bg_thresh_lo_value,
-            bbox_means=cfg.train.bbox_means,
-            bbox_stds=cfg.train.bbox_stds,
-        ),
-    )(rois, roi_valid, gt_boxes, gt_classes,
-      gt_valid, jax.random.split(k_sample, b))
+    with stage("roi_sample"):
+        samples = jax.vmap(
+            partial(
+                sample_rois,
+                num_classes=model.num_classes,
+                batch_rois=cfg.train.batch_rois,
+                fg_fraction=cfg.train.fg_fraction,
+                fg_thresh=cfg.train.fg_thresh,
+                bg_thresh_hi=cfg.train.bg_thresh_hi,
+                bg_thresh_lo=cfg.train.bg_thresh_lo_value,
+                bbox_means=cfg.train.bbox_means,
+                bbox_stds=cfg.train.bbox_stds,
+            ),
+        )(rois, roi_valid, gt_boxes, gt_classes,
+          gt_valid, jax.random.split(k_sample, b))
 
     r = cfg.train.batch_rois
     pooled = pyramid_roi_align(pyramid, samples.rois, samples.valid,
                                model.roi_pool_size, plane_of=plane_of,
                                windows=windows)
-    cls_logits, bbox_deltas = model.apply(params, pooled,
-                                          method="box_head")
+    with stage("box_head"):
+        cls_logits, bbox_deltas = model.apply(params, pooled,
+                                              method="box_head")
 
-    labels = jnp.where(samples.valid.reshape(-1),
-                       samples.labels.reshape(-1), -1)
-    rcnn_l = rcnn_losses(
-        cls_logits, bbox_deltas, labels,
-        samples.bbox_targets.reshape(b * r, -1),
-        samples.bbox_weights.reshape(b * r, -1),
-        cfg.train.batch_rois, b)
+    with stage("rcnn_loss"):
+        labels = jnp.where(samples.valid.reshape(-1),
+                           samples.labels.reshape(-1), -1)
+        rcnn_l = rcnn_losses(
+            cls_logits, bbox_deltas, labels,
+            samples.bbox_targets.reshape(b * r, -1),
+            samples.bbox_weights.reshape(b * r, -1),
+            cfg.train.batch_rois, b)
 
     total = (rpn_l["rpn_cls_loss"] + rpn_l["rpn_bbox_loss"]
              + rcnn_l["rcnn_cls_loss"] + rcnn_l["rcnn_bbox_loss"])

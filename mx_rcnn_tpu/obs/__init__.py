@@ -6,7 +6,14 @@ graftscope adds the machine-readable layer underneath it:
 - ``events``:        typed append-only JSONL event stream (EventLog /
                      NullEventLog; schema = EVENT_TYPES)
 - ``timing``:        StepTimer — per-iteration data-wait / dispatch /
-                     step split, no host syncs added
+                     step split (``place_ms`` and ``enqueue_ms`` inside
+                     the dispatch), no host syncs added; owner of the
+                     loop's spans on the profiler's clock
+                     (``LOOP_SPANS``: ``train.next_batch``, a ``train``
+                     step annotation holding ``train.key`` /
+                     ``train.place`` / ``train.enqueue`` /
+                     ``train.metrics``, and
+                     ``train.checkpoint``)
 - ``compile_track``: every XLA compile becomes a ``compile`` event with
                      the triggering batch-shape signature
 - ``watchdog``:      StallWatchdog — a hung run emits a ``stall`` event
@@ -19,9 +26,12 @@ graftprof (this layer's profiling/cost pass) adds:
 - ``costs``:         XLA ``cost_analysis``/``memory_analysis`` per
                      compiled shape bucket → ``cost`` events, computed
                      MFU, HBM footprint, padding-waste accounting
-- ``profile``:       programmatic jax.profiler capture windows
-                     (``obs.trace_at_step``; stall-armed) + a coarse
-                     trace summarizer → ``trace`` events
+- ``profile``:       ``STAGES`` / ``stage`` — the step program's
+                     ``jax.named_scope`` names (backbone … update);
+                     programmatic jax.profiler capture windows
+                     (``obs.trace_at_step``; stall-armed) folded by
+                     stage → ``trace`` events carrying ``stages``
+                     ({stage: device ms}) and ``unscoped_ms``
 - ``ledger``:        ``python -m mx_rcnn_tpu.obs.ledger`` — append-only
                      cross-run perf history (bench_obs/history.jsonl) with a
                      regression-gating ``check`` subcommand

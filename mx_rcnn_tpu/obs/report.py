@@ -412,9 +412,11 @@ def render(summary: Dict[str, Any]) -> str:
                      f"pixels (p50) | canvas util "
                      f"{1.0 - summary['pad_waste']:.1%}")
     for t in summary.get("traces", ()):
-        ph = (t.get("summary") or {}).get("phases")
+        folded = t.get("summary") or {}
         lines.append(f"  trace:      [{t.get('reason')}] {t.get('dir')}"
-                     + (f" phases(ms)={ph}" if ph else ""))
+                     + (f" stages(ms)={folded['stages']} "
+                        f"unscoped(ms)={folded.get('unscoped_ms')}"
+                        if folded.get("stages") else ""))
     be = summary.get("backend", {})
     if be.get("retries"):
         lines.append(

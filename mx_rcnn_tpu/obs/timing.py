@@ -20,15 +20,76 @@ The split follows the loop's own structure (tools/train.py::fit_detector):
                   drain still happens — at Speedometer log boundaries —
                   so windowed step_ms is honest end-to-end time.
 
-When the sink is disabled, ``iterate`` degrades to ``enumerate`` and
-``dispatched()`` to one attribute check: zero events, zero allocations.
+  place_ms      — inside dispatch: the ``train.place`` span
+                  (``shard_batch``, the host→device placement of the
+                  batch). Grows with the batch's bytes.
+  enqueue_ms    — inside dispatch: the ``train.enqueue`` span (the
+                  ``step_fn`` call): the host's cost of launching the
+                  step. Read on the chip (PR 25): 7-10 ms, and it does
+                  NOT absorb back-pressure - with the device's queue
+                  full the loop blocks in the iteration's first device
+                  dispatch, the rng key's ``fold_in`` (``train.key``),
+                  so ``dispatch_ms - place_ms - enqueue_ms`` is the key
+                  plus the wait for the device.
+
+The timer also OWNS the loop's spans on the profiler's clock
+(``jax.profiler.TraceAnnotation``): they land in the same xplane as the
+device ops, whoever started the profiler, so a device-idle gap can be
+laid to the phase the loop thread was in. ``iterate`` wraps the loader's
+``next()`` in ``train.next_batch`` and the rest of the iteration in a
+``StepTraceAnnotation`` named ``train`` whose ``step_num`` is the
+``step`` of that iteration's ``step`` event; the loop body marks its
+phases with ``timer.span(name)``, ``name`` one of ``LOOP_SPANS``.
+
+When the sink is disabled, ``iterate`` degrades to ``enumerate``,
+``span()`` to one shared null context and ``dispatched()`` to one
+attribute check: zero events, zero annotations, zero allocations.
 """
 
 from __future__ import annotations
 
+import contextlib
 import time
 
 from mx_rcnn_tpu.obs.events import EventLog
+
+#: the step annotation (``StepTraceAnnotation``): one per dispatch, from
+#: batch-in-hand to the end of the iteration
+STEP_SPAN = "train"
+#: the loop's phases, the one closed list ``span`` accepts:
+#: ``next_batch`` (``iterate``'s own: blocked in the loader), ``key`` (the
+#: dispatch's rng key, ``fold_in``: two tiny device programs, the
+#: iteration's first dispatch and so the one that blocks while the device's
+#: queue is full - back-pressure lands here, not in ``enqueue``), ``place``
+#: (``shard_batch``), ``enqueue`` (the ``step_fn`` call), ``metrics``
+#: (``bag.update`` + Speedometer, which holds the every-``frequent``-steps
+#: host sync), ``checkpoint`` (the epoch-end save)
+LOOP_SPANS = ("train.next_batch", "train.key", "train.place",
+              "train.enqueue", "train.metrics", "train.checkpoint")
+_NO_SPAN = contextlib.nullcontext()
+
+
+class _Span:
+    """One loop phase: a ``TraceAnnotation`` on the profiler's clock, and
+    its duration kept for the iteration's ``step`` event."""
+
+    __slots__ = ("spent", "name", "annotation", "t0")
+
+    def __init__(self, spent: dict, name: str):
+        import jax.profiler
+
+        self.spent, self.name = spent, name
+        self.annotation = jax.profiler.TraceAnnotation(name)
+
+    def __enter__(self):
+        self.t0 = time.perf_counter()
+        self.annotation.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self.annotation.__exit__(*exc)
+        self.spent[self.name] = (self.spent.get(self.name, 0.0)
+                                 + time.perf_counter() - self.t0)
 
 
 class StepTimer:
@@ -39,8 +100,15 @@ class StepTimer:
         timer = StepTimer(event_log, watchdog=watchdog)
         for i, batch in timer.iterate(epoch, batches):
             state, metrics = step_fn(state, batch, key)
+            with timer.span("train.key"):
+                key = jax.random.fold_in(rng, i)
+            with timer.span("train.place"):
+                sharded = shard_batch(batch, mesh)
+            with timer.span("train.enqueue"):
+                state, metrics = step_fn(state, sharded, key)
             timer.dispatched()          # marks the dispatch boundary
-            ...                          # metrics/callbacks
+            with timer.span("train.metrics"):
+                ...                      # metrics/callbacks
 
     Also drives the stall watchdog (one ``beat`` per completed iteration,
     carrying the iteration duration for the trailing-median threshold)
@@ -60,6 +128,18 @@ class StepTimer:
         self.enrich = enrich
         self.total_steps = 0
         self._t_dispatch = None
+        self._spent = {}  # span name -> seconds, this iteration
+
+    def span(self, name: str):
+        """A context manager for one phase of the loop body (``name`` in
+        ``LOOP_SPANS``): a profiler annotation whose duration also lands
+        in the iteration's ``step`` event. The shared null context when
+        the sink is disabled."""
+        if not self.log.enabled:
+            return _NO_SPAN
+        if name not in LOOP_SPANS:
+            raise ValueError(f"{name!r} is not one of LOOP_SPANS")
+        return _Span(self._spent, name)
 
     def dispatched(self):
         """Record the train-step return time (the dispatch boundary)."""
@@ -75,6 +155,8 @@ class StepTimer:
         if not self.log.enabled:
             yield from enumerate(batches, start)
             return
+        import jax.profiler
+
         from mx_rcnn_tpu.obs import compile_track
 
         it = iter(batches)
@@ -87,7 +169,8 @@ class StepTimer:
                 # input plane), not dispatch (the device queue).
                 self.watchdog.note_phase("data_wait")
             try:
-                batch = next(it)
+                with jax.profiler.TraceAnnotation(LOOP_SPANS[0]):
+                    batch = next(it)
             except StopIteration:
                 return
             t1 = time.perf_counter()
@@ -96,7 +179,10 @@ class StepTimer:
             if self.track_shapes:
                 compile_track.note_batch(batch)
             self._t_dispatch = None
-            yield i, batch
+            self._spent.clear()
+            with jax.profiler.StepTraceAnnotation(
+                    STEP_SPAN, step_num=self.total_steps + 1):
+                yield i, batch
             t2 = time.perf_counter()
             self.total_steps += 1
             self.log.set_step(self.total_steps)
@@ -110,6 +196,10 @@ class StepTimer:
             if self._t_dispatch is not None:
                 fields["dispatch_ms"] = round(
                     (self._t_dispatch - t1) * 1e3, 3)
+            for name, field in (("train.place", "place_ms"),
+                                ("train.enqueue", "enqueue_ms")):
+                if name in self._spent:
+                    fields[field] = round(self._spent[name] * 1e3, 3)
             if self.enrich is not None:
                 fields.update(self.enrich(batch) or {})
             self.log.emit("step", **fields)

@@ -43,6 +43,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 BLOCK = 128
+#: the kernel's name in a compiled program and in a profiler trace, on one
+#: chip and under a ``shard_map`` alike
+KERNEL_NAME = "nms_sweep"
 
 
 def _iou_tile(x1i, y1i, x2i, y2i, cols):
@@ -172,6 +175,7 @@ def nms_keep_sorted(boxes: jnp.ndarray, valid: jnp.ndarray,
         ],
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_vmem_limit(n_pad)),
         interpret=interpret,
+        name=KERNEL_NAME,
     )(rows, cols, cols, vmask, vmask)
     return keep[:, 0, :n] > 0.0
 

@@ -187,15 +187,17 @@ def main(argv=None):
                 state, metrics = run_step(state, batch, k)
             jax.block_until_ready(metrics["TotalLoss"])
         print(f"trace written to {args.trace_dir}")
-        # graftprof: fold the capture into the coarse phase breakdown
+        # graftprof: fold the capture by the step's named stages
         # (obs/profile.py) so the split is readable without TensorBoard.
         from mx_rcnn_tpu.obs.profile import summarize_trace
 
         summary = summarize_trace(args.trace_dir)
         if summary:
-            print("trace phases (ms): "
+            print("trace stages (ms): "
                   + ", ".join(f"{k}={v}"
-                              for k, v in summary["phases"].items()))
+                              for k, v in summary["stages"].items())
+                  + f"; unscoped={summary['unscoped_ms']} of "
+                  f"{summary['total_ms']}")
             if elog is not None and elog.enabled:
                 elog.emit("trace", dir=args.trace_dir, reason="manual",
                           summary=summary)

@@ -948,10 +948,15 @@ def fit_detector(
                                 "train_dispatch",
                                 step=(epoch * steps_per_epoch
                                       + (i + 1) * multi))
-                        k = jax.random.fold_in(  # graftlint: disable=prng-key-reuse — the root is folded with a DISTINCT global dispatch index each iteration (the resumable-key derivation; see the rng comment above)
-                            rng, epoch * disp_per_epoch + i)
-                        sharded = shard_batch(batch, mesh,
-                                              stacked=multi > 1)
+                        with timer.span("train.key"):
+                            # the iteration's first device dispatch: with
+                            # the device's queue full it is HERE that the
+                            # loop blocks (read on the chip, PR 25)
+                            k = jax.random.fold_in(  # graftlint: disable=prng-key-reuse — the root is folded with a DISTINCT global dispatch index each iteration (the resumable-key derivation; see the rng comment above)
+                                rng, epoch * disp_per_epoch + i)
+                        with timer.span("train.place"):
+                            sharded = shard_batch(batch, mesh,
+                                                  stacked=multi > 1)
                         if cost_tracker is not None:
                             # One AOT cost capture per compiled shape
                             # bucket (dict lookup otherwise) — the
@@ -962,15 +967,19 @@ def fit_detector(
                             # Pre-dispatch arming: the window must
                             # INCLUDE step trace_at_step (even step 1).
                             tracer.before_step(timer.total_steps + 1)
-                        if health_on:
-                            state, metrics, pulse = step_fn(state, sharded,
-                                                            k)
-                        else:
-                            state, metrics = step_fn(state, sharded, k)
+                        with timer.span("train.enqueue"):
+                            if health_on:
+                                state, metrics, pulse = step_fn(
+                                    state, sharded, k)
+                            else:
+                                state, metrics = step_fn(state, sharded, k)
                         pos = (epoch, i + 1)
                         timer.dispatched()
-                        bag.update(metrics)
-                        speedometer(epoch, i, bag)
+                        with timer.span("train.metrics"):
+                            # Speedometer holds the loop's one host sync,
+                            # every `frequent` dispatches
+                            bag.update(metrics)
+                            speedometer(epoch, i, bag)
                         if tracer is not None:
                             # timer.total_steps increments when the
                             # generator resumes — this dispatch is the
@@ -1083,11 +1092,12 @@ def fit_detector(
                                                      state.opt_state)
                         save = (writer.save if writer is not None
                                 else save_checkpoint)
-                        save(prefix, epoch + 1, save_params, save_opt,
-                             means=cfg.train.bbox_means,
-                             stds=cfg.train.bbox_stds,
-                             num_classes=cfg.dataset.num_classes,
-                             meta=_ckpt_meta(epoch + 1, None))
+                        with timer.span("train.checkpoint"):
+                            save(prefix, epoch + 1, save_params, save_opt,
+                                 means=cfg.train.bbox_means,
+                                 stds=cfg.train.bbox_stds,
+                                 num_classes=cfg.dataset.num_classes,
+                                 meta=_ckpt_meta(epoch + 1, None))
                         epoch_saved = True
                         if obs_log.enabled:
                             obs_log.emit("checkpoint", epoch=epoch + 1,

@@ -23,6 +23,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from mx_rcnn_tpu.config import Config
 from mx_rcnn_tpu.models.faster_rcnn import FasterRCNN, forward_train
+from mx_rcnn_tpu.obs.profile import stage
 from mx_rcnn_tpu.resilience import chaos
 from mx_rcnn_tpu.train import health as health_mod
 
@@ -273,7 +274,8 @@ def make_train_step(
             # chaos nan_at_step: poison the FINAL gradients (post accum
             # fold / cast-up) of the armed optimizer step, in-graph.
             grads = chaos.poison_grads(grads, state.step, nan_at)
-        new_state = state.apply_gradients(grads)
+        with stage("update"):  # tree: tx.update + apply_updates; flat: core.apply
+            new_state = state.apply_gradients(grads)
         if not health:
             return new_state, parts
         num, den = parts["TotalLoss"]

@@ -16,8 +16,11 @@ chip's compiler can refuse is held by tier-1 at no chip time:
   ``make_train_step`` exactly as ``fit_detector`` builds it.
 
 Each asserts ``tpu_custom_call`` in the compiled program: the kernel is in
-it, not its jnp stand-in. Nothing runs — a compile that passes is not a chip
-run. The interpret-mode parity tests are tests/test_nms.py.
+it, not its jnp stand-in; the train steps also hold it under the name a
+trace shows it by (``nms_pallas.KERNEL_NAME``), inside the ``proposal``
+stage, on one chip and under the ``shard_map`` alike. Nothing runs — a
+compile that passes is not a chip run. The interpret-mode parity tests are
+tests/test_nms.py.
 
 Ground rules of this file (guide §2): the topology is described inside a
 module-scoped fixture that skips when it cannot be, never while a module is
@@ -141,24 +144,55 @@ def test_kernel_partitions_over_a_data_mesh(data_mesh):
         bare.lower(*_nms_args(4, 12000, sharded)).compile()
 
 
-def test_data_parallel_train_step_compiles_with_the_kernel(data_mesh,
-                                                           monkeypatch):
-    """make_train_step over the 4-device mesh, as fit_detector builds it
-    (tiny widths — the R-101 step takes minutes and is chip_smoke.py's):
-    the Pallas NMS is in the partitioned program, and so is the gradient
-    all-reduce."""
+def _tiny_step_hlo(mesh, n_images):
     from mx_rcnn_tpu.config import generate_config
     from mx_rcnn_tpu.models.faster_rcnn import build_model
     from mx_rcnn_tpu.train.step import abstract_step_inputs, make_train_step
 
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = generate_config("resnet50", "synthetic", **{
         "train.rpn_pre_nms_top_n": 256, "train.rpn_post_nms_top_n": 64,
         "train.batch_rois": 32, "train.max_gt_boxes": 8,
         "network.anchor_scales": (2, 4, 8), "image.pad_shape": (128, 128)})
     model = build_model(cfg)
-    compiled = make_train_step(model, cfg, mesh=data_mesh).lower(
-        *abstract_step_inputs(model, cfg, data_mesh, 4)).compile()
-    hlo = compiled.as_text()
+    return make_train_step(model, cfg, mesh=mesh).lower(
+        *abstract_step_inputs(model, cfg, mesh, n_images)).compile().as_text()
+
+
+def _assert_kernel_named_in_its_stage(hlo):
+    """The program's Mosaic calls are instructions named after the kernel,
+    and their scope paths (``op_name``) lie in the ``proposal`` stage."""
+    import re
+
+    from mx_rcnn_tpu.obs.profile import stage_of
+
+    paths = re.findall(
+        rf'%{nms_pallas.KERNEL_NAME}[\w.]* = [^\n]*{KERNEL}[^\n]*'
+        r'op_name="([^"]*)"', hlo)
+    assert paths, "no Mosaic call named after the kernel"
+    assert all(f"/{nms_pallas.KERNEL_NAME}/" in p
+               and stage_of(p) == "proposal" for p in paths), paths
+
+
+def test_data_parallel_train_step_compiles_with_the_kernel(data_mesh,
+                                                           monkeypatch):
+    """make_train_step over the 4-device mesh, as fit_detector builds it
+    (tiny widths — the R-101 step takes minutes and is chip_smoke.py's):
+    the Pallas NMS is in the partitioned program under its own name, and
+    so is the gradient all-reduce."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    hlo = _tiny_step_hlo(data_mesh, 4)
     assert KERNEL in hlo
     assert "all-reduce" in hlo
+    _assert_kernel_named_in_its_stage(hlo)
+
+
+def test_one_chip_train_step_names_the_kernel(topo, monkeypatch):
+    """The same step on a mesh of one described chip (no ``shard_map``
+    around the kernel, no collective): the trace will show the kernel as
+    ``nms_sweep`` there too."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.asarray(topo.devices[:1]).reshape(1, 1),
+                ("data", "model"))
+    hlo = _tiny_step_hlo(mesh, 1)
+    assert KERNEL in hlo and "all-reduce" not in hlo
+    _assert_kernel_named_in_its_stage(hlo)

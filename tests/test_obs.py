@@ -181,6 +181,100 @@ def test_step_timer_disabled_is_passthrough_and_lazy():
     assert timer.total_steps == 0
 
 
+def _host_events(trace_dir):
+    """[(thread, name, start_ns, end_ns, stats)] of the host plane."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    path, = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    return [(line.name, e.name, e.start_ns, e.start_ns + e.duration_ns,
+             dict(e.stats))
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events]
+
+
+def test_step_timer_spans_land_on_the_profilers_clock(tmp_path):
+    """Under a profiler trace the host plane holds the loop's phases:
+    ``train.next_batch`` between the step annotations, ``train.key`` /
+    ``train.place`` / ``train.enqueue`` / ``train.metrics`` inside them,
+    on one thread,
+    with ``step_num`` rising as the step events' ``step`` does; the same
+    clock reads put ``place_ms`` and ``enqueue_ms`` into the event."""
+    import jax
+
+    from mx_rcnn_tpu.obs.timing import LOOP_SPANS, STEP_SPAN
+
+    log = open_event_log(str(tmp_path / "obs"))
+    timer = StepTimer(log)
+    jax.profiler.start_trace(str(tmp_path / "trace"))
+    try:
+        for epoch in range(2):  # the counter runs on across epochs
+            for _ in timer.iterate(epoch, _slow_loader(2, wait_s=0.005)):
+                with timer.span("train.key"):
+                    pass
+                with timer.span("train.place"):
+                    time.sleep(0.02)
+                with timer.span("train.enqueue"):
+                    time.sleep(0.01)
+                timer.dispatched()
+                with timer.span("train.metrics"):
+                    time.sleep(0.002)
+            with timer.span("train.checkpoint"):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    log.close()
+
+    ours = [e for e in _host_events(str(tmp_path / "trace"))
+            if e[1] == STEP_SPAN or e[1] in LOOP_SPANS]
+    assert len({e[0] for e in ours}) == 1  # the loop thread
+    steps = sorted((e for e in ours if e[1] == STEP_SPAN),
+                   key=lambda e: e[2])
+    assert [e[4]["step_num"] for e in steps] == [1, 2, 3, 4]
+    inside = {name: [e for e in ours if e[1] == name] for name in LOOP_SPANS}
+    # 2 x (2 batches + the exhausted next()), 4 bodies, 2 epoch ends
+    assert [len(inside[n]) for n in LOOP_SPANS] == [6, 4, 4, 4, 4, 2]
+    for name in LOOP_SPANS[1:5]:
+        for e, st in zip(sorted(inside[name], key=lambda e: e[2]), steps):
+            assert st[2] <= e[2] and e[3] <= st[3]  # nested in its step
+    for e in inside["train.next_batch"] + inside["train.checkpoint"]:
+        assert not any(st[2] < e[3] and e[2] < st[3] for st in steps)
+
+    events = [e for e in report.load_events(str(tmp_path / "obs"))
+              if e["type"] == "step"]
+    assert [e["step"] for e in events] == [1, 2, 3, 4]
+    for e in events:
+        assert e["place_ms"] >= 18.0 and e["enqueue_ms"] >= 8.0
+        assert e["place_ms"] + e["enqueue_ms"] <= e["dispatch_ms"] + 0.5
+    with pytest.raises(ValueError, match="LOOP_SPANS"):
+        timer.span("train.lunch")
+
+
+def test_step_timer_disabled_makes_no_annotation(monkeypatch):
+    """With the null sink the loop creates no annotation object at all:
+    ``span()`` hands out ONE shared null context whatever the name, and
+    ``iterate`` never reaches the profiler."""
+    import jax.profiler
+
+    def boom(*a, **k):
+        raise AssertionError("an annotation was created with obs off")
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", boom)
+    monkeypatch.setattr(jax.profiler, "StepTraceAnnotation", boom)
+    timer = StepTimer(NullEventLog())
+    null = timer.span("train.place")
+    assert timer.span("train.enqueue") is null
+    assert timer.span("not even a span's name") is null
+    for i, batch in timer.iterate(0, [{"x": 1}, {"x": 2}], start=3):
+        with timer.span("train.place"), timer.span("train.enqueue"):
+            pass
+        timer.dispatched()
+    assert i == 4 and timer.total_steps == 0
+
+
 # ---------------------------------------------------------------------------
 # Speedometer emission
 # ---------------------------------------------------------------------------
@@ -356,16 +450,25 @@ def test_loader_pad_waste_counters():
 def test_trace_controller_step_window(tmp_path):
     """obs.trace_at_step semantics: the window opens before step K,
     closes trace_steps completed steps later, and the closed window
-    emits a `trace` event with the coarse phase summary."""
+    emits a `trace` event with the per-stage summary: the scope paths
+    come out of the compiled program the capture carries, forward and
+    backward ops alike."""
     import jax
     import jax.numpy as jnp
 
-    from mx_rcnn_tpu.obs.profile import TraceController, summarize_trace
+    from mx_rcnn_tpu.obs.profile import (STAGES, TraceController, stage,
+                                         summarize_trace)
 
     log = open_event_log(str(tmp_path))
     tc = TraceController(log, str(tmp_path / "trace"),
                          trace_at_step=2, trace_steps=1)
-    f = jax.jit(lambda x: x @ x)
+    def two_stages(x):
+        with stage("backbone"):
+            y = x @ x
+        with stage("update"):
+            return jnp.tanh(y) @ y
+
+    f = jax.jit(jax.grad(lambda x: two_stages(x).sum()))
     x = jnp.ones((64, 64))
     for step in range(1, 5):
         tc.before_step(step)  # window opens BEFORE step K, so K=1 works
@@ -379,13 +482,76 @@ def test_trace_controller_step_window(tmp_path):
     assert traces[0]["reason"] == "step 2"
     summary = traces[0]["summary"]
     assert summary is not None and summary["events"] > 0
-    assert summary["total_ms"] >= 0
-    assert set(summary["phases"]) <= {"forward", "backward", "update",
-                                      "host", "infra"}
+    assert set(summary) == {"file", "events", "total_ms", "stages",
+                            "unscoped_ms", "top_ops"}
+    assert set(summary["stages"]) == {"backbone", "update"} <= set(STAGES)
+    assert min(summary["stages"].values()) > 0
+    assert summary["total_ms"] == pytest.approx(
+        sum(summary["stages"].values()) + summary["unscoped_ms"], abs=0.01)
+    assert summary["top_ops"] and summary["top_ops"][0]["ms"] > 0
     # the summarizer is reusable on the saved dir, and honest about
     # a dir with no capture
     assert summarize_trace(traces[0]["dir"]) is not None
     assert summarize_trace(str(tmp_path / "nowhere")) is None
+
+
+def test_stage_of_reads_the_innermost_stage_of_a_scope_path():
+    from mx_rcnn_tpu.obs.profile import STAGES, stage, stage_of
+
+    assert stage_of("jit(step)/jvp(roi_align)/dot_general") == "roi_align"
+    assert stage_of(
+        "jit(step)/transpose(jvp(roi_align))/dot_general") == "roi_align"
+    assert stage_of("jit(step)/transpose(jvp(FPNFasterRCNN.extract))/"
+                    "neck/neck/select_and_scatter_add") == "neck"
+    assert stage_of("jit(step)/jvp(proposal)/shard_map/nms_sweep") == \
+        "proposal"
+    assert stage_of("jit(step)/jvp(backbone)/neck/conv") == "neck"
+    # a stage is a whole segment: neither a flax method's name nor a
+    # primitive's is one
+    assert stage_of("jit(step)/jvp(FasterRCNN.box_head)/dot_general") is None
+    assert stage_of("jit(step)/jvp()/dynamic_update_slice") is None
+    assert stage_of("jit(step)/dynamic-update-slice.3") is None
+    assert stage_of("") is None
+    assert len(set(STAGES)) == len(STAGES) == 11
+    with pytest.raises(ValueError, match="STAGES"):
+        stage("backward")
+
+
+@pytest.mark.compile_heavy
+@pytest.mark.parametrize("network", ["resnet50", "resnet50_fpn"])
+def test_lowered_train_step_carries_every_stage(network):
+    """The tiny C4 and FPN train steps, lowered as fit_detector builds
+    them, name every stage the family uses in their ops' metadata:
+    forward (``jvp(stage)``), backward (``transpose(jvp(stage))``) where
+    gradients flow, and the optimizer's ``update``. A refactor that drops
+    a scope fails here, not on the chip."""
+    import re
+
+    from mx_rcnn_tpu.models.zoo import build_model, forward_train
+    from mx_rcnn_tpu.obs.profile import STAGES, stage_of
+    from mx_rcnn_tpu.parallel.mesh import create_mesh
+    from mx_rcnn_tpu.train.step import abstract_step_inputs, make_train_step
+
+    cfg = generate_config(network, "synthetic", **{
+        "train.rpn_pre_nms_top_n": 256, "train.rpn_post_nms_top_n": 64,
+        "train.batch_rois": 32, "train.max_gt_boxes": 8,
+        "image.pad_shape": (128, 128)})
+    model, mesh = build_model(cfg), create_mesh("1")
+    text = make_train_step(
+        model, cfg, mesh=mesh, forward_fn=forward_train).lower(
+            *abstract_step_inputs(model, cfg, mesh, 1)).as_text(
+                debug_info=True)
+    paths = set(re.findall(r'loc\("(jit\(step\)[^"]*)"', text))
+    forward = {stage_of(p) for p in paths if "transpose(" not in p}
+    backward = {stage_of(p) for p in paths if "transpose(jvp(" in p}
+    used = set(STAGES) - ({"neck"} if network == "resnet50" else set())
+    assert forward - {None} == used
+    # no gradient flows through the targets, the proposals, the sampling
+    # or the update itself
+    assert backward - {None} == used - {
+        "rpn_targets", "proposal", "roi_sample", "update"}
+    # the update is no part of the differentiated function
+    assert any(p.startswith("jit(step)/update/") for p in paths)
 
 
 def test_watchdog_stall_arms_trace_window(tmp_path):
@@ -489,7 +655,7 @@ def _synthetic_events():
         mk("step", step=4, epoch=0, batch=3, data_wait_ms=2.0,
            step_ms=40.0, canvas=[8, 8], pad_waste=0.35),
         mk("trace", dir="obs/trace/step2", reason="step 2",
-           summary={"phases": {"forward": 9.0, "host": 1.0},
+           summary={"stages": {"backbone": 9.0}, "unscoped_ms": 1.0,
                     "total_ms": 10.0, "events": 4, "top_ops": []}),
         mk("epoch", epoch=0, metrics={"TotalLoss": 1.0}, pad_waste=0.25),
         mk("checkpoint", epoch=1, prefix="p"),
@@ -526,7 +692,9 @@ def test_report_aggregates_synthetic_log():
     assert s["cost"]["hbm_bytes"] == 2e9
     assert s["pad_waste"] == pytest.approx(0.25)  # p50 of the step events
     assert s["traces"][0]["reason"] == "step 2"
-    assert s["traces"][0]["summary"]["phases"]["forward"] == 9.0
+    assert s["traces"][0]["summary"]["stages"]["backbone"] == 9.0
+    assert "stages(ms)={'backbone': 9.0} unscoped(ms)=1.0" in \
+        report.render(s)
     blob = report.bench_blob(s)
     assert blob["value"] == 150.0 and blob["compile_count"] == 2
     assert blob["stall_count"] == 1
