@@ -33,6 +33,7 @@ from mx_rcnn_tpu.models.rpn import RPNHead
 from mx_rcnn_tpu.obs.profile import stage
 from mx_rcnn_tpu.ops.anchors import anchor_grid
 from mx_rcnn_tpu.ops.boxes import bbox_pred, clip_boxes
+from mx_rcnn_tpu.ops.canvas import rois_by_plane
 from mx_rcnn_tpu.ops.proposal import generate_proposals
 from mx_rcnn_tpu.ops.roi_align import roi_align, roi_pool
 from mx_rcnn_tpu.targets.rcnn_targets import sample_rois
@@ -111,7 +112,7 @@ class FasterRCNN(nn.Module):
         """Init-only path touching every submodule."""
         feat = self.extract(images)
         rpn_cls, rpn_box = self.rpn_forward(feat)
-        pooled = roi_align(feat, rois, self.roi_pool_size, 1.0 / 16.0)
+        pooled = roi_align(feat, rois, self.roi_pool_size, 1.0 / 16.0)[0]
         cls, box = self.box_head(pooled)
         return feat, rpn_cls, rpn_box, cls, box
 
@@ -145,28 +146,30 @@ def _pair_logits(cls_logits: jnp.ndarray, num_anchors: int) -> jnp.ndarray:
     return jnp.stack([bg, fg], axis=-1)
 
 
-def _pool_rois(feat, rois, roi_valid, pool_size, pool_type,
-               plane_of=None, windows=None):
+def _pool_rois(feat, rois, roi_valid, pool_size, pool_type, windows=None):
     """Batched ROI pooling: (B,Hf,Wf,C) + (B,R,4) → (B·R,P,P,C).
 
-    Builds the (batch_idx, x1..y2) 5-vector layout the pooling ops share with
-    the reference's ROIPooling input convention.
+    The rois stay grouped by image through the op (ops/roi_align.py); only
+    the quantized `roi_pool` still takes the reference's ROIPooling layout,
+    (batch_idx, x1..y2) rows.
 
-    graftcanvas: on a packed batch `feat` holds PLANES — `plane_of` (B,)
-    maps each image row to its plane, `windows` (B, 4) [y0, x0, h, w]
-    clamps border samples to the image's own cells (ops/roi_align.py).
+    graftcanvas: on a packed batch `feat` holds PLANES, I images each in
+    row order (ops/canvas.py::rois_by_plane), and `windows` (B, 4)
+    [y0, x0, h, w] clamps border samples to the image's own cells.
     """
     b, r = rois.shape[0], rois.shape[1]
+    planes = feat.shape[0]
     with stage("roi_align"):
-        ids = (jnp.arange(b, dtype=jnp.float32) if plane_of is None
-               else island(plane_of))
-        batch_idx = jnp.repeat(ids, r)[:, None]
-        flat = jnp.concatenate([batch_idx, rois.reshape(b * r, 4)], axis=1)
         if pool_type == "align":
-            win = None if windows is None else jnp.repeat(windows, r, axis=0)
-            pooled = roi_align(feat, flat, pool_size, 1.0 / 16.0,
+            grouped, win = rois_by_plane(planes, rois, windows)
+            pooled = roi_align(feat, grouped, pool_size, 1.0 / 16.0,
                                windows=win)
+            pooled = pooled.reshape(b * r, *pooled.shape[2:])
         else:
+            plane_idx = jnp.repeat(
+                jnp.arange(planes, dtype=jnp.float32), b * r // planes)
+            flat = jnp.concatenate(
+                [plane_idx[:, None], rois.reshape(b * r, 4)], axis=1)
             pooled = roi_pool(feat, flat, pool_size, 1.0 / 16.0)
         # Zero padded slots so dead rois contribute nothing downstream.
         return pooled * roi_valid.reshape(b * r, 1, 1, 1).astype(
@@ -331,7 +334,7 @@ def forward_train(
     r = cfg.train.batch_rois
     pooled = _pool_rois(feat, samples.rois, samples.valid,
                         model.roi_pool_size, model.roi_pool_type,
-                        plane_of=plane_of, windows=windows)
+                        windows=windows)
     with stage("box_head"):
         cls_logits, bbox_deltas = model.apply(
             params, pooled, False, method=FasterRCNN.box_head,
@@ -568,5 +571,5 @@ def init_params(model: FasterRCNN, cfg: Config, rng: jax.Array,
     convs make the real padded shape unnecessary at init)."""
     h, w = image_shape or (64, 64)
     images = jnp.zeros((1, h, w, 3), jnp.float32)
-    rois = jnp.asarray([[0.0, 0.0, 0.0, 31.0, 31.0]], jnp.float32)
+    rois = jnp.asarray([[[0.0, 0.0, 31.0, 31.0]]], jnp.float32)
     return model.init(rng, images, rois)

@@ -20,8 +20,9 @@ per-level budget), NMS'd within each level, and the top post_nms of the
 score-ranked union is taken (Detectron-lineage semantics; the joint
 union-NMS variant stays available via fpn_nms_per_level=False) — every
 shape is compile-time fixed either way.
-ROI-to-level assignment computes the cheap matmul pool on EVERY level and
-selects by mask (4 levels × a 13 GFLOP/step op beats any dynamic gather).
+ROI-to-level assignment computes the matmul pool on EVERY level and selects
+by mask: static shapes at 4x the pooling; its cost on the chip is unmeasured
+(pyramid_roi_align).
 """
 
 from __future__ import annotations
@@ -41,6 +42,7 @@ from mx_rcnn_tpu.models.rpn import RPNHead
 from mx_rcnn_tpu.obs.profile import stage
 from mx_rcnn_tpu.ops.anchors import anchor_grid
 from mx_rcnn_tpu.ops.boxes import bbox_pred, clip_boxes
+from mx_rcnn_tpu.ops.canvas import rois_by_plane
 from mx_rcnn_tpu.ops.nms import nms_dispatch
 from mx_rcnn_tpu.ops.proposal import _decode_one_image
 from mx_rcnn_tpu.ops.roi_align import roi_align
@@ -238,12 +240,12 @@ class FPNFasterRCNN(nn.Module):
         """Init-only path touching every submodule."""
         pyramid = self.extract(images)
         rpn_out = self.rpn_forward(pyramid)
-        pooled = roi_align(pyramid[2], rois, self.roi_pool_size, 1.0 / 4.0)
+        pooled = roi_align(pyramid[2], rois, self.roi_pool_size, 1.0 / 4.0)[0]
         cls, box = self.box_head(pooled)
         outs = (pyramid, rpn_out, cls, box)
         if self.use_mask:
             mp = roi_align(pyramid[2], rois, self.mask_pool_size, 1.0 / 4.0)
-            outs = outs + (self.mask_forward(mp),)
+            outs = outs + (self.mask_forward(mp[0]),)
         return outs
 
 
@@ -516,35 +518,33 @@ def pyramid_roi_align(
     rois: jnp.ndarray,
     roi_valid: jnp.ndarray,
     pool_size: int,
-    plane_of: jnp.ndarray = None,
     windows: jnp.ndarray = None,
 ) -> jnp.ndarray:
     """(B, R, 4) rois → (B·R, P, P, C) pooled from each roi's FPN level.
 
-    Static-shape strategy: pool from every ROI level and mask-select — the
-    matmul ROIAlign is cheap enough that 4x beats any data-dependent
-    partition (see module docstring).
+    Static-shape strategy: pool every roi from every ROI level and
+    mask-select, 4x the pooling a data-dependent partition would do. What
+    that costs on the chip is unmeasured (no FPN cell yet, PERF.md section
+    7); each level's pool keeps the rois grouped by image
+    (ops/roi_align.py), so none of them crosses images.
 
-    graftcanvas: on a packed batch the pyramid holds PLANES, not images —
-    `plane_of` (B,) maps each image row to its plane, and `windows`
-    (B, 4) [y0, x0, h, w] placement rects clamp border samples to the
-    image's own cells (ops/roi_align.py).
+    graftcanvas: on a packed batch the pyramid holds PLANES, I images each
+    in row order (ops/canvas.py::rois_by_plane), and `windows` (B, 4)
+    [y0, x0, h, w] placement rects clamp border samples to the image's
+    own cells (ops/roi_align.py).
     """
     b, r = rois.shape[0], rois.shape[1]
     with stage("roi_align"):
-        ids = (jnp.arange(b, dtype=jnp.float32) if plane_of is None
-               else island(plane_of))
-        batch_idx = jnp.repeat(ids, r)[:, None]
-        flat = jnp.concatenate([batch_idx, rois.reshape(b * r, 4)], axis=1)
-        win = (None if windows is None
-               else jnp.repeat(windows, r, axis=0))  # (B·R, 4)
-        levels = roi_levels(rois.reshape(b * r, 4))
+        grouped, win = rois_by_plane(pyramid[ROI_LEVELS[0]].shape[0], rois,
+                                     windows)
+        levels = roi_levels(grouped)
         out = None
         for lv in ROI_LEVELS:
-            pooled = roi_align(pyramid[lv], flat, pool_size,
+            pooled = roi_align(pyramid[lv], grouped, pool_size,
                                1.0 / (2 ** lv), windows=win)
-            sel = (levels == lv)[:, None, None, None].astype(pooled.dtype)
+            sel = (levels == lv)[..., None, None, None].astype(pooled.dtype)
             out = pooled * sel if out is None else out + pooled * sel
+        out = out.reshape(b * r, *out.shape[2:])
         return out * roi_valid.reshape(b * r, 1, 1, 1).astype(out.dtype)
 
 
@@ -684,8 +684,7 @@ def forward_train(
 
     r = cfg.train.batch_rois
     pooled = pyramid_roi_align(pyramid, samples.rois, samples.valid,
-                               model.roi_pool_size, plane_of=plane_of,
-                               windows=windows)
+                               model.roi_pool_size, windows=windows)
     with stage("box_head"):
         cls_logits, bbox_deltas = model.apply(params, pooled,
                                               method="box_head")
@@ -719,7 +718,7 @@ def forward_train(
 
         mask_pooled = pyramid_roi_align(
             pyramid, samples.rois, samples.valid & samples.fg_mask,
-            model.mask_pool_size, plane_of=plane_of, windows=windows)
+            model.mask_pool_size, windows=windows)
         mask_logits = model.apply(params, mask_pooled,
                                   method="mask_forward")
         m_res = mask_logits.shape[1]
@@ -852,5 +851,5 @@ def init_fpn_params(model: FPNFasterRCNN, cfg: Config, rng: jax.Array,
                     image_shape=None):
     h, w = image_shape or (64, 64)
     images = jnp.zeros((1, h, w, 3), jnp.float32)
-    rois = jnp.asarray([[0.0, 0.0, 0.0, 31.0, 31.0]], jnp.float32)
+    rois = jnp.asarray([[[0.0, 0.0, 31.0, 31.0]]], jnp.float32)
     return model.init(rng, images, rois)

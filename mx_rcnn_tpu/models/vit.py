@@ -426,12 +426,12 @@ class ViTDet(nn.Module):
 
         pyramid = self.extract(images)
         rpn_out = self.rpn_forward(pyramid)
-        pooled = roi_align(pyramid[2], rois, self.roi_pool_size, 1.0 / 4.0)
+        pooled = roi_align(pyramid[2], rois, self.roi_pool_size, 1.0 / 4.0)[0]
         cls, box = self.box_head(pooled)
         outs = (pyramid, rpn_out, cls, box)
         if self.use_mask:
             mp = roi_align(pyramid[2], rois, self.mask_pool_size, 1.0 / 4.0)
-            outs = outs + (self.mask_forward(mp),)
+            outs = outs + (self.mask_forward(mp[0]),)
         return outs
 
 
@@ -548,5 +548,5 @@ def init_vitdet_params(model: ViTDet, cfg: Config, rng: jax.Array,
                        image_shape=None):
     h, w = image_shape or (64, 64)
     images = jnp.zeros((1, h, w, 3), jnp.float32)
-    rois = jnp.asarray([[0.0, 0.0, 0.0, 31.0, 31.0]], jnp.float32)
+    rois = jnp.asarray([[[0.0, 0.0, 31.0, 31.0]]], jnp.float32)
     return model.init(rng, images, rois)

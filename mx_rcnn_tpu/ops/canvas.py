@@ -16,7 +16,9 @@ image.canvas_pack):
 
 P = planes (one per data shard x accum chunk), I = images per plane.
 Forwards flatten (P, I) -> B images; `plane_of` maps image -> plane for
-per-image reads of per-plane tensors (RPN outputs, ROI pooling).
+per-image reads of per-plane tensors (RPN outputs). ROI pooling goes the
+other way, `rois_by_plane`: plane p's images are rows p*I .. p*I+I-1, so
+their rois regroup to the plane by shape alone.
 """
 
 from __future__ import annotations
@@ -53,6 +55,19 @@ def packed_views(batch):
 def plane_take(per_plane: jnp.ndarray, plane_of: jnp.ndarray) -> jnp.ndarray:
     """Per-plane tensor (P, ...) -> per-image rows (B, ...)."""
     return jnp.take(per_plane, plane_of, axis=0)
+
+
+def rois_by_plane(planes: int, rois: jnp.ndarray, windows=None):
+    """Per-image rois (B, R, 4) -> the groups ROIAlign pools, (P, I*R, 4).
+
+    `windows` (B, 4), one placement rect per image, comes back per roi in
+    the same grouping (None stays None). A bucketed batch is the case
+    P = B, I = 1: its rois come back as they are."""
+    grouped = rois.reshape(planes, -1, 4)
+    if windows is None:
+        return grouped, None
+    per_roi = jnp.repeat(windows, rois.shape[1], axis=0)
+    return grouped, per_roi.reshape(planes, -1, 4)
 
 
 def placement_masks(im_info: jnp.ndarray, canvas_hw: Tuple[int, int],

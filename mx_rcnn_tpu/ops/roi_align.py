@@ -5,20 +5,26 @@ Replaces MXNet's C++/CUDA builtins ``mx.symbol.ROIPooling`` and
 (rcnn/symbol/symbol_vgg.py 7x7 pool, rcnn/symbol/symbol_resnet.py 14x14 pool,
 spatial_scale 1/16).
 
-TPU formulation — this is the "Pallas-or-provably-fast" design decision:
-bilinear interpolation is SEPARABLE, so ROIAlign is exactly two small
-matmuls per ROI,
+TPU formulation: bilinear interpolation is SEPARABLE, so ROIAlign is exactly
+two small matmuls per ROI,
 
     pooled[i, j, c] = sum_h sum_w  Wy[i, h] * feat[h, w, c] * Wx[j, w]
 
 where ``Wy (P, H)`` / ``Wx (P, W)`` hold the tent-function (hat) bilinear
 weights of each bin's sample points, bin-averaging folded in. That maps the
-op onto the MXU as a batched (R·P, H) x (H, W·C) contraction instead of the
-CUDA kernels' per-point gathers — gathers lower to slow scalar loads on TPU,
-while these matmuls run at MXU rate and their transposes ARE the backward
-pass. A custom Pallas kernel would only re-derive this same schedule, so the
-einsum form is the intended final design, not a stopgap (profiled: the pool
-is <5% of the train step, see tools/profile.py).
+op onto the MXU instead of the CUDA kernels' per-point gathers — gathers
+lower to slow scalar loads on TPU, while these matmuls' transposes ARE the
+backward pass.
+
+The rois of an image only ever touch that image's feature map, and every
+caller holds them grouped by image, ``(B, R, 4)``: the image axis is a BATCH
+dimension of both contractions, ``(R·P, H) x (H, W·C)`` per image and then
+``(P, W) x (W, C)`` per roi and row. Under a mesh that shards the batch over
+``data`` GSPMD partitions both with no exchange. What the op costs on a v5e
+is the HBM traffic of the ``(B, R, P, W, C)`` intermediate, not its FLOPs:
+PERF.md sections 5 and 6 carry the measured share of the train step (PR 26;
+the masked loop over every image of the batch that this replaced was 56 % of
+the one-chip step and 84 % of the four-chip one: ledger, PR 25).
 
 - ``roi_align``: bilinear sampling, ``sampling_ratio`` points per bin axis,
   average-pooled (He et al. Mask R-CNN semantics; ``aligned=True`` applies the
@@ -68,34 +74,36 @@ def roi_align(
     aligned: bool = False,
     windows: jnp.ndarray = None,
 ) -> jnp.ndarray:
-    """ROIAlign.
+    """ROIAlign of each image's rois against that image's feature map.
 
     Args:
       features: (B, H, W, C) feature maps (NHWC — TPU-native layout; the
         reference's graphs are NCHW because cuDNN prefers it).
-      rois: (R, 5) rows of (batch_idx, x1, y1, x2, y2) in image coords —
-        same layout as the reference's Proposal op output.
+      rois: (B, R, 4) rows of (x1, y1, x2, y2) in image coords, grouped by
+        image: ``rois[i]`` are pooled from ``features[i]`` and from nothing
+        else (the reference's Proposal op carries a batch_idx column
+        instead; here the map is in the shape).
       output_size: pooled grid side P.
       spatial_scale: e.g. 1/16 for C4.
       sampling_ratio: sample points per bin axis.
       aligned: half-pixel correction.
-      windows: optional (R, 4) rows [y0, x0, h, w] in image coords — the
-        ROI's placement rect on a packed canvas (graftcanvas). Sample
+      windows: optional (B, R, 4) rows [y0, x0, h, w] in image coords —
+        each ROI's placement rect on a packed canvas (graftcanvas). Sample
         points then clamp to the rect's feature cells instead of the
         whole map, reproducing the bucketed per-image border behavior.
 
-    Returns: (R, P, P, C), features.dtype.
+    Returns: (B, R, P, P, C), features.dtype.
     """
-    b, h, w, _ = features.shape
+    _, h, w, _ = features.shape
     p = output_size
     s = sampling_ratio
     offset = 0.5 if aligned else 0.0
 
     def one_roi_weights(roi, win):
-        x1 = roi[1] * spatial_scale - offset
-        y1 = roi[2] * spatial_scale - offset
-        x2 = roi[3] * spatial_scale - offset
-        y2 = roi[4] * spatial_scale - offset
+        x1 = roi[0] * spatial_scale - offset
+        y1 = roi[1] * spatial_scale - offset
+        x2 = roi[2] * spatial_scale - offset
+        y2 = roi[3] * spatial_scale - offset
         rw = jnp.maximum(x2 - x1, 1.0) if not aligned else (x2 - x1)
         rh = jnp.maximum(y2 - y1, 1.0) if not aligned else (y2 - y1)
         cy = cx = (0.0, None)
@@ -108,25 +116,15 @@ def roi_align(
         wx = _tent_weights(x1, rw / p, p, s, w, *cx)  # (P, W)
         return wy, wx
 
-    wy, wx = jax.vmap(one_roi_weights, in_axes=(0, None if windows is None
-                                                else 0))(rois, windows)
-    batch_idx = rois[:, 0].astype(jnp.int32)
+    in_axes = (0, None if windows is None else 0)
+    wy, wx = jax.vmap(jax.vmap(one_roi_weights, in_axes=in_axes),
+                      in_axes=in_axes)(rois, windows)
     dt = features.dtype
-    wy = wy.astype(dt)
-    wx = wx.astype(dt)
-
-    # Contract against each image's features with the ROI→image assignment
-    # folded into the weights (zeroing non-matching ROIs), summing the per-
-    # image contributions — exactly one image contributes per ROI. This keeps
-    # the contraction a clean (R·P, H) x (H, W·C) matmul per image instead of
-    # a per-ROI feature-map gather (which would materialize (R, H, W, C)).
-    tmp = None
-    for bi in range(b):
-        wy_b = jnp.where((batch_idx == bi)[:, None, None], wy, 0)
-        t = jnp.einsum("rph,hwc->rpwc", wy_b, features[bi],
-                       preferred_element_type=jnp.float32)
-        tmp = t if tmp is None else tmp + t
-    out = jnp.einsum("rqw,rpwc->rpqc", wx, tmp.astype(dt),
+    # The image axis is a batch dimension of both operands: no roi meets
+    # another image's map, and a batch sharded over a mesh axis stays put.
+    tmp = jnp.einsum("brph,bhwc->brpwc", wy.astype(dt), features,
+                     preferred_element_type=jnp.float32)
+    out = jnp.einsum("brqw,brpwc->brpqc", wx.astype(dt), tmp.astype(dt),
                      preferred_element_type=jnp.float32)
     return out.astype(dt)
 

@@ -13,7 +13,9 @@ chip's compiler can refuse is held by tier-1 at no chip time:
   refuses a bare Mosaic call ("cannot be automatically partitioned") and
   ``nms_dispatch`` has to wrap it in a ``shard_map`` over ``data``;
 - a whole data-parallel train step on that mesh, tiny in width, built by
-  ``make_train_step`` exactly as ``fit_detector`` builds it.
+  ``make_train_step`` exactly as ``fit_detector`` builds it; in it the
+  ``roi_align`` stage is partitioned over ``data`` with no exchange, each
+  device contracting its own images' rois against its own feature maps.
 
 Each asserts ``tpu_custom_call`` in the compiled program: the kernel is in
 it, not its jnp stand-in; the train steps also hold it under the name a
@@ -30,7 +32,9 @@ worker); no child processes; the compile cache is off around the compiles
 (an executable compiled for a described device cannot be read back).
 """
 
+import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -144,7 +148,11 @@ def test_kernel_partitions_over_a_data_mesh(data_mesh):
         bare.lower(*_nms_args(4, 12000, sharded)).compile()
 
 
+@functools.lru_cache(maxsize=None)
 def _tiny_step_hlo(mesh, n_images):
+    """The compiled step's text; one compile per (mesh, batch) for the
+    tests that read it. Call it with ``jax.default_backend`` patched to
+    "tpu", as every caller here does."""
     from mx_rcnn_tpu.config import generate_config
     from mx_rcnn_tpu.models.faster_rcnn import build_model
     from mx_rcnn_tpu.train.step import abstract_step_inputs, make_train_step
@@ -161,8 +169,6 @@ def _tiny_step_hlo(mesh, n_images):
 def _assert_kernel_named_in_its_stage(hlo):
     """The program's Mosaic calls are instructions named after the kernel,
     and their scope paths (``op_name``) lie in the ``proposal`` stage."""
-    import re
-
     from mx_rcnn_tpu.obs.profile import stage_of
 
     paths = re.findall(
@@ -186,13 +192,66 @@ def test_data_parallel_train_step_compiles_with_the_kernel(data_mesh,
     _assert_kernel_named_in_its_stage(hlo)
 
 
-def test_one_chip_train_step_names_the_kernel(topo, monkeypatch):
+@pytest.fixture(scope="module")
+def one_chip_mesh(topo):
+    return Mesh(np.asarray(topo.devices[:1]).reshape(1, 1),
+                ("data", "model"))
+
+
+def test_one_chip_train_step_names_the_kernel(one_chip_mesh, monkeypatch):
     """The same step on a mesh of one described chip (no ``shard_map``
     around the kernel, no collective): the trace will show the kernel as
     ``nms_sweep`` there too."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    mesh = Mesh(np.asarray(topo.devices[:1]).reshape(1, 1),
-                ("data", "model"))
-    hlo = _tiny_step_hlo(mesh, 1)
+    hlo = _tiny_step_hlo(one_chip_mesh, 2)
     assert KERNEL in hlo and "all-reduce" not in hlo
     _assert_kernel_named_in_its_stage(hlo)
+
+
+# The tiny step's ROIAlign: 32 rois an image, a 14x14 pool, a 128/16 = 8x8
+# map of 1024 channels. Per image, the elements each contraction of
+# ops/roi_align.py puts out, forward or backward: (r,p,w,c), (r,p,q,c)
+# and the map's own (h,w,c).
+_ROI_ALIGN_OUT = {32 * 14 * 8 * 1024, 32 * 14 * 14 * 1024, 8 * 8 * 1024}
+_H_CONTRACTION = "/jvp(roi_align)/brph,bhwc->brpwc/dot_general"
+
+
+def _roi_align_ops(hlo):
+    """(opcode, elements put out, op_name) of every instruction, fused or
+    not, whose scope path lies in the ``roi_align`` stage."""
+    from mx_rcnn_tpu.obs.profile import stage_of
+
+    ops = re.findall(r'^\s*(?:ROOT )?%[\w.-]+ = \w+\[([\d,]*)\]\S* '
+                     r'([\w-]+)\([^\n]*op_name="([^"]*)"', hlo, re.M)
+    return [(opcode, int(np.prod([int(d) for d in dims.split(",") if d])),
+             path) for dims, opcode, path in ops
+            if stage_of(path) == "roi_align"]
+
+
+def test_roi_align_partitions_over_data_with_no_exchange(data_mesh,
+                                                         monkeypatch):
+    """Four images on four devices: the image axis is a batch dimension of
+    both contractions, so GSPMD leaves each device ITS image's rois against
+    ITS map — no collective moves a map or a roi inside the stage, and no
+    contraction is four images wide."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    ops = _roi_align_ops(_tiny_step_hlo(data_mesh, 4))
+    moved = [(o, path) for o, _, path in ops if o.startswith(
+        ("all-gather", "all-to-all", "collective-permute", "all-reduce"))]
+    assert not moved, moved
+    sizes = [n for o, n, _ in ops if o == "convolution"]
+    assert len(sizes) >= 4, ops   # two einsums, forward and backward
+    assert set(sizes) <= _ROI_ALIGN_OUT, sizes
+
+
+def test_one_chip_roi_align_is_one_batched_contraction(one_chip_mesh,
+                                                       monkeypatch):
+    """Two images on one chip: ONE forward contraction over H that is two
+    images wide, not one per image of all the rois."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    ops = _roi_align_ops(_tiny_step_hlo(one_chip_mesh, 2))
+    forward_h = [n for o, n, path in ops if o == "convolution"
+                 and path.endswith(_H_CONTRACTION)]
+    assert forward_h == [2 * 32 * 14 * 8 * 1024], forward_h
+    assert {n for o, n, _ in ops if o == "convolution"} <= {
+        2 * n for n in _ROI_ALIGN_OUT}

@@ -115,9 +115,9 @@ def test_pyramid_roi_align_selects_assigned_level(rng):
     assert out.shape == (2, 7, 7, 8)
     lv_of = np.asarray(F.roi_levels(rois[0]))
     for i, lv in enumerate(lv_of):
-        flat = jnp.asarray([[0, *np.asarray(rois)[0, i]]], jnp.float32)
-        want = roi_align(pyramid[int(lv)], flat, 7, 1.0 / 2 ** int(lv))
-        np.testing.assert_allclose(np.asarray(out[i]), np.asarray(want[0]),
+        want = roi_align(pyramid[int(lv)], rois[:, i:i + 1], 7,
+                         1.0 / 2 ** int(lv))
+        np.testing.assert_allclose(np.asarray(out[i]), np.asarray(want[0, 0]),
                                    rtol=1e-5, atol=1e-5)
 
 
