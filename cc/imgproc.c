@@ -1,16 +1,17 @@
 /* Fused image normalize+pad kernels for the host input pipeline.
  *
  * The loader's numpy normalize ((img - mean) / std) and zero-pad stages
- * hold the GIL and walk the image twice; at flagship shapes they are the
- * measured bottleneck of the packed-shard path and the reason worker
- * threads scale INVERSELY (PERF.md r4). These kernels do both in one
+ * hold the GIL and walk the image twice. These kernels do both in one
  * pass, called through ctypes (which releases the GIL for the duration),
- * so decode/normalize workers actually run in parallel.
+ * so loader workers run in parallel, and they write a destination the
+ * caller owns: the training loader passes each image's row of a reused
+ * batch buffer (data/loader.py), so no page of a batch is new to the
+ * process.
  *
  * Reference lineage: rcnn/io/image.py::transform + tensor_vstack padding
  * (pure numpy there; the reference's native layer was the CUDA ops, not
- * IO — this is TPU-era surface, where the host must keep up with a chip
- * that consumes 40-55 img/s).
+ * IO — this is TPU-era surface, where one host must keep up with four
+ * chips that consume 70 img/s each).
  *
  * Layout: HWC, C=3, RGB. dst is (ph, pw, 3) float32, fully written
  * (image region normalized, remainder zeroed). src strides are

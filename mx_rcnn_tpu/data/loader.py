@@ -8,18 +8,29 @@ TPU deltas:
 - anchor/ROI target assignment moved on-device (targets/), so AnchorLoader
   only yields images + padded gt boxes;
 - every batch has ONE static shape (config.image.pad_shape + max_gt_boxes);
-- a worker-thread pool decodes/resizes ahead of the device (the reference
-  overlaps only via MXNet's PrefetchingIter when wired, SURVEY.md §4.1 'hot
-  loops'). Thread scaling is INVERSE beyond ~2 workers (GIL contention on
-  the numpy normalize/pad stages — measured 71.8 img/s at 1 worker vs
-  52.3 at 8, flagship shapes; PERF.md r4), so the default is 2; the
-  packed shard format (data/packed.py) is the throughput path;
+- a worker-thread pool assembles batches ahead of the device (the
+  reference overlaps only via MXNet's PrefetchingIter when wired,
+  SURVEY.md §4.1 'hot loops'). A training batch's pixels are written ONCE,
+  by the normalize kernel, into their row of a batch buffer the loader
+  owns and reuses (_BufferPool). What used to cap the loader was not the
+  GIL (the kernel runs outside it) but fresh pages: a 7.9 MB temporary
+  per image and a np.stack into a new (32, 640, 1024, 3) float32 every
+  batch meant 252 MB of pages the kernel zero-fills on first touch and
+  unmaps when the batch dies, which does not scale with threads in one
+  address space. Measured on the four-chip host (30 cores, PR 28,
+  PERF.md §6), this loader alone at batch 32: 137.5 img/s before, 1652.3
+  after with two workers (208.8 -> 2701.2 with four); at batch 8 on the
+  one-chip host (13 cores) 138.7 -> 1271.8. Four chips ask for 275, so
+  the default stays at 2 workers: more threads would only take cores from
+  the train loop's own host work. The packed shard format
+  (data/packed.py) is the throughput path;
 - aspect grouping survives as a perf knob (groups portrait/landscape so the
   short-side resize wastes less canvas), not a correctness feature.
 """
 
 from __future__ import annotations
 
+import sys
 import threading
 import time
 from typing import Dict, Iterator, List, Optional, Sequence, Tuple
@@ -29,12 +40,11 @@ import numpy as np
 from mx_rcnn_tpu.config import Config
 from mx_rcnn_tpu.logger import logger
 from mx_rcnn_tpu.data.feedguard import DataStallError, DataWorkerError
+from mx_rcnn_tpu.data._native_img import normalize_pad
 from mx_rcnn_tpu.data.image import (
     flip_image_and_boxes,
     load_image,
-    pad_image,
     resize_image,
-    transform_image,
 )
 
 
@@ -83,16 +93,19 @@ def resolve_pad_bucket(cfg: Config, scale_idx: int,
 
 
 def _load_roidb_entry(entry: Dict, cfg: Config, scale_idx: int = 0,
-                      pad: Optional[tuple] = None):
+                      pad: Optional[tuple] = None,
+                      out: Optional[np.ndarray] = None):
     """roidb record → (padded image f32 HWC, im_info, boxes, classes) at the
-    chosen training scale. Handles the `flipped` flag the imdb sets.
+    chosen training scale. Handles the `flipped` flag the imdb sets. The
+    image is written into ``out`` (a (pad_h, pad_w, 3) float32 row of the
+    loader's batch buffer) when one is given, into a fresh array otherwise.
 
     Packed entries (data/packed.py shards) take the mmap fast path: the
     decode+resize already happened at pack time."""
     if "packed" in entry:
         from mx_rcnn_tpu.data.packed import load_packed_entry
 
-        return load_packed_entry(entry, cfg, scale_idx, pad)
+        return load_packed_entry(entry, cfg, scale_idx, pad, out)
     if "image_data" in entry:  # synthetic datasets embed pixels directly
         img = entry["image_data"].astype(np.float32)
     else:
@@ -105,17 +118,9 @@ def _load_roidb_entry(entry: Dict, cfg: Config, scale_idx: int = 0,
     boxes *= scale
     h, w = img.shape[:2]
     pad = pad if pad is not None else pad_shape_for(cfg, scale_idx)
-    # Fused GIL-free normalize+pad (cc/imgproc.c); numpy fallback.
-    from mx_rcnn_tpu.data._native_img import normalize_pad
-
-    fused = normalize_pad(np.ascontiguousarray(img, np.float32),
-                          cfg.image.pixel_means, cfg.image.pixel_stds, pad)
-    if fused is not None:
-        img = fused
-    else:
-        img = pad_image(
-            transform_image(img, cfg.image.pixel_means,
-                            cfg.image.pixel_stds), pad)
+    img = normalize_pad(np.ascontiguousarray(img, np.float32),
+                        cfg.image.pixel_means, cfg.image.pixel_stds, pad,
+                        out=out)
     im_info = np.asarray([h, w, scale], np.float32)
     return img, im_info, boxes, entry["gt_classes"].astype(np.int32)
 
@@ -148,16 +153,9 @@ def _load_roidb_content(entry: Dict, cfg: Config, scale_idx: int,
     img, scale = resize_image(img, target, max_size)
     boxes *= scale
     h, w = img.shape[:2]
-    # Fused GIL-free normalize (cc/imgproc.c) with pad == content dims
-    # (a no-op pad keeps the one-pass kernel); numpy fallback.
-    from mx_rcnn_tpu.data._native_img import normalize_pad
-
-    fused = normalize_pad(np.ascontiguousarray(img, np.float32),
-                          cfg.image.pixel_means, cfg.image.pixel_stds,
-                          (h, w))
-    img = (fused if fused is not None else
-           transform_image(img, cfg.image.pixel_means,
-                           cfg.image.pixel_stds))
+    # pad == content dims: a no-op pad keeps the one-pass kernel
+    img = normalize_pad(np.ascontiguousarray(img, np.float32),
+                        cfg.image.pixel_means, cfg.image.pixel_stds, (h, w))
     im_info = np.asarray([h, w, scale], np.float32)
     return img, im_info, boxes, entry["gt_classes"].astype(np.int32)
 
@@ -207,6 +205,44 @@ def _entry_gt_masks(entry: Dict, m: int, max_gt: int) -> np.ndarray:
     if entry.get("flipped"):
         out = out[:, :, ::-1]
     return out
+
+
+class _BufferPool:
+    """The float32 batch buffers one loader owns, per shape, reused.
+
+    ``take`` hands out a buffer that nothing outside the pool references
+    any more, and allocates a new one when there is none: no release
+    call, no ring length. A consumer that keeps a batch (the benchmark's
+    window keeps its first three ``image`` arrays by reference) keeps its
+    buffer out of circulation for as long; ``jax.device_put`` holds the
+    host array until its transfer is done (on the CPU backend, for the
+    life of the device array), so unreferenced is also what makes reuse
+    safe against a copy in flight. References are counted on the OWNING
+    array: numpy collapses a view of a view onto it (``buf[:][0].base is
+    buf``), so a surviving row or slice of a batch counts too.
+    """
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._bufs: Dict[tuple, List[np.ndarray]] = {}
+        self.allocated = 0
+        # what sys.getrefcount reads, in the form take() asks it, of an
+        # array that only a list holds
+        probe = [np.empty(0, np.float32)]
+        self._idle_refs = sys.getrefcount(probe[0])
+
+    def take(self, shape: Sequence[int]) -> np.ndarray:
+        """An idle (or new) C-contiguous float32 buffer of ``shape``;
+        its contents are whatever the last batch left there."""
+        shape = tuple(int(d) for d in shape)
+        with self._lock:
+            bufs = self._bufs.setdefault(shape, [])
+            for k in range(len(bufs)):
+                if sys.getrefcount(bufs[k]) == self._idle_refs:
+                    return bufs[k]
+            bufs.append(np.empty(shape, np.float32))
+            self.allocated += 1
+            return bufs[-1]
 
 
 class _PrefetchIterator:
@@ -499,6 +535,9 @@ class AnchorLoader(_CloseableLoader):
     Yields dicts with keys image (B,H,W,3) f32, im_info (B,3),
     gt_boxes (B,G,4), gt_classes (B,G), gt_valid (B,G) — the forward_train
     batch contract. B = cfg.train.batch_images × num_shards (devices).
+    ``image`` is one of the loader's reused buffers (_BufferPool): it is
+    the consumer's for as long as the consumer references it, and goes
+    back into circulation when the last reference is dropped.
 
     graftcanvas (cfg.image.canvas_pack): batches are instead PACKED —
     each shard's images shelf-packed into one fixed canvas plane
@@ -537,6 +576,7 @@ class AnchorLoader(_CloseableLoader):
         self._depth = prefetch_depth
         self._workers = workers
         self._guard = guard
+        self._pool = _BufferPool()
         self._canvas_spec = None
         if cfg.image.canvas_pack:
             from mx_rcnn_tpu.data.canvas import validate_canvas_pack
@@ -658,7 +698,8 @@ class AnchorLoader(_CloseableLoader):
         placements, fit, _ = plan_batch(
             self._content_sizes_fn(idxs, scale_idx), len(idxs), spec)
         planes = len(idxs) // spec.images
-        image = np.zeros((planes, ch, cw, 3), np.float32)
+        image = self._pool.take((planes, ch, cw, 3))
+        image.fill(0.0)
         info = np.zeros((planes, spec.images, 5), np.float32)
         gtb = np.zeros((planes, spec.images, g, 4), np.float32)
         gtc = np.zeros((planes, spec.images, g), np.int32)
@@ -720,32 +761,32 @@ class AnchorLoader(_CloseableLoader):
         pad = resolve_pad_bucket(cfg, scale_idx, [
             self.roidb[i].get("width", 1) >= self.roidb[i].get("height", 1)
             for i in idxs])
-        imgs, infos, gtb, gtc, gtv, gtm = [], [], [], [], [], []
-        for i in idxs:
-            def _load_entry(k, _s=scale_idx, _p=pad):
-                # A quarantine substitute can carry the other orientation;
-                # pad_image refuses overflow, so load those against the
-                # square cover and let the clamp below cut the batch shape.
+        image = self._pool.take((len(idxs), pad[0], pad[1], 3))
+        infos, gtb, gtc, gtv, gtm = [], [], [], [], []
+        for j, i in enumerate(idxs):
+            def _load_entry(k, _s=scale_idx, _p=pad, _row=image[j]):
+                # A quarantine substitute can carry the other orientation
+                # and overflow this batch's bucket: load those against the
+                # square cover and let the clamp below cut them into the
+                # row.
                 e = self.roidb[k]
                 land = e.get("width", 1) >= e.get("height", 1)
                 fits = _p[1] >= _p[0] if land else _p[0] >= _p[1]
-                p = _p if fits else (max(_p), max(_p))
-                return _load_roidb_entry(e, cfg, _s, p)
+                if fits:
+                    return _load_roidb_entry(e, cfg, _s, _p, out=_row)
+                return _load_roidb_entry(e, cfg, _s, (max(_p), max(_p)))
 
             (img, info, boxes, classes), ri = self._guarded(_load_entry, i)
             entry = self.roidb[ri]
             if img.shape[:2] != tuple(pad):
                 # A mid-batch quarantine substitute with the other
-                # orientation overflowed this batch's bucket — clamp its
-                # content in (deterministic; once per discovered record).
-                clamped = np.zeros((pad[0], pad[1], img.shape[2]),
-                                   img.dtype)
+                # orientation — clamp its content into the row
+                # (deterministic; once per discovered record).
                 ch = min(img.shape[0], pad[0])
                 cw = min(img.shape[1], pad[1])
-                clamped[:ch, :cw] = img[:ch, :cw]
-                img = clamped
+                image[j].fill(0.0)
+                image[j, :ch, :cw] = img[:ch, :cw]
             b, c, v = _pad_gt(boxes, classes, g)
-            imgs.append(img)
             infos.append(info)
             gtb.append(b)
             gtc.append(c)
@@ -753,7 +794,7 @@ class AnchorLoader(_CloseableLoader):
             if with_masks:
                 gtm.append(_entry_gt_masks(entry, m, g))
         batch = {
-            "image": np.stack(imgs),
+            "image": image,
             "im_info": np.stack(infos),
             "gt_boxes": np.stack(gtb),
             "gt_classes": np.stack(gtc),

@@ -1,8 +1,7 @@
 """Packed pre-decoded shard format for the input pipeline.
 
 SURVEY.md §8 hard-part 5: host cv2 JPEG decode + resize cannot sustain a
-v5e chip (measured: ~19 img/s single-thread, with INVERSE thread scaling
-from GIL contention, vs a 40-55 img/s chip step rate — PERF.md r4). The
+v5e chip (~19 img/s on one thread against a chip's 70 img/s). The
 reference has no equivalent (MXNet's .rec IndexedRecordIO is the closest
 ancestor); this is the TPU-era replacement: decode and resize ONCE at pack
 time, then train-time loading is an mmap slice + normalize + pad.
@@ -37,6 +36,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from mx_rcnn_tpu.config import Config
+from mx_rcnn_tpu.data._native_img import normalize_pad
 from mx_rcnn_tpu.logger import logger
 
 _GT_KEYS = ("gt_classes", "segmentations", "gt_masks")
@@ -213,10 +213,12 @@ def _shard_mmap(path: str) -> np.ndarray:
 
 
 def load_packed_entry(entry: Dict, cfg: Config, scale_idx: int,
-                      pad: Optional[tuple]):
+                      pad: Optional[tuple],
+                      out: Optional[np.ndarray] = None):
     """Packed analog of loader._load_roidb_entry: mmap slice → f32 →
-    normalize → pad. Returns (img, im_info, boxes, classes)."""
-    from mx_rcnn_tpu.data.image import pad_image, transform_image
+    normalize → pad, written into ``out`` (the image's row of the
+    loader's batch buffer) when one is given. Returns (img, im_info,
+    boxes, classes)."""
     from mx_rcnn_tpu.data.loader import pad_shape_for
 
     ref = entry["packed"].get(scale_idx)
@@ -237,18 +239,8 @@ def load_packed_entry(entry: Dict, cfg: Config, scale_idx: int,
         boxes[:, 2] = w0 - x1 - 1
     boxes *= scale
     pad = pad if pad is not None else pad_shape_for(cfg, scale_idx)
-    # Fused GIL-free mirror+normalize+pad (cc/imgproc.c) with the numpy
-    # chain as fallback.
-    from mx_rcnn_tpu.data._native_img import normalize_pad
-
     img = normalize_pad(img_u8, cfg.image.pixel_means,
-                        cfg.image.pixel_stds, pad, flip=flipped)
-    if img is None:
-        arr = img_u8[:, ::-1] if flipped else img_u8
-        img = pad_image(
-            transform_image(arr.astype(np.float32),
-                            cfg.image.pixel_means, cfg.image.pixel_stds),
-            pad)
+                        cfg.image.pixel_stds, pad, flip=flipped, out=out)
     im_info = np.asarray([rh, rw, scale], np.float32)
     return img, im_info, boxes, entry["gt_classes"].astype(np.int32)
 
@@ -265,7 +257,7 @@ def load_packed_content(entry: Dict, cfg: Config, scale_idx: int,
     Returns (img f32 HWC unpadded, im_info [h, w, scale], boxes,
     classes) with `scale` the ORIGINAL-image → content scale (stored
     pack scale × any fit resample)."""
-    from mx_rcnn_tpu.data.image import resize_image, transform_image
+    from mx_rcnn_tpu.data.image import resize_image
 
     ref = entry["packed"].get(scale_idx)
     if ref is None:
@@ -273,8 +265,6 @@ def load_packed_content(entry: Dict, cfg: Config, scale_idx: int,
             f"scale_idx {scale_idx} is not packed (have "
             f"{sorted(entry['packed'])}); re-pack with "
             "write_packed_dataset covering every training scale")
-    from mx_rcnn_tpu.data._native_img import normalize_pad
-
     rh, rw = ref["hw"]
     scale = ref["scale"]
     img_u8 = np.asarray(_shard_mmap(ref["file"])[ref["index"], :rh, :rw])
@@ -294,19 +284,11 @@ def load_packed_content(entry: Dict, cfg: Config, scale_idx: int,
         img = normalize_pad(np.ascontiguousarray(arr, np.float32),
                             cfg.image.pixel_means, cfg.image.pixel_stds,
                             arr.shape[:2])
-        if img is None:
-            img = transform_image(arr, cfg.image.pixel_means,
-                                  cfg.image.pixel_stds)
     else:
-        # Fused u8→f32 mirror+normalize (cc/imgproc.c), pad == content
-        # dims — the same one-pass kernel the bucketed mmap path uses.
+        # Fused u8→f32 mirror+normalize, pad == content dims — the same
+        # one-pass kernel the bucketed mmap path uses.
         img = normalize_pad(img_u8, cfg.image.pixel_means,
                             cfg.image.pixel_stds, (rh, rw), flip=flipped)
-        if img is None:
-            arr = (img_u8[:, ::-1] if flipped else img_u8)
-            img = transform_image(arr.astype(np.float32),
-                                  cfg.image.pixel_means,
-                                  cfg.image.pixel_stds)
     boxes *= scale
     im_info = np.asarray([img.shape[0], img.shape[1], scale], np.float32)
     return img, im_info, boxes, entry["gt_classes"].astype(np.int32)

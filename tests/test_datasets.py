@@ -381,3 +381,259 @@ def test_loader_context_manager():
             if i == 1:
                 break  # abandon mid-epoch; __exit__ must clean up
     assert not _worker_threads()
+
+
+# ---------------------------------------------------------------------------
+# batch assembly into the loader's reused buffers (data/loader.py::_BufferPool)
+# ---------------------------------------------------------------------------
+
+
+def _pool_cfg(**over):
+    base = {
+        "image.scales": ((40, 64),), "image.pad_shape": (48, 64),
+        "train.batch_images": 1, "train.flip": False,
+        "train.max_gt_boxes": 4}
+    base.update(over)
+    return generate_config("resnet50", "synthetic", **base)
+
+
+def _pixel_roidb(n, seed=0):
+    """``image_data`` records, each with its own pixels; every fifth one
+    upright, so that some batches take the square cover."""
+    rs = np.random.RandomState(seed)
+    roidb = []
+    for i in range(n):
+        h, w = (30 + i % 7, 44 + i % 11)
+        if i % 5 == 4:
+            h, w = w, h
+        roidb.append({
+            "image_data": (rs.rand(h, w, 3) * 255).astype(np.uint8),
+            "height": h, "width": w,
+            "boxes": np.asarray([[2 + i % 9, 3, 20 + i % 9, 25]], np.float32),
+            "gt_classes": np.asarray([1 + i % 3], np.int32),
+            "flipped": False})
+    return roidb
+
+
+def _plain_batch(entries, cfg):
+    """The batch assembled the plain way: every image loaded into an array
+    of its own (no ``out``), then ``np.stack``."""
+    from mx_rcnn_tpu.data.loader import (_load_roidb_entry, _pad_gt,
+                                         resolve_pad_bucket)
+
+    pad = resolve_pad_bucket(cfg, 0, [e["width"] >= e["height"]
+                                      for e in entries])
+    loaded = [_load_roidb_entry(e, cfg, 0, pad) for e in entries]
+    gt = [_pad_gt(b, c, cfg.train.max_gt_boxes) for _, _, b, c in loaded]
+    return {"image": np.stack([l[0] for l in loaded]),
+            "im_info": np.stack([l[1] for l in loaded]),
+            "gt_boxes": np.stack([g[0] for g in gt]),
+            "gt_classes": np.stack([g[1] for g in gt]),
+            "gt_valid": np.stack([g[2] for g in gt])}
+
+
+def _assert_batch_equal(got, want):
+    assert sorted(got) == sorted(want)
+    for k in want:
+        assert got[k].dtype == want[k].dtype and got[k].shape == want[k].shape
+        assert got[k].tobytes() == want[k].tobytes(), k
+
+
+@pytest.mark.parametrize("flipped", [False, True], ids=["plain", "flipped"])
+@pytest.mark.parametrize("source", ["packed", "image_data"])
+@pytest.mark.parametrize("num_shards", [1, 4])
+def test_loader_batches_equal_plain_assembly(tmp_path, num_shards, source,
+                                             flipped):
+    """Every batch of an epoch, written in place into a reused buffer, is
+    bit for bit the batch of per-image arrays and np.stack. Nothing is
+    kept, so the buffers go round several times in the epoch."""
+    from mx_rcnn_tpu.data.datasets.imdb import append_flipped_roidb
+    from mx_rcnn_tpu.data.packed import (load_packed_roidb,
+                                         write_packed_dataset)
+
+    cfg = _pool_cfg()
+    roidb = _pixel_roidb(48)
+    if source == "packed":
+        write_packed_dataset(roidb, cfg, str(tmp_path / "pack"),
+                             shard_images=16)
+        roidb = load_packed_roidb(str(tmp_path / "pack"), cfg)
+    if flipped:
+        roidb = append_flipped_roidb(roidb, name="test")
+    loader = AnchorLoader(roidb, cfg, num_shards=num_shards, shuffle=False)
+    b = loader.batch_size
+    n = 0
+    for k, batch in enumerate(loader):
+        _assert_batch_equal(batch, _plain_batch(roidb[k * b:(k + 1) * b], cfg))
+        n += 1
+    assert n == len(roidb) // b and n > loader._pool.allocated
+
+
+def _constant_roidb(n):
+    """Record i is all pixels i + 1 and exactly fills the pad shape, so a
+    batch's image reads ``(i + 1 - mean) / std`` everywhere."""
+    return [{"image_data": np.full((48, 64, 3), i + 1, np.uint8),
+             "height": 48, "width": 64,
+             "boxes": np.asarray([[1, 1, 20, 20]], np.float32),
+             "gt_classes": np.asarray([1], np.int32), "flipped": False}
+            for i in range(n)]
+
+
+def _constant_loader(n, **kw):
+    cfg = _pool_cfg(**{"image.scales": ((48, 64),)})
+    return AnchorLoader(_constant_roidb(n), cfg, shuffle=False, **kw)
+
+
+def _expected_constant(loader, i):
+    img = np.empty((48, 64, 3), np.float32)
+    img[:] = ((np.float32(i + 1)
+               - np.asarray(loader.cfg.image.pixel_means, np.float32))
+              / np.asarray(loader.cfg.image.pixel_stds, np.float32))
+    return img
+
+
+def test_pool_leaves_kept_batches_alone():
+    """(a) A consumer that keeps the first three image arrays by reference,
+    as benchmarks/window.py does, finds them unchanged twenty batches on."""
+    loader = _constant_loader(40)
+    kept, copies = [], []
+    for k, batch in enumerate(loader):
+        if k < 3:
+            kept.append(batch["image"])
+            copies.append(batch["image"].copy())
+        if k == 23:
+            break
+    loader.close()
+    for k in range(3):
+        np.testing.assert_array_equal(kept[k], copies[k])
+        np.testing.assert_allclose(kept[k][0], _expected_constant(loader, k),
+                                   rtol=1e-6)
+
+
+def test_pool_leaves_a_placed_batch_alone():
+    """(b) An array placed with jax.device_put from a batch holds that
+    batch's values after twenty more batches: the device array (or the
+    transfer) references the host buffer, so the pool does not reuse it."""
+    import jax
+
+    loader = _constant_loader(40)
+    placed = copy = None
+    for k, batch in enumerate(loader):
+        if k == 1:
+            copy = batch["image"].copy()
+            placed = jax.device_put(batch["image"])
+        if k == 22:
+            break
+    loader.close()
+    np.testing.assert_array_equal(np.asarray(placed), copy)
+
+
+def test_pool_stays_small_when_nothing_is_kept():
+    """(c) Over 200 batches with nothing kept, the pool never allocates
+    more than prefetch_depth + workers + 2 buffers: it reuses."""
+    loader = _constant_loader(200, prefetch_depth=4, workers=2)
+    n = 0
+    for k, batch in enumerate(loader):
+        assert batch["image"][0, 0, 0, 0] == _expected_constant(
+            loader, k)[0, 0, 0]
+        n += 1
+    assert n == 200
+    assert 1 <= loader._pool.allocated <= 4 + 2 + 2
+
+
+def test_pool_loader_close_joins_workers():
+    """(d) close() still joins every worker mid-epoch; a batch handed out
+    before it stays intact, and the next epoch reuses the same buffers."""
+    loader = _constant_loader(60)
+    it = iter(loader)
+    first = next(it)["image"]
+    copy = first.copy()
+    next(it)
+    assert _worker_threads(), "prefetch pool never started"
+    loader.close()
+    assert not _worker_threads(), "worker threads survived close()"
+    assert sum(1 for _ in loader) == 60
+    assert not _worker_threads()
+    np.testing.assert_array_equal(first, copy)
+    assert loader._pool.allocated <= 4 + 2 + 2 + 1   # `first` is still held
+
+
+def test_pool_under_many_workers_and_fast_switching():
+    """More workers than cores and a short switch interval: every batch
+    still carries its own record's pixels in every row, whether or not the
+    consumer keeps some of them (a buffer handed to two workers at once, or
+    reused while kept, would mix records)."""
+    import sys
+    import time
+
+    n, workers = 240, 2 * (os.cpu_count() or 4)
+    cfg = _pool_cfg(**{"image.scales": ((48, 64),), "train.batch_images": 2})
+    loader = AnchorLoader(_constant_roidb(n), cfg, shuffle=False,
+                          workers=workers, prefetch_depth=workers)
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    t0 = time.monotonic()
+    kept = {}
+    try:
+        for k, batch in enumerate(loader):
+            for j in range(2):
+                want = _expected_constant(loader, 2 * k + j)
+                assert (batch["image"][j] == want).all(), (k, j)
+            if k % 7 == 0:
+                kept[k] = batch["image"]
+            assert time.monotonic() - t0 < 120
+    finally:
+        sys.setswitchinterval(old)
+        loader.close()
+    assert k == n // 2 - 1
+    for k, image in kept.items():
+        assert (image[1] == _expected_constant(loader, 2 * k + 1)).all(), k
+
+
+def test_substitute_of_other_orientation_is_clamped_into_its_row():
+    """A quarantine substitute discovered mid-batch can be upright in a
+    lying batch: it is loaded against the square cover and its content cut
+    into the row of the batch buffer, the rest of the row zero."""
+    from mx_rcnn_tpu.data.loader import _load_roidb_entry
+
+    cfg = _pool_cfg()
+    roidb = _pixel_roidb(10)
+    upright = next(i for i, e in enumerate(roidb)
+                   if e["height"] > e["width"])
+    lying = [i for i, e in enumerate(roidb) if e["width"] >= e["height"]][:2]
+
+    class Substituting:
+        chaos_spec = None
+
+        def resolve(self, i):
+            return i
+
+        def load(self, load_one, i, cancel=None):
+            k = upright if i == lying[1] else i
+            return load_one(k), k
+
+    loader = AnchorLoader(roidb, cfg, num_shards=2, shuffle=False,
+                          guard=Substituting())
+    for _ in range(3):   # the same rows again: stale pixels must not show
+        batch = loader._make_batch((lying, 0))
+        assert batch["image"].shape == (2, 48, 64, 3)
+        want0 = _load_roidb_entry(roidb[lying[0]], cfg, 0, (48, 64))[0]
+        square, info = _load_roidb_entry(roidb[upright], cfg, 0,
+                                         (64, 64))[:2]
+        np.testing.assert_array_equal(batch["image"][0], want0)
+        np.testing.assert_array_equal(batch["image"][1], square[:48, :64])
+        np.testing.assert_array_equal(batch["im_info"][1], info)
+        del batch
+
+
+def test_loader_without_native_layer_takes_the_same_road(monkeypatch):
+    """With no toolchain the numpy chain fills the same rows: batches
+    equal the plain assembly (which then runs the numpy chain too)."""
+    from mx_rcnn_tpu.data import _native_img
+
+    monkeypatch.setattr(_native_img, "get_lib", lambda: None)
+    cfg = _pool_cfg()
+    roidb = _pixel_roidb(24)
+    loader = AnchorLoader(roidb, cfg, num_shards=2, shuffle=False)
+    for k, batch in enumerate(loader):
+        _assert_batch_equal(batch, _plain_batch(roidb[2 * k:2 * k + 2], cfg))
+    assert k == 11 and loader._pool.allocated < 12

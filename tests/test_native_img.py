@@ -67,3 +67,60 @@ def test_fused_noncontiguous_mmap_slice(rng, tmp_path):
     out = _native_img.normalize_pad(view, MEANS, STDS, (32, 48))
     np.testing.assert_allclose(out, _ref(np.array(view), (32, 48)),
                                rtol=1e-6, atol=1e-5)
+
+
+# -- the caller's destination (a row of the loader's batch buffer) ---------
+
+
+def _source(rng, kind):
+    img = (rng.rand(37, 53, 3) * 255).astype(
+        np.float32 if kind == "float32" else np.uint8)
+    return img, kind == "uint8_flipped"
+
+
+@pytest.mark.parametrize("kind", ["uint8", "uint8_flipped", "float32"])
+def test_out_row_equals_fresh_array(rng, kind):
+    """normalize_pad(out=row) writes every element of the row, stale ones
+    included, to what normalize_pad() returns, and returns the row."""
+    img, flip = _source(rng, kind)
+    want = _native_img.normalize_pad(img, MEANS, STDS, (40, 64), flip=flip)
+    buf = np.full((3, 40, 64, 3), np.nan, np.float32)
+    got = _native_img.normalize_pad(img, MEANS, STDS, (40, 64), flip=flip,
+                                    out=buf[1])
+    assert got.base is buf and np.shares_memory(got, buf[1])
+    assert buf[1].tobytes() == want.tobytes()
+    assert np.isnan(buf[0]).all() and np.isnan(buf[2]).all()
+
+
+@pytest.mark.parametrize("bad", [
+    pytest.param(lambda: np.empty((40, 63, 3), np.float32), id="shape"),
+    pytest.param(lambda: np.empty((40, 64, 3), np.float64), id="dtype"),
+    pytest.param(lambda: np.empty((40, 128, 3), np.float32)[:, ::2],
+                 id="layout"),
+    pytest.param(lambda: np.empty((64, 40, 3), np.float32).transpose(1, 0, 2),
+                 id="transposed"),
+    pytest.param(lambda: [[0.0]], id="not_an_array"),
+])
+def test_out_of_the_wrong_kind_is_refused(rng, bad):
+    img = (rng.rand(37, 53, 3) * 255).astype(np.uint8)
+    with pytest.raises(ValueError, match="out must be"):
+        _native_img.normalize_pad(img, MEANS, STDS, (40, 64), out=bad())
+
+
+@pytest.mark.parametrize("kind", ["uint8", "uint8_flipped", "float32"])
+def test_numpy_fallback_honours_out(rng, kind, monkeypatch):
+    """Without the native layer the numpy chain writes the same
+    destination: transform_image + pad_image's values, bit for bit."""
+    img, flip = _source(rng, kind)
+    monkeypatch.setattr(_native_img, "get_lib", lambda: None)
+    want = _ref(img, (40, 64), flip=flip)
+    fresh = _native_img.normalize_pad(img, MEANS, STDS, (40, 64), flip=flip)
+    assert fresh.dtype == np.float32 and fresh.tobytes() == want.tobytes()
+    buf = np.full((2, 40, 64, 3), np.nan, np.float32)
+    got = _native_img.normalize_pad(img, MEANS, STDS, (40, 64), flip=flip,
+                                    out=buf[0])
+    assert got.base is buf and buf[0].tobytes() == want.tobytes()
+    assert np.isnan(buf[1]).all()
+    with pytest.raises(ValueError, match="out must be"):
+        _native_img.normalize_pad(img, MEANS, STDS, (40, 64),
+                                  out=np.empty((40, 64, 3), np.float64))
