@@ -15,9 +15,11 @@ iterations of an O(N) step inside `lax.fori_loop`. This matches the
 sequential-suppression semantics of the Cython/CUDA kernels exactly
 (including the strict `>` threshold comparison).
 
-The blockwise-bitmask Pallas kernel (ops/nms_pallas.py, the nms_kernel.cu
-formulation on MXU-sized tiles) is the TPU path; the jnp versions here are
-the reference implementation and its correctness oracle. ``nms_dispatch``
+The blocked Pallas kernel (ops/nms_pallas.py, the nms_kernel.cu formulation
+on the vector unit: per 128-box block a diagonal IoU tile, a resolve on whole
+vectors, and a fused sweep of the later columns only; no matrix product) is
+the TPU path; the jnp versions here are the reference implementation and its
+correctness oracle. ``nms_dispatch``
 at the bottom is the one place that decides which runs.
 """
 

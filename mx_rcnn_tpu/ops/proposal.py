@@ -12,7 +12,7 @@ Pipeline (reference semantics, static shapes):
   → greedy NMS(thresh) → top post_nms_top_n, padded + validity mask.
 
 NMS dispatch (``nms_impl``):
-  - "pallas": the blocked-bitmask Pallas TPU kernel
+  - "pallas": the blocked greedy-NMS Pallas TPU kernel
     (ops/nms_pallas.py::batched_nms — the nms_kernel.cu analog), one batched
     call over all images.
   - "xla": the jnp formulations (ops/nms.py) — bitmask for small candidate

@@ -6,9 +6,8 @@ chip's compiler can refuse is held by tier-1 at no chip time:
 
 - the Pallas NMS kernel (ops/nms_pallas.py) at every N the presets reach —
   6000 / 12000 (C4 test / train), 20000 (alternate training's
-  ``test.proposal_pre_nms_top_n``, which needs more than Mosaic's default
-  16 MiB of scoped VMEM), the FPN per-level 1000 / 2000 and all-level
-  5000 / 10000 — at batch 1 and 2;
+  ``test.proposal_pre_nms_top_n``), the FPN per-level 1000 / 2000 and
+  all-level 5000 / 10000 — at batch 1 and 2, none stating a VMEM limit;
 - the same kernel under a 4-device ``(data, model)`` mesh, where GSPMD
   refuses a bare Mosaic call ("cannot be automatically partitioned") and
   ``nms_dispatch`` has to wrap it in a ``shard_map`` over ``data``;
@@ -98,22 +97,25 @@ def test_nms_kernel_compiles_at_every_preset_n(one_chip, n, batch):
     assert KERNEL in compiled.as_text()
 
 
-def test_only_the_alternate_budget_states_a_vmem_limit():
-    """Up to the C4 train budget the kernel runs in Mosaic's default 16 MiB
-    of scoped VMEM (the programs there are unchanged); past it the kernel
-    states its real need instead of the config lowering a reference
-    value."""
+def test_the_alternate_budget_compiles_without_a_stated_vmem_limit(one_chip):
+    """The kernel holds no (BLOCK, N) tile, so even the largest preset
+    (alternate training's 20000 proposals) fits Mosaic's default scoped
+    VMEM: the call states no limit of its own, and the chip's compiler
+    takes it."""
     from mx_rcnn_tpu.config import generate_config
 
-    pad = lambda n: -(-n // nms_pallas.BLOCK) * nms_pallas.BLOCK
-    assert nms_pallas._vmem_limit(pad(12000)) is None
-    need = nms_pallas._vmem_limit(pad(20000))
-    assert 20.44 * 2 ** 20 < need < 32 * 2 ** 20  # the compiler asked 20.44M
     cfg = generate_config("resnet101", "coco")
     assert cfg.test.proposal_pre_nms_top_n == 20000
     assert (cfg.train.rpn_pre_nms_top_n, cfg.train.rpn_post_nms_top_n,
             cfg.test.rpn_pre_nms_top_n, cfg.test.rpn_post_nms_top_n) == (
                 12000, 2000, 6000, 300)
+    boxes, _, valid = _nms_args(2, cfg.test.proposal_pre_nms_top_n, one_chip)
+    lowered = jax.jit(
+        lambda b, v: nms_pallas.nms_keep_sorted(b, v, 0.7)
+    ).lower(boxes, valid)
+    # a stated vmem_limit_bytes is lowered to a scoped-memory entry
+    assert "scoped_memory_configs" not in lowered.as_text()
+    assert KERNEL in lowered.compile().as_text()
 
 
 def test_whole_nms_op_compiles_with_auto_dispatch(one_chip, monkeypatch):

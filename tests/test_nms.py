@@ -6,7 +6,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from functools import partial
+from functools import cache, partial
 
 from mx_rcnn_tpu.ops import nms_pallas
 from mx_rcnn_tpu.ops.nms import nms, nms_bitmask, nms_dispatch
@@ -43,6 +43,95 @@ def random_dets(rng, n):
     # Distinct scores avoid tie-order ambiguity between implementations.
     scores = rng.permutation(n).astype(np.float32) / n + 0.01
     return boxes, scores
+
+
+_jit_batched_nms = jax.jit(batched_nms, static_argnums=(3, 4))
+_jit_keep_sorted = jax.jit(partial(nms_pallas.nms_keep_sorted,
+                                   iou_threshold=0.7, interpret=True))
+
+
+@cache
+def _jit_oracle(oracle, n):
+    return jax.jit(jax.vmap(partial(oracle, iou_threshold=0.7, max_output=n)))
+
+
+def _far_small_dets(rng, s, n):
+    """Random boxes inside [0, 140): clear of the boxes a case plants."""
+    return np.stack([random_dets(rng, n)[0] for _ in range(s)])
+
+
+def _chain_case(rng):
+    """(a) 129 boxes, each over the threshold with its successor only (IoU
+    85/115, then 70/130): the resolve needs all its 128 passes, and the
+    129th box, alone in the second block, hangs on the 128th's fate."""
+    x = 15.0 * np.arange(129, dtype=np.float32)
+    zeros = np.zeros_like(x)
+    boxes = np.stack([x, zeros, x + 99, zeros + 99], 1)[None]
+
+    def check(kept):
+        assert kept[0].tolist() == [i % 2 == 0 for i in range(129)]
+    return boxes, np.ones((1, 129), bool), check
+
+
+def _far_case(rng):
+    """(b) The first box suppresses the whole last block (near copies of
+    it) and nothing in the six blocks between."""
+    boxes = _far_small_dets(rng, 2, 1000)
+    boxes[0, 0] = [200, 200, 299, 299]
+    boxes[0, 896:] = boxes[0, 0] + rng.randint(0, 4, (104, 1))
+
+    def check(kept):
+        assert kept[0, 0] and not kept[0, 896:].any()
+        assert kept[0, 1:896].sum() > 100
+    return boxes, np.ones((2, 1000), bool), check
+
+
+def _greedy_order_case(rng):
+    """(c) E in the first block is kept and suppresses L in the last; X,
+    after L, overlaps L alone: a suppressed box suppresses nothing, and
+    the late block leaves the first block's answer as it was."""
+    boxes = _far_small_dets(rng, 2, 1000)
+    boxes[0, 5] = [200, 0, 299, 99]     # E
+    boxes[0, 900] = [200, 10, 299, 109]  # L: IoU(E, L) = 9000 / 11000
+    boxes[0, 950] = [200, 20, 299, 119]  # X: IoU(L, X) the same, (E, X) 2/3
+
+    def check(kept):
+        assert kept[0, 5] and not kept[0, 900] and kept[0, 950]
+    return boxes, np.ones((2, 1000), bool), check
+
+
+def _validity_case(rng):
+    """(d) Every third box invalid, the last block wholly so; the invalid
+    boxes at 3 and 6 are copies of the valid ones that follow them."""
+    boxes = _far_small_dets(rng, 2, 1000)
+    boxes[0, 4], boxes[0, 7] = boxes[0, 3], boxes[0, 6]
+    valid = np.ones((2, 1000), bool)
+    valid[:, ::3] = False
+    valid[:, 896:] = False
+
+    def check(kept):
+        assert not kept[~valid].any()
+        assert kept[0, 4] and kept[0, 7]
+        assert kept.sum(1).min() > 50
+    return boxes, valid, check
+
+
+def _random_case(s, n):
+    def case(rng):
+        return (_far_small_dets(rng, s, n), np.ones((s, n), bool),
+                lambda kept: None)
+    return case
+
+
+_SCHEDULE_CASES = {
+    "chain_129": _chain_case,
+    "far_last_block": _far_case,
+    "greedy_order_across_blocks": _greedy_order_case,
+    "invalid_interleaved_and_tail": _validity_case,
+    "n129_s1": _random_case(1, 129),
+    "n1000_s2": _random_case(2, 1000),
+    "n1300_s8": _random_case(8, 1300),
+}
 
 
 @pytest.mark.parametrize("impl", [nms, nms_bitmask])
@@ -193,6 +282,30 @@ class TestBatchedNMSPallas:
         jitted = jax.jit(lambda b, s, v: batched_nms(b, s, v, 0.5, 40))(*args)
         assert np.array_equal(eager[0], jitted[0])
         assert np.array_equal(eager[1], jitted[1])
+
+    @pytest.mark.parametrize("case", sorted(_SCHEDULE_CASES))
+    def test_schedule_cases(self, rng, case):
+        """What the kernel's schedule could get wrong, each against both jnp
+        oracles, exactly: the resolve's worst case, the sweep's far end, the
+        greedy order across blocks, validity, and box counts that fill
+        neither the last block nor the last chunk."""
+        boxes, valid, check = _SCHEDULE_CASES[case](rng)
+        s, n = valid.shape
+        # scores fall with the index: the kernel sees the boxes in this order
+        scores = np.tile(np.linspace(1.0, 0.01, n, dtype=np.float32), (s, 1))
+        args = (jnp.asarray(boxes), jnp.asarray(scores), jnp.asarray(valid))
+        ki, kv = _jit_batched_nms(*args, 0.7, n)
+        for oracle in (nms, nms_bitmask):
+            ki2, kv2 = _jit_oracle(oracle, n)(*args)
+            assert np.array_equal(ki, ki2), oracle.__name__
+            assert np.array_equal(kv, kv2), oracle.__name__
+        kept = np.zeros((s, n), bool)
+        for i in range(s):
+            kept[i, np.asarray(ki)[i][np.asarray(kv)[i]]] = True
+        check(kept)
+        # and the kernel alone, on the boxes as given: an invalid box in the
+        # middle of a block neither survives nor suppresses
+        assert np.array_equal(_jit_keep_sorted(args[0], args[2]), kept)
 
 
 def test_generate_proposals_pallas_vs_xla(rng):
