@@ -21,33 +21,20 @@ Timing discipline: every repetition ends by fetching the loss scalar to the
 host — a barrier on any backend (the bytes cannot arrive before the step
 that computes them has run), and what a training loop's logging does anyway.
 
-The `*_msd8` recipes drive 8 optimizer steps per host dispatch
-(train.multi_step_dispatch — one lax.scan-ed program), paying the fixed
-per-dispatch overhead once per 8 steps instead of amortizing it with batch 2.
-The `*_flat` recipes run the flatcore storage mode (train.flat_params —
-fused flat-buffer optimizer update, train/flatcore.py), and
-`update_r101`/`update_detr` isolate the optimizer update itself (tree vs
-flat at full model size) so the many-buffer floor is a tracked number.
+`update_r101`/`update_detr` isolate the optimizer update itself
+(`apply_gradients` alone at full model size).
 
 Crash-durability: every completed config's row is flushed to
 <obs_dir>/partial.json (MX_RCNN_BENCH_PARTIAL overrides) the moment it
 lands — a sweep killed at its time limit keeps its finished measurements.
 
 MFU: analytic FLOPs from XLA's own cost model for the whole compiled
-program (fwd+bwd+update, x8 for msd8), divided by the published peak of the
+program (fwd+bwd+update), divided by the published peak of the
 device the cell ran on IN THE RECIPE'S COMPUTE DTYPE
 (obs/costs.py::peak_flops_for — one table keyed by device_kind, unknown
 devices and unpublished dtypes raise); every row carries a `compute_dtype`
 field and `ledger check` only grades rows against prior rows of the SAME
 dtype.
-
-The `*_bf16` recipes run graftcast's flatcore-native mixed precision
-(train.compute_dtype=bf16 + train.flat_params: f32 master buffers, ONE
-cast kernel per dtype buffer feeding the forward — train/precision.py);
-`update_r101_bf16` isolates the update+shadow-cast program so the
-cast's marginal cost over the plain flat update (`update_r101`, pinned
-f32 so its trend line keeps measuring the same program) is a tracked
-number.
 
 graftscope: every run also writes an event stream + folded summary to
 MX_RCNN_BENCH_OBS (default ./bench_obs) — per-config `bench` events,
@@ -239,35 +226,22 @@ def bench_config(cfg, reps: int = 5, iters: int = 20):
 
     from mx_rcnn_tpu.models.zoo import build_model, forward_train, init_params
     from mx_rcnn_tpu.parallel.mesh import create_mesh, shard_batch
-    from mx_rcnn_tpu.train import flatcore, precision
+    from mx_rcnn_tpu.train import precision
     from mx_rcnn_tpu.train.optimizer import build_optimizer
     from mx_rcnn_tpu.train.step import create_train_state, make_train_step
 
     policy = precision.policy_of(cfg)
 
     b = cfg.train.batch_images
-    multi = max(1, cfg.train.multi_step_dispatch)
     batch = (make_packed_batch(cfg) if cfg.image.canvas_pack
              else make_batch(cfg))
-    if multi > 1:
-        batch = {k: np.stack([v] * multi) for k, v in batch.items()}
-        iters = max(1, iters // multi)
     model = build_model(cfg)
     params = init_params(model, cfg, jax.random.PRNGKey(0))
-    # flatcore recipes (train.flat_params): flat-buffer state, fused
-    # update. Built directly (init_state) — flattening a fresh tree state
-    # would round-trip every zero opt slot through the host.
-    core = None
-    if flatcore.flat_mode_for(cfg):
-        core = flatcore.FlatCore(cfg, params, steps_per_epoch=1000)
-        state = core.init_state(params)
-    else:
-        tx = build_optimizer(cfg, params, steps_per_epoch=1000)
-        state = create_train_state(params, tx)
+    tx = build_optimizer(cfg, params, steps_per_epoch=1000)
+    state = create_train_state(params, tx)
     mesh = create_mesh(str(jax.device_count()))
-    step_fn = make_train_step(model, cfg, mesh=mesh, forward_fn=forward_train,
-                              flat_core=core)
-    batch = shard_batch(batch, mesh, stacked=multi > 1)
+    step_fn = make_train_step(model, cfg, mesh=mesh, forward_fn=forward_train)
+    batch = shard_batch(batch, mesh)
 
     rng = jax.random.PRNGKey(1)
     # AOT-compile ONCE and time the compiled executable directly: this
@@ -280,9 +254,6 @@ def bench_config(cfg, reps: int = 5, iters: int = 20):
     with compile_track.count() as cc:
         rng, k0 = jax.random.split(rng)
         compiled = step_fn.lower(state, batch, k0).compile()
-        # XLA cost analysis counts a lax.scan BODY once, not per trip
-        # (verified: the msd8 program reports the same flops as one
-        # step), so this is per-OPTIMIZER-STEP flops for every recipe.
         costs = obs_costs.executable_costs(compiled)
         flops = costs.get("flops", 0.0)
 
@@ -292,7 +263,6 @@ def bench_config(cfg, reps: int = 5, iters: int = 20):
             state, metrics = compiled(state, batch, k)
             float(np.asarray(metrics["TotalLoss"]))
 
-    imgs_per_dispatch = b * multi
     rates = []
     for _ in range(reps):
         t0 = time.perf_counter()
@@ -301,8 +271,7 @@ def bench_config(cfg, reps: int = 5, iters: int = 20):
             state, metrics = compiled(state, batch, k)
         # Barrier: fetch the scalar VALUE (see module docstring).
         float(np.asarray(metrics["TotalLoss"]))
-        rates.append(iters * imgs_per_dispatch
-                     / (time.perf_counter() - t0))
+        rates.append(iters * b / (time.perf_counter() - t0))
     img_s = statistics.median(rates)
     per_chip = img_s / jax.device_count()
     step_ms = 1000.0 * b / img_s  # per optimizer step
@@ -329,15 +298,14 @@ def bench_config(cfg, reps: int = 5, iters: int = 20):
 
 
 def bench_update_config(cfg, reps: int = 5, iters: int = 50):
-    """Isolated optimizer-update microbench: tree vs flat over the SAME
-    synthetic gradients at full model size — the ~6 ms many-buffer floor
-    (PERF.md r4 item 3) as a TRACKED number instead of a probe anecdote.
-    No forward/backward: the jitted program is exactly `apply_gradients`,
-    donated state, barrier = materializing the step counter's bytes."""
+    """Isolated optimizer-update microbench over synthetic gradients at
+    full model size. No forward/backward: the jitted program is exactly
+    `apply_gradients`, donated state, barrier = materializing the step
+    counter's bytes."""
     import jax
 
     from mx_rcnn_tpu.models.zoo import build_model, init_params
-    from mx_rcnn_tpu.train import flatcore, precision
+    from mx_rcnn_tpu.train import precision
     from mx_rcnn_tpu.train.optimizer import build_optimizer
     from mx_rcnn_tpu.train.step import create_train_state
 
@@ -350,8 +318,7 @@ def bench_update_config(cfg, reps: int = 5, iters: int = 50):
                                      p.shape) * 1e-3).astype(p.dtype),
         params)
     tx = build_optimizer(cfg, params, steps_per_epoch=1000)
-    core = flatcore.FlatCore(cfg, params, steps_per_epoch=1000)
-    n_leaves = len(core.table.segments)
+    n_leaves = len(jax.tree_util.tree_leaves(params))
 
     def timed(state, gr):
         fn = jax.jit(lambda s, g: s.apply_gradients(g), donate_argnums=(0,))
@@ -368,23 +335,10 @@ def bench_update_config(cfg, reps: int = 5, iters: int = 50):
             rates.append(1000.0 * (time.perf_counter() - t0) / iters)
         return statistics.median(rates)
 
-    # Flat state/grads are built BEFORE the tree timing: timed() donates
-    # its state, whose param leaves alias `params` — flattening afterwards
-    # would device_get deleted arrays.
-    flat_state = core.init_state(params)
-    fgrads = {d: jax.numpy.asarray(b)
-              for d, b in core.table.flatten(grads).items()}
     with compile_track.count() as cc:  # graftprof compile accounting
         tree_ms = timed(create_train_state(params, tx), grads)
-        flat_ms = timed(flat_state, fgrads)
     return {
         "tree_ms": round(tree_ms, 3),
-        # under compute_dtype=bf16 flat_ms INCLUDES the graftcast shadow
-        # cast (FlatCore.apply re-materializes the bf16 view buffer —
-        # one convert per dtype buffer); vs the f32-pinned update_r101
-        # row this isolates the cast's marginal per-step cost.
-        "flat_ms": round(flat_ms, 3),
-        "speedup": round(tree_ms / flat_ms, 3) if flat_ms else None,
         "param_leaves": n_leaves,
         "optimizer": cfg.train.optimizer,
         "compute_dtype": policy.short,
@@ -512,19 +466,16 @@ def flagship_cells() -> dict:
 
     # Flagship shapes: (600,1000)-scale COCO canvas padded to 640x1024,
     # full train proposal path. All five BASELINE families; C4 and FPN at
-    # batch 1 (reference recipe, r01-r03 comparison point), batch 2 (the
-    # Detectron-lineage recipe; amortizes fixed per-dispatch overhead) and
-    # multi-step dispatch (8 steps per host call; eliminates it).
-    def cfg_for(net, b, multi=1):
+    # batch 1 (reference recipe, r01-r03 comparison point) and batch 2
+    # (the Detectron-lineage recipe; amortizes fixed per-dispatch overhead).
+    def cfg_for(net, b):
         return generate_config(net, "coco", **{
-            "image.pad_shape": (640, 1024), "train.batch_images": b,
-            "train.multi_step_dispatch": multi})
+            "image.pad_shape": (640, 1024), "train.batch_images": b})
 
     train = {
         # BASELINE configs 1-2 (C4 lineage; headline family).
         "c4_r101": cfg_for("resnet101", 1),
         "c4_r101_b2": cfg_for("resnet101", 2),
-        "c4_r101_msd8": cfg_for("resnet101", 1, multi=8),
         # BASELINE config 3 (acceptance config).
         "fpn_r101": cfg_for("resnet101_fpn", 1),
         "fpn_r101_b2": cfg_for("resnet101_fpn", 2),
@@ -534,7 +485,6 @@ def flagship_cells() -> dict:
         "fpn_r101_b2_exact": generate_config("resnet101_fpn", "coco", **{
             "image.pad_shape": (640, 1024), "train.batch_images": 2,
             "network.proposal_topk": "exact"}),
-        "fpn_r101_msd8": cfg_for("resnet101_fpn", 1, multi=8),
         # BASELINE config 4 (+ b2: amortizes per-dispatch overhead and the
         # HBM-bound optimizer floor; PERF.md "batch>1 lever").
         "mask_r101_fpn": cfg_for("resnet101_fpn_mask", 1),
@@ -552,14 +502,6 @@ def flagship_cells() -> dict:
             "image.pad_shape": (608, 1024), "train.batch_images": 1}),
         "vgg16_voc_b2": generate_config("vgg", "PascalVOC", **{
             "image.pad_shape": (608, 1024), "train.batch_images": 2}),
-        # flatcore (train/flatcore.py): full-step A/B against the plain
-        # recipes above — the fused flat update vs the per-leaf chain.
-        "c4_r101_flat": generate_config("resnet101", "coco", **{
-            "image.pad_shape": (640, 1024), "train.batch_images": 1,
-            "train.flat_params": True}),
-        "detr_r50_flat": generate_config("detr_r50", "coco", **{
-            "image.pad_shape": (640, 1024), "train.batch_images": 1,
-            "train.flat_params": True}),
         # graftcanvas (image.canvas_pack): whole-batch canvas packing
         # A/B against the bucketed b2 recipes above — ONE compiled
         # train-step shape regardless of scale/orientation mix, content
@@ -575,30 +517,8 @@ def flagship_cells() -> dict:
             "image.canvas_shape": (1248, 1024)}),
         "fpn_r101_canvas": generate_config("resnet101_fpn", "coco", **{
             "train.batch_images": 2, "image.canvas_pack": True}),
-        # graftcast (train/precision.py): flatcore-native mixed
-        # precision — f32 flat master weights, ONE bf16 cast kernel per
-        # dtype buffer feeding the forward, f32 islands/grads/update.
-        # NOTE on A/B reading: every flat recipe inherits the bf16
-        # DEFAULT, so from round 8 on c4_r101_flat runs this same
-        # one-cast program at b1 — the per-leaf-cast flat baseline
-        # ENDED at round 7, and the one-cast win is read as the flat
-        # recipes' round-7→8 trend (same recipe, same bf16 dtype
-        # bucket). These b2 rows exist to grade the flagship batch
-        # geometry; rows carry compute_dtype so `ledger check` never
-        # grades them against a different dtype.
-        "c4_r101_bf16": generate_config("resnet101", "coco", **{
-            "image.pad_shape": (640, 1024), "train.batch_images": 2,
-            "train.flat_params": True, "train.compute_dtype": "bf16"}),
-        "fpn_r101_bf16": generate_config("resnet101_fpn", "coco", **{
-            "image.pad_shape": (640, 1024), "train.batch_images": 2,
-            "train.flat_params": True, "train.compute_dtype": "bf16"}),
     }
-    # Isolated optimizer-update microbench (tree vs flat) at full model
-    # size: the many-buffer floor as a tracked number.
-    # update_r101/update_detr are PINNED f32 so their trend lines keep
-    # measuring the exact pre-graftcast program (the pure flat update);
-    # update_r101_bf16 adds the shadow cast — the delta vs update_r101
-    # is the cast's marginal per-step cost.
+    # Isolated optimizer-update microbench at full model size.
     update = {
         "update_r101": generate_config("resnet101", "coco", **{
             "image.pad_shape": (640, 1024),
@@ -606,9 +526,6 @@ def flagship_cells() -> dict:
         "update_detr": generate_config("detr_r50", "coco", **{
             "image.pad_shape": (640, 1024),
             "train.compute_dtype": "f32"}),
-        "update_r101_bf16": generate_config("resnet101", "coco", **{
-            "image.pad_shape": (640, 1024),
-            "train.compute_dtype": "bf16"}),
     }
     # Inference path (SURVEY §4.2 call stack: test.py → Predictor →
     # pred_eval): the jitted detect program at the test proposal budget.

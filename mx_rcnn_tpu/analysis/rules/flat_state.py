@@ -1,15 +1,11 @@
 """flat-state-access: no index-poking into optimizer state in traced code.
 
-With flatcore (train/flatcore.py) the SAME logical state has two physical
-layouts — the optax tree (per-leaf) and dtype-segregated flat buffers —
-interchangeable at checkpoint boundaries. A jit-reachable
-``opt_state[...]`` subscript hard-codes ONE layout's internals (optax's
-chain position / namedtuple index, e.g. ``opt_state[1][0].trace``), which
-silently breaks the moment the state arrives in the other form or optax
-re-arranges its wrappers. Inside traced code, optimizer/param state may
-only be touched through the flatcore segment API
-(``SegmentTable.segment_view`` / ``unflatten``) or whole-tree
-``tree_map`` — both are layout-agnostic.
+A jit-reachable ``opt_state[...]`` subscript hard-codes optax's wrapper
+order (chain position / namedtuple index, e.g.
+``opt_state[1][0].trace``), which silently breaks the moment optax
+re-arranges its wrappers or ``build_optimizer`` changes its chain. Inside
+traced code, optimizer state may only be touched through a whole-tree
+``tree_map``, which does not depend on the layout.
 
 Host-side code (checkpoint conversion, tests) may still index: the rule
 only fires inside jit-reachable functions (tracing.py reachability).
@@ -29,9 +25,8 @@ from mx_rcnn_tpu.analysis.engine import FileContext, Finding
 from mx_rcnn_tpu.analysis.tracing import dotted_name
 
 NAME = "flat-state-access"
-RATIONALE = ("a jit-reachable `opt_state[...]` subscript hard-codes one "
-             "physical state layout; flatcore's flat/tree interchange "
-             "requires the segment API or whole-tree tree_map")
+RATIONALE = ("a jit-reachable `opt_state[...]` subscript hard-codes "
+             "optax's wrapper order; go through a whole-tree tree_map")
 
 
 def _subscript_root(node: ast.AST) -> Optional[str]:
@@ -65,6 +60,5 @@ def check(ctx: FileContext) -> Iterator[Finding]:
         yield ctx.finding(
             NAME, node,
             "optimizer state indexed by position inside jit-reachable "
-            "code — layout-fragile under the flat/tree state interchange "
-            "(train/flatcore.py); go through the flatcore segment API or "
-            "a whole-tree tree_map")
+            "code — hard-codes optax's wrapper order; go through a "
+            "whole-tree tree_map")

@@ -180,40 +180,19 @@ class TrainConfig:
     # islands (norm statistics, losses, bbox decode/encode, NMS scores)
     # and f32 gradients/optimizer updates; "f32" runs everything float32
     # (the numerics reference the bf16 parity gates compare against).
-    # Checkpoints are f32 tree-form either way and interchange between
-    # the two bit-for-bit at the master-weight level. Under
-    # train.flat_params the bf16 param casts collapse to ONE cast kernel
-    # per dtype buffer (the FlatTrainState.compute shadow); tree mode
-    # keeps flax's per-leaf promotion (same values). Accepts the long
+    # Checkpoints are f32 either way and interchange between the two
+    # bit-for-bit at the master-weight level. The modules cast their f32
+    # leaves at use (flax's per-leaf promotion). Accepts the long
     # spellings "float32"/"bfloat16" too.
     compute_dtype: str = "bf16"
     # Optimizer slot dtype: "float32" (default) or "bfloat16" — stores
     # the SGD momentum / AdamW first-moment accumulator in bf16 (halves
     # that tree's memory; the AdamW second moment always stays f32 — its
-    # precision matters for the rsqrt). NOTE measured NEUTRAL on step
-    # time on v5e (7.14 vs 7.21 ms DETR update — the update cost is a
-    # formulation-invariant floor, PERF.md r4); this knob is a MEMORY
-    # lever for big models, not a speed lever here.
+    # precision matters for the rsqrt). A MEMORY lever for big models,
+    # not a speed lever: the update has no device time of its own
+    # (`stage.update_ms.train` 0.0001 ms, ledger, PR 29: fused into the
+    # weight-gradient convolutions).
     opt_state_dtype: str = "float32"
-    # flatcore (train/flatcore.py): store all trainable leaves in ONE
-    # contiguous dtype-segregated buffer per tree (params / momentum /
-    # both Adam moments) with a static segment table; the optimizer
-    # update runs as a handful of fused elementwise kernels over the
-    # flat buffers instead of hundreds of per-leaf kernels (the ~6 ms
-    # many-buffer update floor, PERF.md r4 item 3), and the DP gradient
-    # allreduce becomes one psum per buffer. Exact — parity-gated
-    # against the tree path (tests/test_flatcore.py). TP/PP configs
-    # route back to the per-leaf path (a sharded leaf has no contiguous
-    # image in a flat buffer). Checkpoints stay in TREE form on disk,
-    # interchangeable between modes. Default off until the on-chip A/B
-    # (bench.py update_* recipes) confirms the win.
-    flat_params: bool = False
-    # Multi-step dispatch: each host call drives this many FULL optimizer
-    # steps through one jitted lax.scan over step-stacked batches
-    # (train/step.py), amortizing the fixed per-dispatch host overhead
-    # across K steps. Orthogonal to grad_accum_steps (which merges micro-grads
-    # into ONE update; this performs K separate updates). 1 = off.
-    multi_step_dispatch: int = 1
     # Data
     batch_images: int = 1  # images per device
     shuffle: bool = True

@@ -17,31 +17,10 @@ forwards share their input/output contracts.
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
 
 from mx_rcnn_tpu.config import Config
 from mx_rcnn_tpu.models import faster_rcnn as _c4
 from mx_rcnn_tpu.models import fpn as _fpn
-
-
-def param_flatten_spec(params):
-    """Canonical flatten spec: ((path, shape, dtype), ...) for every leaf.
-
-    THE one ordering contract between a model's param tree and flatcore's
-    segment tables (train/flatcore.py): `jax.tree_util` flatten order over
-    the flax param dict (keys sorted, depth-first), which is deterministic
-    for a given tree structure. Every family goes through init_params →
-    plain nested dicts, so the spec is derivable from any state that holds
-    the tree — params, gradients, or optimizer slots — and two trees with
-    the same spec are segment-compatible buffer-for-buffer.
-    """
-    flat, _ = jax.tree_util.tree_flatten_with_path(params)
-    out = []
-    for path, leaf in flat:
-        keys = [str(getattr(p, "key", getattr(p, "idx", p))) for p in path]
-        out.append(("/".join(keys), tuple(leaf.shape),
-                    jnp.dtype(leaf.dtype).name))
-    return tuple(out)
 
 
 def _is_pyramid_model(model) -> bool:

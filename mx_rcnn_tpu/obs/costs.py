@@ -120,9 +120,9 @@ def batch_pad_waste(batch) -> Dict[str, Any]:
     is the image tensor's static (H, W) × its PLANE count — for a
     bucketed batch that is one canvas per im_info row, for a packed
     batch one per canvas plane holding several rows, so packed rows
-    honestly report canvas utilization. Works on plain and multi-step-
-    dispatch-stacked batches (leading-axes flattening). Returns {} when
-    the batch lacks the train contract keys (custom loaders)."""
+    honestly report canvas utilization (leading-axes flattening).
+    Returns {} when the batch lacks the train contract keys (custom
+    loaders)."""
     try:
         image = batch["image"]
         info = np.asarray(batch["im_info"], np.float64)

@@ -1,7 +1,7 @@
 """graftpulse host layer — health folding, anomaly tripwires, flight recorder.
 
 train/health.py computes the numerics signal INSIDE the compiled step
-(per-buffer nonfinite counts + squared norms of grads/params/update,
+(whole-tree nonfinite counts + squared norms of grads/params/update,
 plus the pooled loss, returned as extra step outputs). This module is
 the host half:
 
@@ -44,14 +44,10 @@ from typing import Any, Callable, Dict, List, Optional
 from mx_rcnn_tpu.logger import logger
 from mx_rcnn_tpu.obs.events import _json_default
 
-#: the train/health.py key suffixes/prefixes (kept literal here so this
-#: module stays importable without jax — the contract is pinned by tests)
+#: the train/health.py key suffixes (kept literal here so this module
+#: stays importable without jax — the contract is pinned by tests)
 _NF = "/nf"
 _SQ = "/sq"
-#: train/health.py PIN_PREFIX — full device buffers riding the health
-#: dict purely as program-output pins (CPU XLA schedule quirk); NEVER
-#: pulled to host, skipped by the cadenced read below.
-_PIN = "_pin/"
 
 
 class NumericsAnomaly(Exception):
@@ -186,8 +182,7 @@ class HealthMonitor:
         it into a ``health`` event and run the tripwires."""
         if self._latest is None:
             return None
-        vals = {k: float(v) for k, v in self._latest.items()
-                if not k.startswith(_PIN)}
+        vals = {k: float(v) for k, v in self._latest.items()}
         self._latest = None
         loss = vals.pop("loss", None)
         nonfinite = {k[:-len(_NF)]: int(v) for k, v in vals.items()

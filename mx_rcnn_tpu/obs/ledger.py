@@ -25,8 +25,7 @@ which no code path of the repo writes.
   step ms, HBM, pad waste, compile cost).
 - ``check`` diffs candidate rows against the BEST prior row per config
   and exits non-zero on a throughput or MFU regression past the
-  threshold (default 10%) — the regression gate the next chip window's
-  flatcore A/B lands under.
+  threshold (default 10%).
 
 stdlib-only, like ``obs.report`` — a ledger can be appended/folded on
 any machine the JSON can be copied to.
@@ -45,7 +44,7 @@ from typing import Any, Dict, Iterable, List, Optional, Tuple
 #: else a recipe emits stays in the source artifact, not the ledger).
 _METRIC_FIELDS = (
     "img_s_per_chip", "mfu", "step_ms", "hbm_bytes", "pad_waste",
-    "compile_s", "n_executables", "tree_ms", "flat_ms", "speedup",
+    "compile_s", "n_executables", "tree_ms",
     "ms_per_img", "error", "timeout_s", "compute_dtype",
     # environment-drift attribution (graftpulse satellite): a cross-run
     # regression should be pinnable to an env change — jaxlib upgrade,

@@ -100,13 +100,11 @@ def _percentile(sorted_vals: List[float], pct: float) -> float:
     return sorted_vals[idx]
 
 
-def _fold_costs(cost_events, timed, all_step_ms: List[float],
-                multi: int) -> Dict[str, Any]:
+def _fold_costs(cost_events, timed,
+                all_step_ms: List[float]) -> Dict[str, Any]:
     """Join per-bucket XLA cost accounting (graftprof `cost` events) with
     the measured step times of that bucket's canvas → per-bucket and
-    aggregate MFU. ``multi`` (train.multi_step_dispatch) converts a
-    dispatch's wall time into per-optimizer-step time — cost_analysis
-    counts a scan body once, so flops are already per step."""
+    aggregate MFU."""
     buckets = []
     agg_flops = agg_time_s = 0.0
     for c in cost_events:
@@ -119,11 +117,11 @@ def _fold_costs(cost_events, timed, all_step_ms: List[float],
         p50 = _percentile(in_bucket, 50)
         flops = c.get("flops")
         peak = c.get("peak_flops") or 0.0
-        step_s = (p50 / 1e3) / max(1, multi)
+        step_s = p50 / 1e3
         mfu = (flops / step_s / peak
                if flops and step_s > 0 and peak > 0 else None)
         if flops and in_bucket and p50 > 0:
-            agg_flops += flops * len(in_bucket) * max(1, multi)
+            agg_flops += flops * len(in_bucket)
             agg_time_s += (p50 / 1e3) * len(in_bucket)
         buckets.append({
             "canvas": canvas,
@@ -184,8 +182,7 @@ def summarize(events: List[Dict[str, Any]]) -> Dict[str, Any]:
     # graftprof: per-bucket cost accounting joined with measured step
     # time → computed MFU (obs/costs.py emits one `cost` event per
     # compiled shape bucket; step events carry the batch canvas).
-    multi = run_meta.get("multi_step_dispatch") or 1
-    cost = _fold_costs(by_type.get("cost", ()), timed, step_ms, multi)
+    cost = _fold_costs(by_type.get("cost", ()), timed, step_ms)
     pad_vals = sorted(e["pad_waste"] for e in timed if "pad_waste" in e)
     pad_waste = (round(_percentile(pad_vals, 50), 4) if pad_vals else None)
 

@@ -3,8 +3,7 @@
 The split follows the loop's own structure (tools/train.py::fit_detector):
 
   data_wait_ms  — time blocked in the loader's ``next()`` (host input
-                  pipeline: decode/augment/stack; includes the
-                  multi-step-dispatch group stacking).
+                  pipeline: decode/augment/stack).
   dispatch_ms   — from batch-in-hand to the train step's RETURN. The step
                   is an async dispatch, so in steady state this is the
                   host-side enqueue cost — UNLESS the device queue is

@@ -48,11 +48,9 @@ def create_mesh(spec: str = "", devices=None) -> Mesh:
     return Mesh(arr, ("data", "model"))
 
 
-def batch_sharding(mesh: Mesh, stacked: bool = False) -> NamedSharding:
-    """Leading-axis (batch) sharding over the data axis; ``stacked`` for
-    multi-step-dispatch batches whose leading axis is the step index
-    (leaves (K, B, ...) — batch axis 1 shards, step axis replicates)."""
-    return NamedSharding(mesh, P(None, "data") if stacked else P("data"))
+def batch_sharding(mesh: Mesh) -> NamedSharding:
+    """Leading-axis (batch) sharding over the data axis."""
+    return NamedSharding(mesh, P("data"))
 
 
 def replicated(mesh: Mesh) -> NamedSharding:
@@ -74,17 +72,17 @@ def place_replicated(tree, mesh: Mesh):
     return jax.device_put(tree, sharding)
 
 
-def shard_batch(batch: dict, mesh: Mesh, stacked: bool = False) -> dict:
+def shard_batch(batch: dict, mesh: Mesh) -> dict:
     """Place a host batch dict onto the mesh, sharded along the batch axis
-    (axis 0, or axis 1 of a ``stacked`` multi-step batch).
+    (axis 0).
 
     The analog of DataParallelExecutorGroup slicing a batch across contexts
     (reference: mxnet executor_group via work_load_list) — here one
-    device_put with a NamedSharding; the batch's sharded dim must divide by
+    device_put with a NamedSharding; the batch's leading dim must divide by
     the data-axis size. Under a multi-process runtime each process passes
     its LOCAL slice and the global array is assembled across hosts
     (parallel/distributed.py).
     """
     from mx_rcnn_tpu.parallel.distributed import make_global_batch
 
-    return make_global_batch(batch, mesh, batch_sharding(mesh, stacked))
+    return make_global_batch(batch, mesh, batch_sharding(mesh))

@@ -75,38 +75,6 @@ TP_RULES: Tuple[Tuple[str, P], ...] = (
 )
 
 
-def flat_segment_specs(params, specs):
-    """Map per-leaf PartitionSpecs onto flatcore buffer segments.
-
-    The flat path (train/flatcore.py) concatenates leaves into one
-    replicated buffer per dtype, so it is only sound when EVERY leaf is
-    replicated — then each buffer takes ``P()`` and the DP gradient
-    allreduce is ONE psum per buffer. Any sharded leaf (the TP/PP rules
-    above) has no contiguous image inside a flat buffer: return None and
-    the caller keeps the per-leaf tree path for the whole state (mixing
-    per-segment layouts inside one buffer would force GSPMD to reshard
-    every step — worse than the many-buffer floor it replaces).
-
-    graftcast: the compute shadow (``FlatTrainState.compute``, one
-    buffer per float dtype group under ``train.compute_dtype=bf16``)
-    inherits its MASTER buffer's placement by construction — it is
-    derived state keyed by the same dtype-group names, so the ``P()``
-    verdict here covers it, and the future ZeRO-1 flat shards (ROADMAP)
-    shard master and shadow along the same segment boundaries with the
-    cast running shard-local.
-    """
-    import jax.numpy as jnp
-
-    flat_specs = jax.tree_util.tree_leaves(
-        specs, is_leaf=lambda x: isinstance(x, P))
-    for spec in flat_specs:
-        if isinstance(spec, P) and any(ax is not None for ax in spec):
-            return None
-    dtypes = {jnp.dtype(leaf.dtype).name
-              for leaf in jax.tree_util.tree_leaves(params)}
-    return {d: P() for d in sorted(dtypes)}
-
-
 def elastic_mesh_spec(data: int, model: int, n_devices: int,
                       micro_batch: int, mode: str = "shrink") -> str:
     """Re-derive a mesh spec when the backend comes back with a different

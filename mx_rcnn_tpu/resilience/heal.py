@@ -14,8 +14,7 @@ recovery loop (tools/train.py::fit_detector):
    shape error, an INVALID_ARGUMENT — propagates untouched.
 2. **Capture.** An in-memory emergency capture of the last known-good
    state: first a *live* capture (``jax.device_get`` of the current
-   train state into host-OWNED numpy copies — tree form even from flat
-   buffers, via ``FlatCore.tree_state``); if the post-loss state is
+   train state into host-OWNED numpy copies); if the post-loss state is
    unreadable (donated buffers on a dead backend poison the read), fall
    back to the standing host snapshot the loop refreshes every
    ``resilience.heal_snapshot_dispatches`` dispatches — the replayed
@@ -32,8 +31,8 @@ recovery loop (tools/train.py::fit_detector):
 4. **Re-shard.** The backend may come back with a DIFFERENT device
    count (spot reclaim, partial slice): the caller rebuilds the mesh via
    ``parallel.partition.elastic_mesh_spec`` (model axis preserved, data
-   axis re-cut to the largest batch-divisible size), re-derives
-   partition specs and re-cuts flatcore buffers against the new mesh —
+   axis re-cut to the largest batch-divisible size) and re-derives
+   partition specs against the new mesh —
    the GLOBAL batch is invariant, so the loader, the LR schedule and the
    loss trajectory carry straight across the shrink.
 

@@ -77,23 +77,6 @@ def load_gt_roidbs(cfg: Config, image_set: Optional[str] = None,
     return filter_roidb(merge_roidb(roidbs))
 
 
-def _dispatch_batches(loader, multi: int):
-    """Group the loader stream into multi-step-dispatch super-batches:
-    K consecutive batches stacked on a NEW leading step axis (leaves
-    (K, B, ...) — train/step.py scans one optimizer step per row). K=1
-    passes batches through untouched. A trailing partial group is dropped
-    (logged by fit_detector) — an epoch boundary effect only."""
-    if multi <= 1:
-        yield from loader
-        return
-    group = []
-    for batch in loader:
-        group.append(batch)
-        if len(group) == multi:
-            yield {k: np.stack([b[k] for b in group]) for k in group[0]}
-            group = []
-
-
 def fit_detector(
     cfg: Config,
     roidb: List[Dict],
@@ -141,7 +124,7 @@ def fit_detector(
     TRANSIENT step-time backend loss no longer kills the run — the loop
     captures the last known-good host state in memory, re-acquires the
     backend under resilience.backend_deadline_s, rebuilds the session
-    (mesh, partition specs, flat buffers re-cut) and continues, emitting
+    (mesh, partition specs) and continues, emitting
     a `heal` event. If the backend returns with fewer devices the data
     axis is re-cut to the largest batch-divisible size — the GLOBAL
     batch is invariant, so the loader stream, LR schedule and loss
@@ -155,13 +138,11 @@ def fit_detector(
     that READS the just-saved checkpoint from disk must not assume it has
     landed (it is durable by the next epoch's save and before return).
 
-    epoch_callback(epoch, state, bag): with train.flat_params the state is
-    a FlatTrainState — `.step` and `.params` (host-owned copies) keep
-    working, but there is no `.opt_state` tree; use the checkpoint for
-    optimizer inspection. A graftheal recovery that lands inside the
-    epoch-end window REPLAYS it (event, save, callback) rather than
-    dropping it — callbacks should tolerate a rare re-invocation for
-    the same epoch.
+    epoch_callback(epoch, state, bag): the state is the loop's TrainState
+    (`.step`, `.params`, `.opt_state`). A graftheal recovery that lands
+    inside the epoch-end window REPLAYS it (event, save, callback) rather
+    than dropping it — callbacks should tolerate a rare re-invocation
+    for the same epoch.
     """
     from mx_rcnn_tpu.parallel.distributed import (
         is_primary,
@@ -313,13 +294,7 @@ def fit_detector(
     # devices carry more rows each), and the checkpoint meta sidecar
     # records it so a resume onto a different topology can convert a
     # dispatch tag minted under another mesh (see below).
-    multi = max(1, cfg.train.multi_step_dispatch)
-    ipd = cfg.train.batch_images * accum * n_data * multi
-    disp_per_epoch = max(1, steps_per_epoch // multi)
-    if multi > 1 and len(loader) % multi:
-        logger.warning(
-            "multi_step_dispatch=%d drops %d trailing batch(es) per epoch "
-            "(loader yields %d)", multi, len(loader) % multi, len(loader))
+    ipd = cfg.train.batch_images * accum * n_data
 
     # Resume discovery BEFORE building the optimizer: a restored opt_state
     # carries optax's schedule counter; without one the LR schedule is
@@ -377,11 +352,11 @@ def fit_detector(
                 # (train/optimizer.py).
                 opt_state = rebase_schedule_count(
                     opt_state,
-                    begin_epoch * steps_per_epoch + skip_dispatch * multi)
+                    begin_epoch * steps_per_epoch + skip_dispatch)
                 logger.warning(
                     "elastic resume: optimizer counters rebased to step "
                     "%d (this run's units)",
-                    begin_epoch * steps_per_epoch + skip_dispatch * multi)
+                    begin_epoch * steps_per_epoch + skip_dispatch)
         logger.info("resumed from %s epoch %d%s (opt_state %s)", prefix,
                     resume_epoch,
                     f" dispatch {resume_dispatch}"
@@ -405,7 +380,7 @@ def fit_detector(
             cfg, mesh=mesh, prefix=prefix, batch_size=ipd,
             steps_per_epoch=steps_per_epoch, begin_epoch=begin_epoch,
             end_epoch=end_epoch, grad_accum=accum,
-            multi_step_dispatch=multi, compute_dtype=policy.short))
+            compute_dtype=policy.short))
         if cfg.obs.track_compiles:
             compile_track.activate(obs_log)
         # graftprof: trace windows (obs.trace_at_step counts dispatches
@@ -500,27 +475,9 @@ def fit_detector(
                                 "with primary-only saves",
                          hosts=n_hosts)
     elif cfg.train.async_checkpoint:
-        from mx_rcnn_tpu.train import flatcore as _flatcore
+        from mx_rcnn_tpu.train.checkpoint import CheckpointWriter
 
-        if (_flatcore.flat_mode_for(cfg)
-                and jax.default_backend() == "cpu"):
-            # Flat mode on the CPU backend: the background tensorstore
-            # write racing the flat step's large host-buffer churn
-            # (113+ MB donated buffers and backward concatenates every
-            # step) crashes in the native allocator — reproduced as
-            # free(): invalid pointer under MALLOC_CHECK_ with flat+async
-            # only; tree+async and flat+sync run clean. On TPU the step's
-            # buffers live in HBM, not host malloc, so async stays on.
-            # (flat_mode_for without params is the cfg-level routing —
-            # the rare TP-spec downgrade inside the session would only
-            # make this choice conservative, never unsafe.)
-            logger.info("flatcore on CPU backend: epoch checkpoints go "
-                        "synchronous (async writer would race the flat "
-                        "step's host allocator)")
-        else:
-            from mx_rcnn_tpu.train.checkpoint import CheckpointWriter
-
-            writer = CheckpointWriter()
+        writer = CheckpointWriter()
 
     # graftguard preemption (resilience/preempt.py): the handlers only
     # RECORD the signal; the loop below honors it at step boundaries.
@@ -584,7 +541,7 @@ def fit_detector(
 
     # Per-session device-facing objects, (re)assigned by the session loop
     # below; declared here so the closures and the return path see them.
-    state = flat_core = bag = None
+    state = bag = None
     pos = (carry.epoch, carry.dispatch)
     # Coordinated-stop latch: this host has published its preemption
     # request to the quorum (at most one request per run — the agreed
@@ -615,16 +572,13 @@ def fit_detector(
 
     def _capture() -> HealCarry:
         """graftheal's in-memory emergency capture: the live train state
-        as host-OWNED tree-form copies (np.array, never device views —
+        as host-OWNED copies (np.array, never device views —
         the backend they came from is about to be torn down), tagged
         with its position and the drained metric sums."""
         if state is None:
             raise RuntimeError("no live state to capture yet")
-        if flat_core is not None:
-            cap_params, cap_opt = flat_core.tree_state(state)
-        else:
-            cap_params = host_tree_copy(state.params)
-            cap_opt = host_tree_copy(state.opt_state)
+        cap_params = host_tree_copy(state.params)
+        cap_opt = host_tree_copy(state.opt_state)
         if sched_begin:
             # This session's optimizer was built FRESH with its schedule
             # offset by begin_step, so its counters are session-relative
@@ -633,7 +587,7 @@ def fit_detector(
             # opt_state is present — so normalize to the capture
             # position (== sched_begin + updates this session).
             cap_opt = rebase_schedule_count(
-                cap_opt, pos[0] * steps_per_epoch + pos[1] * multi)
+                cap_opt, pos[0] * steps_per_epoch + pos[1])
         return HealCarry(params=cap_params, opt_state=cap_opt,
                          epoch=pos[0], dispatch=pos[1],
                          bag=bag.snapshot() if bag is not None else None)
@@ -661,12 +615,8 @@ def fit_detector(
                              agreed=[at_epoch, at_dispatch])
         saved = None
         if need_save and cfg.resilience.preempt_save and is_primary():
-            if flat_core is not None:
-                save_params, save_opt = flat_core.tree_state(state)
-            else:
-                save_params, save_opt = state.params, state.opt_state
             saved = save_checkpoint(
-                prefix, at_epoch, save_params, save_opt,
+                prefix, at_epoch, state.params, state.opt_state,
                 means=cfg.train.bbox_means, stds=cfg.train.bbox_stds,
                 num_classes=cfg.dataset.num_classes, dispatch=at_dispatch,
                 meta=_ckpt_meta(at_epoch, at_dispatch, hosts=arrived))
@@ -675,8 +625,7 @@ def fit_detector(
         signum = guard.signum if guard is not None else None
         if obs_log.enabled:
             obs_log.emit("preempt", signal=signum,
-                         step=(at_epoch * steps_per_epoch
-                               + (at_dispatch or 0) * multi),
+                         step=at_epoch * steps_per_epoch + (at_dispatch or 0),
                          saved=saved)
         if recorder is not None:
             recorder.dump("preempt")
@@ -724,7 +673,7 @@ def fit_detector(
     try:
         while True:  # one iteration per backend session; graftheal re-enters
             try:
-                state = flat_core = bag = None
+                state = bag = None
                 pos = (carry.epoch, carry.dispatch)
                 if cost_tracker is not None:
                     # New session, possibly a new per-device program
@@ -782,16 +731,14 @@ def fit_detector(
                             loader = _build_loader(n_local)
                             steps_per_epoch = max(len(loader), 1)
                             ipd = (cfg.train.batch_images * accum
-                                   * new_data * multi)
-                            disp_per_epoch = max(1,
-                                                 steps_per_epoch // multi)
+                                   * new_data)
                             images_done = carry.dispatch * old_ipd_live
                             carry.dispatch = images_done // ipd
                             if carry.opt_state is not None:
                                 carry.opt_state = rebase_schedule_count(
                                     carry.opt_state,
                                     carry.epoch * steps_per_epoch
-                                    + carry.dispatch * multi)
+                                    + carry.dispatch)
                             logger.warning(
                                 "elastic rescale: global batch now %d "
                                 "image(s)/dispatch (was %d); LR schedule "
@@ -800,7 +747,7 @@ def fit_detector(
                                 "nominal run impossible by construction",
                                 ipd, old_ipd_live,
                                 carry.epoch * steps_per_epoch
-                                + carry.dispatch * multi)
+                                + carry.dispatch)
                     healer.note_devices(int(mesh.devices.size),
                                         mesh.devices.flat[0].platform)
 
@@ -809,8 +756,7 @@ def fit_detector(
                 # the schedule by begin_step instead (never both).
                 b_epoch, b_skip = carry.epoch, carry.dispatch
                 sched_begin = (0 if carry.opt_state is not None
-                               else b_epoch * steps_per_epoch
-                               + b_skip * multi)
+                               else b_epoch * steps_per_epoch + b_skip)
                 tx = build_optimizer(cfg, carry.params, steps_per_epoch,
                                      begin_step=sched_begin)
                 state = create_train_state(carry.params, tx)
@@ -819,7 +765,7 @@ def fit_detector(
                 if b_epoch or b_skip:
                     state = state.replace(
                         step=jax.numpy.asarray(
-                            b_epoch * steps_per_epoch + b_skip * multi,
+                            b_epoch * steps_per_epoch + b_skip,
                             jax.numpy.int32))
 
                 # Partition specs are RE-DERIVED against the session's
@@ -840,68 +786,26 @@ def fit_detector(
                             "axis is 1 (build the mesh as '<data>x"
                             "<model>', e.g. --tpu-mesh 4x2)")
 
-                # flatcore (train/flatcore.py): persistent flat parameter/
-                # optimizer storage — the update becomes a handful of
-                # fused kernels and the DP allreduce one psum per buffer.
-                # TP/PP (sharded-leaf) runs route back to the per-leaf
-                # path inside flat_mode_for. Checkpoints (and the heal
-                # carry) stay in TREE form — tree_state below — so every
-                # restore path is mode-agnostic and a healed session
-                # simply RE-CUTS the buffers via the SegmentTable.
-                if getattr(cfg.train, "flat_params", False):
-                    from mx_rcnn_tpu.train import flatcore as _flatcore
-
-                    if _flatcore.flat_mode_for(cfg, params=state.params,
-                                               param_specs=param_specs):
-                        flat_core = _flatcore.FlatCore(
-                            cfg, state.params, steps_per_epoch,
-                            begin_step=sched_begin)
-                        if carry.opt_state is not None:
-                            state = flat_core.flatten_state(state)
-                        else:
-                            # Fresh slots: build the flat state directly —
-                            # flatten_state would device_get every zero
-                            # leaf of the per-leaf opt_state just to
-                            # re-upload it as flat zeros.
-                            state = flat_core.init_state(
-                                state.params).replace(
-                                step=jax.numpy.asarray(state.step,
-                                                       jax.numpy.int32))
-                        logger.info(
-                            "flatcore: %d leaves -> %d flat buffer(s) %s",
-                            len(flat_core.table.segments),
-                            len(flat_core.table.sizes),
-                            {d: n for d, n
-                             in flat_core.table.sizes.items()})
-
                 if param_specs is None:
                     # One compile of the train step, not two
                     # (parallel/mesh.py::place_replicated); a TP state
                     # was placed by shard_train_state above.
                     state = place_replicated(state, mesh)
 
-                # Donation on the CPU backend is OFF — for every storage
-                # mode, not just flat. Two observed corruption families:
-                # (1) PR 5's flat crash — donating the ~100 MB flat
-                # buffers races the CPU client's async execution (the
-                # donated input of an enqueued step is reclaimed/
-                # munmapped while referenced; segfault wanders over
-                # later allocs); (2) the graftheal/resume shape — a
-                # session rebuilt from HOST numpy trees (checkpoint
-                # restore, heal carry) feeds numpy-backed arrays into a
-                # donating step, and CPU zero-copy + donation writes
-                # into/frees memory numpy owns (observed in the heal
-                # shrink gate as 1e18 losses one dispatch after the
-                # heal, or a segfault). Donation is an HBM-footprint
-                # optimization — on the host-memory backend correctness
-                # wins. TPU keeps it.
+                # Donation on the CPU backend is OFF: a session rebuilt
+                # from HOST numpy trees (checkpoint restore, heal carry)
+                # feeds numpy-backed arrays into a donating step, and CPU
+                # zero-copy + donation writes into/frees memory numpy
+                # owns (observed in the heal shrink gate as 1e18 losses
+                # one dispatch after the heal, or a segfault). Donation
+                # is an HBM-footprint optimization — on the host-memory
+                # backend correctness wins. TPU keeps it.
                 donate = jax.default_backend() != "cpu"
                 step_fn = make_train_step(model, cfg, mesh=mesh,
                                           donate=donate,
                                           forward_fn=(forward_fn
                                                       or forward_train),
                                           param_specs=param_specs,
-                                          flat_core=flat_core,
                                           health=health_on)
                 # Per-dispatch rng keys are derived from the dispatch's
                 # GLOBAL index (fold_in), not a run-position-dependent
@@ -917,7 +821,7 @@ def fit_detector(
                         # saw.
                         loader.set_epoch(epoch)
                     skip = b_skip if epoch == b_epoch else 0
-                    batches = _dispatch_batches(loader, multi)
+                    batches = loader
                     if skip:
                         logger.info(
                             "mid-epoch resume: skipping %d already-"
@@ -946,17 +850,15 @@ def fit_detector(
                             # optimizer step K.
                             chaos_spec.fire(
                                 "train_dispatch",
-                                step=(epoch * steps_per_epoch
-                                      + (i + 1) * multi))
+                                step=epoch * steps_per_epoch + i + 1)
                         with timer.span("train.key"):
                             # the iteration's first device dispatch: with
                             # the device's queue full it is HERE that the
                             # loop blocks (read on the chip, PR 25)
                             k = jax.random.fold_in(  # graftlint: disable=prng-key-reuse — the root is folded with a DISTINCT global dispatch index each iteration (the resumable-key derivation; see the rng comment above)
-                                rng, epoch * disp_per_epoch + i)
+                                rng, epoch * steps_per_epoch + i)
                         with timer.span("train.place"):
-                            sharded = shard_batch(batch, mesh,
-                                                  stacked=multi > 1)
+                            sharded = shard_batch(batch, mesh)
                         if cost_tracker is not None:
                             # One AOT cost capture per compiled shape
                             # bucket (dict lookup otherwise) — the
@@ -1000,7 +902,7 @@ def fit_detector(
                                 healer.set_fallback(_capture())
                         if chaos_spec.active:
                             chaos_spec.maybe_sigterm(
-                                epoch * steps_per_epoch + done * multi)
+                                epoch * steps_per_epoch + done)
                         if stopper is not None:
                             # Coordinated preemption (graftquorum): the
                             # signaled host PROPOSES its next boundary;
@@ -1009,7 +911,7 @@ def fit_detector(
                             # one barrier+publish in _honor_preemption.
                             # The un-signaled steady state costs one
                             # store read per dispatch.
-                            gdone = epoch * disp_per_epoch + done
+                            gdone = epoch * steps_per_epoch + done
                             if (guard is not None and guard.requested
                                     and not stop_requested):
                                 stopper.request(gdone)
@@ -1055,7 +957,7 @@ def fit_detector(
                     # (data/loader.py).
                     if hasattr(loader, "close"):
                         loader.close()
-                    boundary = (epoch + 1) * disp_per_epoch
+                    boundary = (epoch + 1) * steps_per_epoch
                     if stopper is not None:
                         # Stop check BEFORE the epoch barrier: a host
                         # already waiting in the barrier cannot publish
@@ -1081,19 +983,11 @@ def fit_detector(
                     if is_primary() and (
                             (epoch + 1) % max(1, checkpoint_period) == 0
                             or epoch + 1 == end_epoch):
-                        if flat_core is not None:
-                            # on-disk form is ALWAYS the tree form —
-                            # checkpoints stay interchangeable between
-                            # flat and tree modes
-                            save_params, save_opt = flat_core.tree_state(
-                                state)
-                        else:
-                            save_params, save_opt = (state.params,
-                                                     state.opt_state)
                         save = (writer.save if writer is not None
                                 else save_checkpoint)
                         with timer.span("train.checkpoint"):
-                            save(prefix, epoch + 1, save_params, save_opt,
+                            save(prefix, epoch + 1, state.params,
+                                 state.opt_state,
                                  means=cfg.train.bbox_means,
                                  stds=cfg.train.bbox_stds,
                                  num_classes=cfg.dataset.num_classes,

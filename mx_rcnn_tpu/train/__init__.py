@@ -22,8 +22,6 @@ _EXPORTS = {
     "TrainState": "mx_rcnn_tpu.train.step",
     "create_train_state": "mx_rcnn_tpu.train.step",
     "make_train_step": "mx_rcnn_tpu.train.step",
-    "FlatCore": "mx_rcnn_tpu.train.flatcore",
-    "FlatTrainState": "mx_rcnn_tpu.train.flatcore",
     "MetricBag": "mx_rcnn_tpu.train.metrics",
     "Speedometer": "mx_rcnn_tpu.train.callback",
 }

@@ -4,7 +4,7 @@ PR 11 made bf16 the default compute dtype, and nothing watched numerical
 health: graftscope/graftprof say how FAST a run is, but a run that
 overflows in bf16, diverges after a heal, or silently trains on NaNs was
 invisible until the epoch metric. This module computes the health signal
-INSIDE the compiled train step, flatcore-native:
+INSIDE the compiled train step:
 
 - ``finite_stats`` is ONE fused pass over a buffer: nonfinite count plus
   the finite-masked squared sum (XLA fuses both reductions with the
@@ -12,11 +12,8 @@ INSIDE the compiled train step, flatcore-native:
   informative when a few elements have overflowed — "3 nonfinite, norm
   unchanged" localizes a blowup far better than an all-NaN norm.
 - ``step_health`` probes the three tensors that tell the mixed-precision
-  story (grads, params, the update delta). Flat mode probes each flat
-  dtype buffer — one fused reduction per buffer, the flatcore shape;
-  tree mode gets a coarser whole-tree fold (one count + one norm per
-  kind), since per-leaf reductions would re-create exactly the
-  many-small-kernels serialization flatcore removed.
+  story (grads, params, the update delta), each as one whole-tree fold
+  (one count + one norm per kind).
 - The result is a dict of SCALARS returned as extra step outputs
   (train/step.py ``health=True``): the cadenced device→host read
   (obs/health.py HealthMonitor, ``obs.health_every``) piggybacks on the
@@ -26,9 +23,8 @@ INSIDE the compiled train step, flatcore-native:
 
 Key schema (the contract obs/health.py folds): ``{kind}/{group}/nf``
 (nonfinite count, int32) and ``{kind}/{group}/sq`` (finite-masked squared
-sum, f32) for kind ∈ {grad, param, update}, group = flat buffer dtype
-name or the literal ``tree``; plus ``loss`` (the dispatch's pooled mean
-total loss, f32).
+sum, f32) for kind ∈ {grad, param, update}, group = the literal
+``tree``; plus ``loss`` (the step's pooled mean total loss, f32).
 
 This file is the sanctioned home of jit-reachable ``jnp.isfinite``-style
 probe reductions — the ``health-host-pull`` lint rule flags them
@@ -45,10 +41,6 @@ import jax.numpy as jnp
 #: key suffixes (obs/health.py parses on these)
 NF_SUFFIX = "/nf"
 SQ_SUFFIX = "/sq"
-#: pin-entry prefix — full BUFFERS riding the health dict purely to be
-#: program outputs (see step_health's pin_grads); obs/health.py skips
-#: them (never pulled to host), they are dropped with the dict.
-PIN_PREFIX = "_pin/"
 
 
 def finite_stats(x: jnp.ndarray):
@@ -62,22 +54,9 @@ def finite_stats(x: jnp.ndarray):
     return nf, jnp.sum(xf * xf)
 
 
-def probe_buffers(kind: str, bufs: Dict[str, Any]) -> Dict[str, Any]:
-    """Flat mode: one fused reduction per float dtype buffer. Non-float
-    groups carry no overflow information and are skipped."""
-    out: Dict[str, Any] = {}
-    for d, b in bufs.items():
-        if not jnp.issubdtype(b.dtype, jnp.floating):
-            continue
-        nf, sq = finite_stats(b)
-        out[f"{kind}/{d}{NF_SUFFIX}"] = nf
-        out[f"{kind}/{d}{SQ_SUFFIX}"] = sq
-    return out
-
-
 def probe_tree(kind: str, tree: Any) -> Dict[str, Any]:
-    """Tree mode: the coarse whole-tree fold — per-leaf stats summed into
-    ONE (count, squared-sum) pair under the group name ``tree``."""
+    """The whole-tree fold — per-leaf stats summed into ONE (count,
+    squared-sum) pair under the group name ``tree``."""
     nf_tot = jnp.zeros((), jnp.int32)
     sq_tot = jnp.zeros((), jnp.float32)
     for leaf in jax.tree_util.tree_leaves(tree):
@@ -90,54 +69,18 @@ def probe_tree(kind: str, tree: Any) -> Dict[str, Any]:
             f"{kind}/tree{SQ_SUFFIX}": sq_tot}
 
 
-def step_health(old_state, grads, new_state, flat_core, loss,
-                pin_grads: bool = False) -> Dict[str, Any]:
+def step_health(old_state, grads, new_state, loss) -> Dict[str, Any]:
     """The per-optimizer-step health dict (train/step.py calls this inside
     the traced step, after the update).
 
-    ``grads`` are the FINAL gradients the update consumed: flat mode's
-    f32 master-gradient buffers (post ``master_grads`` under bf16 — the
-    shadow cotangent's nonfinites survive the cast up) or the tree-mode
-    gradient tree. The update delta is probed as ``new − old`` per
-    master buffer/leaf: a nonfinite delta with finite grads localizes
-    the fault to the optimizer math rather than the backward.
-
-    ``pin_grads`` (flat mode, CPU backend, train/step.py decides): ALSO
-    return the probed gradient buffers under ``_pin/`` keys — never
-    pulled to host (obs/health.py skips the prefix), they exist purely
-    to make the buffers PROGRAM OUTPUTS. CPU XLA schedules the flat
-    backward pathologically when its cotangent buffer has only
-    scalar-reduction side-consumers (measured on the 64^2 tiny step:
-    +3.4 s/step — ~8x — for the grad probes alone; +30 ms with the
-    buffer pinned as an output), and ``optimization_barrier`` is
-    dropped by that pipeline — output-ness is the one reliable pin,
-    exactly the graftcast shadow lesson (PERF.md round 8). The probed
-    param/update tensors need no pin: ``new_state`` already IS an
-    output. On TPU the pin is off (an extra live grad-sized HBM buffer
-    per step buys nothing there)."""
+    ``grads`` are the FINAL gradients the update consumed. The update
+    delta is probed as ``new − old`` per leaf: a nonfinite delta with
+    finite grads localizes the fault to the optimizer math rather than
+    the backward."""
     out: Dict[str, Any] = {"loss": jnp.asarray(loss, jnp.float32)}
-    if flat_core is not None:
-        out.update(probe_buffers("grad", grads))
-        out.update(probe_buffers("param", new_state.flat))
-        delta = {d: new_state.flat[d] - old_state.flat[d]
-                 for d in new_state.flat}
-        out.update(probe_buffers("update", delta))
-        if pin_grads:
-            out.update({f"{PIN_PREFIX}{d}": g for d, g in grads.items()})
-    else:
-        out.update(probe_tree("grad", grads))
-        out.update(probe_tree("param", new_state.params))
-        delta = jax.tree_util.tree_map(
-            lambda a, b: a - b, new_state.params, old_state.params)
-        out.update(probe_tree("update", delta))
+    out.update(probe_tree("grad", grads))
+    out.update(probe_tree("param", new_state.params))
+    delta = jax.tree_util.tree_map(
+        lambda a, b: a - b, new_state.params, old_state.params)
+    out.update(probe_tree("update", delta))
     return out
-
-
-def fold_multi_step(h_seq: Dict[str, Any]) -> Dict[str, Any]:
-    """Multi-step dispatch: the scan stacks K per-step health rows —
-    fold to one dict per dispatch. Nonfinite counts SUM over the K steps
-    (a poisoned middle step must surface even if the last one happens to
-    look clean); norms and the loss take the LAST step's row (the
-    trailing-window statistics track the newest state)."""
-    return {k: (jnp.sum(v, axis=0) if k.endswith(NF_SUFFIX) else v[-1])
-            for k, v in h_seq.items()}

@@ -21,10 +21,9 @@ Mapping:
 
 from __future__ import annotations
 
-from typing import Dict, Mapping, Sequence
+from typing import Sequence
 
 import jax
-import jax.numpy as jnp
 import optax
 
 from mx_rcnn_tpu.config import Config
@@ -95,9 +94,8 @@ def build_optimizer(cfg: Config, params, steps_per_epoch: int = 1000,
                     begin_step: int = 0):
     mask = trainable_mask(params, effective_fixed_patterns(cfg))
     sched = lr_schedule(cfg, steps_per_epoch, begin_step)
-    # Optional bf16 storage for the momentum / first-moment slot: the
-    # update is HBM-bound (PERF.md r4 — ~6-7.6 ms/step across families),
-    # and this halves one full-size tree's traffic. f32 default.
+    # Optional bf16 storage for the momentum / first-moment slot: halves
+    # one full-size tree's bytes. f32 default.
     slot_dtype = (None if cfg.train.opt_state_dtype == "float32"
                   else cfg.train.opt_state_dtype)
     if cfg.train.optimizer == "adamw":
@@ -127,12 +125,10 @@ def build_optimizer(cfg: Config, params, steps_per_epoch: int = 1000,
     # for the alternate-training frozen-trunk stages where grads through
     # `features` are real (caught by test_stages.py's trunk-sharing
     # assertion).
-    # One code path for DP and TP. Alternatives were measured on-chip and
-    # REJECTED (r4, PERF.md): optax.flatten (one big vector) costs 10.2 ms
-    # vs this chain's 6.1 — the ravel/unravel are ~300 slice ops each
-    # way; a hand-fused one-kernel-per-leaf SGD measures 6.46 ms — the
-    # update is HBM-traffic-bound (~1.2 GB/step at f32), not
-    # kernel-count-bound, so the chain is already at its floor.
+    # One code path for DP and TP. On the chip the per-leaf chain has no
+    # device time of its own: XLA fuses each leaf's update into the
+    # convolution that produces its weight gradient
+    # (`stage.update_ms.train` 0.0001 ms, ledger, PR 29).
     labels = jax.tree_util.tree_map(
         lambda t: "train" if t else "frozen", mask)
     return optax.multi_transform(
@@ -150,8 +146,7 @@ def rebase_schedule_count(opt_state, step: int):
     steps_per_epoch, its LR schedule) — left unrebased, every schedule
     read (warmup/decay boundaries) would happen at the old run's
     position, silently bending the LR trajectory. Scalar integer leaves
-    are exactly optax's counts (the same invariant flatcore's slot
-    discovery keys on)."""
+    are exactly optax's counts."""
     import numpy as np
 
     def _fix(leaf):
@@ -161,92 +156,3 @@ def rebase_schedule_count(opt_state, step: int):
         return leaf
 
     return jax.tree_util.tree_map(_fix, opt_state)
-
-
-# ---------------------------------------------------------------------------
-# Flat update path (train/flatcore.py storage). The r4 probes showed the
-# ~6 ms update floor is a serialization cost of launching hundreds of
-# per-leaf kernels, not HBM bandwidth — so the structural fix is fewer,
-# bigger buffers, not cheaper per-leaf math. These functions are the
-# elementwise twins of the optax chains above, applied to flatcore's
-# dtype-segregated buffers ({dtype-name: 1-D array}): a handful of fused
-# kernels per step instead of one-per-leaf-per-transform. Freezing is a
-# precomputed per-segment 0/1 scale (`masks`) multiplied into the gradient
-# AND the weight-decay term — the same hard-zero semantics as the
-# multi_transform above (the r3 frozen-grad fix): frozen elements see a
-# structurally zero update, so `p + (-lr * 0)` leaves them bit-identical.
-# ---------------------------------------------------------------------------
-
-
-def flat_sgd_update(params: Mapping[str, jnp.ndarray],
-                    grads: Mapping[str, jnp.ndarray],
-                    trace: Mapping[str, jnp.ndarray],
-                    masks: Mapping[str, jnp.ndarray], *,
-                    lr, momentum: float, wd: float, clip_delta: float,
-                    trace_dtypes: Mapping[str, str]):
-    """clip → add_decayed_weights → trace → (−lr), fused over flat buffers.
-
-    Expression-for-expression the optax chain in build_optimizer (clip of a
-    hard-zeroed gradient is zero; the trace buffer covers frozen segments
-    but stays exactly 0 there), so the trainable elements are BIT-identical
-    to the tree path — same elementwise ops in the same order, just over
-    one buffer per dtype. `trace_dtypes` mirrors optax.trace's
-    accumulator_dtype (the opt_state_dtype memory lever): the update uses
-    the uncast value; the stored slot is cast.
-    """
-    new_p: Dict[str, jnp.ndarray] = {}
-    new_t: Dict[str, jnp.ndarray] = {}
-    for d, p in params.items():
-        m = masks[d]
-        u = jnp.clip(grads[d] * m, -clip_delta, clip_delta)
-        u = u + wd * (p * m)
-        t_new = u + momentum * trace[d]
-        step = jnp.asarray(-1.0, t_new.dtype) * jnp.asarray(
-            lr, t_new.dtype) * t_new
-        new_p[d] = jnp.asarray(p + step).astype(p.dtype)
-        new_t[d] = t_new.astype(trace_dtypes[d])
-    return new_p, new_t
-
-
-def flat_adamw_update(params: Mapping[str, jnp.ndarray],
-                      grads: Mapping[str, jnp.ndarray],
-                      mu: Mapping[str, jnp.ndarray],
-                      nu: Mapping[str, jnp.ndarray],
-                      masks: Mapping[str, jnp.ndarray],
-                      count_inc, *,
-                      lr, wd: float, max_norm: float,
-                      b1: float = 0.9, b2: float = 0.999, eps: float = 1e-8,
-                      mu_dtypes: Mapping[str, str]):
-    """clip_by_global_norm → scale_by_adam → +wd·p → (−lr), flat twin.
-
-    The global norm reduces over the masked buffers (= the trainable
-    leaves, exactly what the multi_transform 'train' partition feeds
-    optax's clip) — per-BUFFER partial sums instead of per-leaf, so the
-    reduction order differs by float rounding only. Everything after is
-    elementwise. `count_inc` is the POST-increment optax step count
-    (scale_by_adam's safe_int32_increment result) — FlatCore.apply
-    computes the bump once and stores the same value, so the bias
-    correction here and the schedule count can never desynchronize.
-    """
-    g = {d: grads[d] * masks[d] for d in grads}
-    gn = jnp.sqrt(sum(jnp.sum(jnp.square(v)) for v in g.values()))
-    trigger = gn < max_norm
-    bc1 = 1 - b1 ** count_inc
-    bc2 = 1 - b2 ** count_inc
-    new_p: Dict[str, jnp.ndarray] = {}
-    new_mu: Dict[str, jnp.ndarray] = {}
-    new_nu: Dict[str, jnp.ndarray] = {}
-    for d, p in params.items():
-        gc = jax.lax.select(trigger, g[d],
-                            (g[d] / gn.astype(g[d].dtype)) * max_norm)
-        mu_new = (1 - b1) * gc + b1 * mu[d]
-        nu_new = (1 - b2) * (gc ** 2) + b2 * nu[d]
-        mu_hat = mu_new / bc1.astype(mu_new.dtype)
-        nu_hat = nu_new / bc2.astype(nu_new.dtype)
-        u = mu_hat / (jnp.sqrt(nu_hat + 0.0) + eps)
-        u = u + wd * (p * masks[d])
-        u = jnp.asarray(-1.0, u.dtype) * jnp.asarray(lr, u.dtype) * u
-        new_p[d] = jnp.asarray(p + u).astype(p.dtype)
-        new_mu[d] = mu_new.astype(mu_dtypes[d])
-        new_nu[d] = nu_new
-    return new_p, new_mu, new_nu

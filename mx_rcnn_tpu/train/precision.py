@@ -1,45 +1,29 @@
-"""graftcast — the central mixed-precision policy (one knob, one cast).
+"""graftcast — the central mixed-precision policy (one knob).
 
-Before this module the repo's bf16 story was implicit: every flax module
-carried ``dtype=bfloat16`` (per PR 1) and cast ITS OWN float32 param
-leaves down at every use — a per-leaf cast tree re-materialized inside
-each compiled step, with no single place that said which numerics run in
-which dtype. ``train.compute_dtype`` replaces that with an explicit
-policy, and flatcore (train/flatcore.py) makes the policy structural:
+``train.compute_dtype`` says in one place which numerics run in which
+dtype:
 
-- **f32 master weights.** Parameters are stored float32, always — in the
-  flat master buffers (flat mode), the tree leaves (tree mode), and the
-  checkpoint (tree form on disk, bit-for-bit interchangeable between
-  ``f32`` and ``bf16`` runs in both directions).
-- **bf16 compute.** With ``train.compute_dtype=bf16`` the forward and
-  backward run bfloat16: activations and the conv/matmul weights are
-  bf16, and matmuls/convs accumulate f32 via XLA's MXU default plus the
-  explicit ``preferred_element_type`` sites (ops/ring_attention.py,
-  ops/roi_align.py, ops/nms_pallas.py).
-- **One cast per dtype buffer (flat mode).** FlatCore carries a COMPUTE
-  SHADOW of each float master buffer in the train state
-  (``FlatTrainState.compute``): the update writes the f32 masters and
-  re-materializes the shadow with ONE ``convert`` per dtype buffer — a
-  program output, so XLA cannot re-duplicate it into consumer fusions
-  (``optimization_barrier`` is dropped by the CPU pipeline; an output is
-  the only reliable pin). The param tree
-  the forward sees is slice/reshape views of the shadow; the per-leaf
-  cast tree is gone (gated in tests/test_precision.py).
+- **f32 master weights.** Parameters, gradients and optimizer slots are
+  float32 tree leaves, always — in the train state and in the checkpoint
+  (bit-for-bit interchangeable between ``f32`` and ``bf16`` runs in both
+  directions).
+- **bf16 compute.** With ``train.compute_dtype=bf16`` every flax module
+  carries ``dtype=bfloat16`` (:func:`model_dtype`) and casts its own
+  float32 leaves down at use (flax's per-leaf promotion): activations
+  and the conv/matmul weights are bf16, and matmuls/convs accumulate f32
+  via XLA's MXU default plus the explicit ``preferred_element_type``
+  sites (ops/ring_attention.py, ops/roi_align.py, ops/nms_pallas.py).
 - **f32 islands.** The numerics that f16-family dtypes demonstrably
   break stay float32 regardless of the knob: all norm statistics
-  (``is_island_param`` keeps the frozen-BN/GroupNorm/LayerNorm
-  parameters on f32 master views; flax's norm layers already compute
-  their statistics in f32), the losses, ``bbox_transform``
-  encode/decode, and NMS scores — model code routes those casts through
-  :func:`island` (the ``dtype-cast-in-jit`` lint rule points here).
-- **f32 gradients.** The backward's buffer cotangent is cast UP once per
-  buffer (the transpose twin of the shadow cast), so the DP psum and the
-  optimizer update run float32 — the update is bit-exact against the
-  ``f32`` path given identical gradients (tests/test_precision.py).
-
-Tree (per-leaf) mode under ``bf16`` keeps flax's per-leaf promotion —
-same values (cast commutes with slicing), just without the structural
-one-cast win; TP/PP runs therefore lose nothing.
+  (:func:`is_island_param` names the frozen-BN/GroupNorm/LayerNorm
+  parameters; flax's norm layers already compute their statistics in
+  f32), the losses, ``bbox_transform`` encode/decode, and NMS scores —
+  model code routes those casts through :func:`island` (the
+  ``dtype-cast-in-jit`` lint rule points here).
+- **f32 gradients.** The cast's transpose hands every leaf a float32
+  cotangent, so the DP psum and the optimizer update run float32 — the
+  update is bit-exact against the ``f32`` path given identical gradients
+  (tests/test_precision.py).
 """
 
 from __future__ import annotations
@@ -60,9 +44,8 @@ _CANON = {
 SHORT = {"float32": "f32", "bfloat16": "bf16"}
 
 #: leaf names that ARE norm statistics / affine (FrozenBatchNorm) — plus
-#: ``pos_embed`` (models/vit.py): it is bilinearly RESIZED before its
-#: per-use cast, and cast does not commute with resize, so a bf16 shadow
-#: view would diverge from tree mode's resize-f32-then-cast
+#: ``pos_embed`` (models/vit.py): it is bilinearly RESIZED in float32
+#: before its per-use cast
 _ISLAND_LEAVES = frozenset(
     {"gamma", "beta", "moving_mean", "moving_var", "pos_embed"})
 #: module-name fragments of the repo's norm layers: make_norm's ``bn*`` /
@@ -70,10 +53,7 @@ _ISLAND_LEAVES = frozenset(
 #: ``norm*`` / ``dec_norm`` LayerNorms (models/vit.py, models/detr.py) —
 #: plus DETR's set-prediction heads (``class_embed`` / ``bbox_mlp*`` /
 #: ``bbox_out``), which are declared ``dtype=jnp.float32`` Denses over
-#: ``island(hs)``: flax computes them with UNCAST f32 weights in tree
-#: mode (no per-use cast for the shadow to commute with), so a bf16
-#: shadow view would silently quantize exactly the box/score numerics
-#: the island contract promises stay f32.
+#: ``island(hs)``: flax computes them with UNCAST f32 weights.
 #: ``_ln`` covers the SFP upsampling LayerNorm (models/vit.py up4_ln)
 _ISLAND_MODULES = ("bn", "norm", "_ln", "class_embed", "bbox_mlp",
                    "bbox_out")
@@ -92,16 +72,11 @@ def normalize_compute_dtype(value: str) -> str:
 @dataclass(frozen=True)
 class Policy:
     """Resolved dtype policy: ``compute`` is what the forward/backward
-    run in, ``master`` what parameters/gradients/optimizer state are
-    stored and updated in (always float32 here — bf16 master weights are
-    a different, accuracy-risky regime this repo does not offer)."""
+    run in. Parameters, gradients and optimizer state are stored and
+    updated in float32 always (bf16 master weights are a different,
+    accuracy-risky regime this repo does not offer)."""
 
     compute: str  # canonical dtype name ("float32" | "bfloat16")
-    master: str = "float32"
-
-    @property
-    def mixed(self) -> bool:
-        return self.compute != self.master
 
     @property
     def compute_jnp(self):
@@ -134,12 +109,12 @@ def island(x: jnp.ndarray) -> jnp.ndarray:
 
 
 def is_island_param(path: str) -> bool:
-    """True for param leaves that must stay f32 VIEWS of the master
-    buffer under a bf16 policy: norm statistics and norm affine terms.
+    """True for param leaves that reach the forward in float32 under a
+    bf16 policy: norm statistics and norm affine terms.
 
-    ``path`` is the flatcore segment path ("/"-joined tree keys, e.g.
+    ``path`` is the "/"-joined tree keys of the leaf (e.g.
     ``params/features/stage2/block0/bn1/scale``). Everything else (conv/
-    dense kernels and biases) reads the compute shadow."""
+    dense kernels and biases) is cast to the compute dtype at use."""
     parts = path.split("/")
     if parts and parts[-1] in _ISLAND_LEAVES:
         return True
@@ -150,17 +125,3 @@ def is_island_param(path: str) -> bool:
         if any(frag in module for frag in _ISLAND_MODULES):
             return True
     return False
-
-
-def cast_buffers(bufs, dtype):
-    """{name: buffer} → same dict with every FLOAT buffer cast to
-    ``dtype`` — exactly one ``convert`` per float buffer (the flatcore
-    compute-shadow materialization). Non-float buffers pass through."""
-    dtype = jnp.dtype(dtype)
-    out = {}
-    for name, buf in bufs.items():
-        if jnp.issubdtype(buf.dtype, jnp.floating) and buf.dtype != dtype:
-            out[name] = buf.astype(dtype)
-        else:
-            out[name] = buf
-    return out

@@ -133,7 +133,6 @@ def make_train_step(
     donate: bool = True,
     forward_fn: Callable = forward_train,
     param_specs=None,
-    flat_core=None,
     health: bool = False,
 ) -> Callable[[TrainState, Dict[str, jnp.ndarray], jax.Array],
               Tuple[TrainState, Dict[str, jnp.ndarray]]]:
@@ -148,51 +147,33 @@ def make_train_step(
     graftcanvas (image.canvas_pack): packed batches shard/accumulate
     UNCHANGED through this machinery — every leaf's leading dim is the
     plane count P (one-plus planes per data shard; im_info/gt tensors are
-    (P, I, ...)), so the P('data') sharding, the accum inner-reshape and
-    multi-step stacking all slice whole planes. The forward detects the
-    packed contract from the batch itself (ops/canvas.py).
-
-    cfg.train.multi_step_dispatch = K > 1 returns a MULTI-step function:
-    it takes step-stacked batches (leaves (K, B, ...), sharded
-    P(None, 'data')) and performs K full optimizer steps in one
-    lax.scan-ed program — one host dispatch pays the fixed dispatch
-    overhead for K steps. Metrics are pooled over the K steps.
+    (P, I, ...)), so the P('data') sharding and the accum inner-reshape
+    slice whole planes. The forward detects the packed contract from the
+    batch itself (ops/canvas.py).
 
     param_specs (parallel/partition.py): tensor-parallel weight shardings.
     The state must then arrive PRE-PLACED (shard_train_state) — shardings
     are inferred from the committed inputs and propagated by GSPMD, which
     inserts the TP collectives alongside the data-axis gradient psum.
 
-    flat_core (train/flatcore.py): state is a FlatTrainState; the loss is
-    differentiated with respect to the FLAT BUFFERS — the param tree the
-    forward sees is slice/reshape views materialized in-graph, so the
-    backward writes one flat gradient per dtype and the DP allreduce is
-    one psum per buffer. Donation, grad accumulation and multi-step
-    dispatch compose unchanged (the flat state is an ordinary pytree).
-
-    graftcast (train.compute_dtype=bf16 + flat_core): the differentiated
-    value is the (master, compute-shadow) buffer PAIR — the forward's
-    views slice the bf16 shadow (f32 islands slice the master), the
-    shadow cotangent is cast up once per buffer inside
-    FlatCore.master_grads, and the update re-materializes the shadow
-    from the new masters (one cast per buffer, a program output). Tree
-    mode under bf16 keeps flax's per-leaf promotion — same values.
+    graftcast (train.compute_dtype=bf16): parameters and optimizer slots
+    stay float32 leaves; the modules compute in bfloat16 by flax's
+    per-leaf promotion (train/precision.py), so gradients reach
+    apply_gradients float32.
 
     graftpulse (health=True, obs.health_every > 0): the step RETURNS a
     third output — the numerics health dict of train/health.py
-    (per-flat-buffer / whole-tree nonfinite counts and squared norms of
-    grads, params and the update delta, plus the pooled loss) — computed
-    in-graph and fused into the same executable, so the cadenced host
-    read (obs/health.py) adds no per-step sync and no extra compile.
+    (whole-tree nonfinite counts and squared norms of grads, params and
+    the update delta, plus the pooled loss) — computed in-graph and
+    fused into the same executable, so the cadenced host read
+    (obs/health.py) adds no per-step sync and no extra compile.
     health=False keeps the exact two-output program (bit-identical HLO
     to pre-graftpulse). Chaos ``nan_at_step=K`` (resilience/chaos.py)
-    poisons step K's final gradients IN-GRAPH here, after the accum fold
-    and the bf16 cast-up — the registered "grad_inject" site, traced in
-    at build time.
+    poisons step K's final gradients IN-GRAPH here, after the accum
+    fold — the registered "grad_inject" site, traced in at build time.
     """
 
     accum = max(1, int(getattr(cfg.train, "grad_accum_steps", 1)))
-    multi = max(1, int(getattr(cfg.train, "multi_step_dispatch", 1)))
     # graftpulse chaos: the spec is env-carried and static per process —
     # parse once at build time; the injection (if armed) is traced into
     # the step at the registered "grad_inject" site below.
@@ -200,47 +181,18 @@ def make_train_step(
     nan_at = int(_spec.nan_at_step)
     if _spec.active:
         _spec.fire("grad_inject")
-    # graftpulse flat-mode CPU quirk (train/health.py::step_health): the
-    # probed gradient buffers must be program OUTPUTS on the CPU backend
-    # or XLA schedules the backward ~8x slower; pinning under a scan
-    # (multi-step) would stack K grad-sized buffers instead, so the pin
-    # is single-step only.
-    pin_grads = (health and flat_core is not None and multi == 1
-                 and jax.default_backend() == "cpu")
-    if flat_core is not None:
-        def as_params(diff):
-            return flat_core.params_view(*diff) if flat_core.policy.mixed \
-                else flat_core.table.unflatten(diff)
-    else:
-        def as_params(diff):
-            return diff
 
-    def _grads_of(diff, chunk, key):
+    def _grads_of(params, chunk, key):
         def loss_fn(p):
-            loss, aux = forward_fn(model, as_params(p), chunk, key, cfg)
+            loss, aux = forward_fn(model, p, chunk, key, cfg)
             return loss, aux
 
-        (_, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(diff)
-        if flat_core is not None and flat_core.policy.mixed:
-            # Cast the shadow cotangent up and fold it into the f32
-            # master gradient HERE, per micro-step: accumulation, the DP
-            # psum and the update all run float32 from this point on.
-            grads = flat_core.master_grads(grads)
+        (_, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
         return grads, _metric_parts(aux)
 
-    def _diff_of(state):
-        if flat_core is None:
-            return state.params
-        if flat_core.policy.mixed:
-            # graftcast: differentiate the (master, shadow) pair — island
-            # grads land f32 in the master cotangent, the bf16 shadow
-            # cotangent is cast up once per buffer (FlatCore.master_grads)
-            return (state.flat, state.compute)
-        return state.flat
-
-    def _one_update(state: TrainState, batch, rng):
+    def step(state: TrainState, batch, rng):
         if accum == 1:
-            grads, parts = _grads_of(_diff_of(state), batch, rng)
+            grads, parts = _grads_of(state.params, batch, rng)
         else:
             # Micro-step accumulation: the batch's leading dim is
             # accum x micro-batch; grads average and metric PARTS sum
@@ -262,7 +214,7 @@ def make_train_step(
             g_tot, p_tot = None, None
             for i in range(accum):
                 chunk = jax.tree.map(lambda x: x[:, i], chunks)
-                g, p = _grads_of(_diff_of(state), chunk, keys[i])
+                g, p = _grads_of(state.params, chunk, keys[i])
                 if g_tot is None:
                     g_tot, p_tot = g, p
                 else:
@@ -272,54 +224,15 @@ def make_train_step(
             parts = p_tot
         if nan_at:
             # chaos nan_at_step: poison the FINAL gradients (post accum
-            # fold / cast-up) of the armed optimizer step, in-graph.
+            # fold) of the armed optimizer step, in-graph.
             grads = chaos.poison_grads(grads, state.step, nan_at)
-        with stage("update"):  # tree: tx.update + apply_updates; flat: core.apply
+        with stage("update"):  # tx.update + apply_updates
             new_state = state.apply_gradients(grads)
+        metrics = _finalize_metrics(parts)
         if not health:
-            return new_state, parts
-        num, den = parts["TotalLoss"]
-        return new_state, parts, health_mod.step_health(
-            state, grads, new_state, flat_core, num / (den + 1e-12),
-            pin_grads=pin_grads)
-
-    if multi == 1:
-        def step(state: TrainState, batch, rng):
-            if health:
-                new_state, parts, pulse = _one_update(state, batch, rng)
-                return new_state, _finalize_metrics(parts), pulse
-            new_state, parts = _one_update(state, batch, rng)
-            return new_state, _finalize_metrics(parts)
-    else:
-        # Multi-step dispatch: K full optimizer steps per host call via
-        # lax.scan over step-stacked batches (leaves (K, B, ...)) — the
-        # fixed per-dispatch overhead is paid once per K steps. Metric
-        # PARTS sum across the K steps before finalizing, so the returned
-        # metrics are the pooled values over all K·B images (identical
-        # accounting to K separate Speedometer updates).
-        def step(state: TrainState, batches, rng):
-            keys = jax.random.split(rng, multi)
-
-            def body(st, xs):
-                chunk, key = xs
-                if health:
-                    st, parts, pulse = _one_update(st, chunk, key)
-                    return st, (parts, pulse)
-                st, parts = _one_update(st, chunk, key)
-                return st, parts
-
-            if health:
-                state, (parts_seq, h_seq) = jax.lax.scan(
-                    body, state, (batches, keys))
-                parts = jax.tree.map(lambda x: jnp.sum(x, axis=0),
-                                     parts_seq)
-                # nonfinite counts sum over the K steps; norms/loss keep
-                # the last step's row (train/health.py).
-                return (state, _finalize_metrics(parts),
-                        health_mod.fold_multi_step(h_seq))
-            state, parts_seq = jax.lax.scan(body, state, (batches, keys))
-            parts = jax.tree.map(lambda x: jnp.sum(x, axis=0), parts_seq)
-            return state, _finalize_metrics(parts)
+            return new_state, metrics
+        return new_state, metrics, health_mod.step_health(
+            state, grads, new_state, metrics["TotalLoss"])
 
     if mesh is None:
         return jax.jit(step, donate_argnums=(0,) if donate else ())
@@ -339,8 +252,7 @@ def make_train_step(
         return jax.jit(step, donate_argnums=(0,) if donate else ())
 
     repl = NamedSharding(mesh, P())
-    data_sh = NamedSharding(mesh, P("data") if multi == 1
-                            else P(None, "data"))
+    data_sh = NamedSharding(mesh, P("data"))
     return jax.jit(
         step,
         in_shardings=(repl, data_sh, repl),

@@ -52,10 +52,9 @@ def error_runner(label):
 # the tiny fit (in-process helper + --fit subprocess mode)
 # ---------------------------------------------------------------------------
 
-def tiny_config(flat: bool = False, obs_dir: str = "", compute: str = "f32",
+def tiny_config(obs_dir: str = "", compute: str = "f32",
                 health_every: int = 0, over_extra=None):
-    """The 64^2 f32 micro-config of tests/test_flatcore.py, plus
-    power-of-two bbox stds: the kill->resume parity gates assert BIT
+    """A 64^2 f32 micro-config with power-of-two bbox stds: the kill->resume parity gates assert BIT
     exactness, and an emergency save round-trips bbox_pred through
     unnormalize (kernel*std) + renormalize (kernel/std) — exact for
     powers of two, not for the default 0.1/0.2. ``compute`` selects the
@@ -96,12 +95,12 @@ def tiny_config(flat: bool = False, obs_dir: str = "", compute: str = "f32",
         over.update(over_extra)
     cfg = generate_config("resnet50", "synthetic", **over)
     return cfg.with_updates(
-        train=replace(cfg.train, flat_params=flat, compute_dtype=compute,
+        train=replace(cfg.train, compute_dtype=compute,
                       bbox_stds=(0.5, 0.5, 0.25, 0.25)))
 
 
 def run_fit(prefix: str, end_epoch: int = 2, resume=False,
-            flat: bool = False, obs_dir: str = "", mesh: str = "1",
+            obs_dir: str = "", mesh: str = "1",
             num_images: int = 3, epoch_metrics=None, compute: str = "f32",
             health_every: int = 0, over_extra=None):
     """num_images x 64^2, seed 0 — returns the final host params.
@@ -119,7 +118,7 @@ def run_fit(prefix: str, end_epoch: int = 2, resume=False,
     if epoch_metrics is not None:
         def cb(epoch, state, bag):
             epoch_metrics.append((epoch, bag.get()))
-    return fit_detector(tiny_config(flat, obs_dir, compute, health_every,
+    return fit_detector(tiny_config(obs_dir, compute, health_every,
                                     over_extra=over_extra),
                         ds.gt_roidb(),
                         prefix=prefix, end_epoch=end_epoch, frequent=1000,
@@ -163,8 +162,6 @@ def main(argv=None):
     p.add_argument("--end-epoch", type=int, default=2)
     p.add_argument("--resume", nargs="?", const=True, default=False,
                    choices=[True, "auto"], metavar="auto")
-    p.add_argument("--flat", action="store_true",
-                   help="train.flat_params=true mode")
     p.add_argument("--obs-dir", default="")
     p.add_argument("--mesh", default="1", help="mesh spec (data[xmodel])")
     p.add_argument("--num-images", type=int, default=3)
@@ -237,7 +234,7 @@ def main(argv=None):
                 p.error(f"--set expects KEY=VALUE, got {pair!r}")
             over_extra[key] = _coerce(raw)
         run_fit(args.fit, end_epoch=args.end_epoch, resume=args.resume,
-                flat=args.flat, obs_dir=args.obs_dir, mesh=args.mesh,
+                obs_dir=args.obs_dir, mesh=args.mesh,
                 num_images=args.num_images, compute=args.compute,
                 over_extra=over_extra or None)
         return 0

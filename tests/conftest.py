@@ -99,27 +99,20 @@ def _uninterrupted_fit(tmp_path_factory, name, **kw):
 
 
 @pytest.fixture(scope="session")
-def bf16_flat_baseline(tmp_path_factory):
-    """Uninterrupted flat + compute_dtype=bf16 tiny fit params — the ONE
+def bf16_baseline(tmp_path_factory):
+    """Uninterrupted compute_dtype=bf16 tiny fit params — the ONE
     graftcast parity reference shared by the kill→resume gate
     (tests/test_resilience.py), the heal-carry gate (tests/test_heal.py)
     and the graftpulse nan→resume gate (tests/test_health.py). Session
     scope: all compare against the bit-identical deterministic run, so a
     single baseline fit pays for every consumer (tier-1 budget)."""
     return _uninterrupted_fit(tmp_path_factory, "bf16_base",
-                              flat=True, compute="bf16")
+                              compute="bf16")
 
 
 @pytest.fixture(scope="session")
 def tree_f32_baseline(tmp_path_factory):
-    """Uninterrupted tree-mode f32 tiny fit params — shared by the
-    SIGTERM kill→resume parity gate (tests/test_resilience.py) and the
+    """Uninterrupted f32 tiny fit params — shared by the SIGTERM
+    kill→resume parity gate (tests/test_resilience.py) and the
     graftpulse nan→resume gate (tests/test_health.py)."""
-    return _uninterrupted_fit(tmp_path_factory, "tree_base", flat=False)
-
-
-@pytest.fixture(scope="session")
-def flat_f32_baseline(tmp_path_factory):
-    """Uninterrupted flat-mode f32 tiny fit params — same sharing
-    contract as tree_f32_baseline."""
-    return _uninterrupted_fit(tmp_path_factory, "flat_base", flat=True)
+    return _uninterrupted_fit(tmp_path_factory, "tree_base")

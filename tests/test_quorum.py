@@ -19,7 +19,7 @@ Two layers:
   * coordinated preemption: SIGTERM one of two hosts -> BOTH drain to
     the agreed boundary, exactly ONE published save (complete host set
     in graft_meta.json), both exit rc 75, and a dual ``--resume auto``
-    reaches params BIT-exact vs an uninterrupted run (tree and flat);
+    reaches params BIT-exact vs an uninterrupted run;
   * multi-host heal with exclusion: both hosts lose devices, one is
     chaos-armed to miss the heal rendezvous -> survivors seal a quorum
     without it, the run continues, the excluded host exits rc 75;
@@ -357,7 +357,7 @@ def test_elastic_mesh_spec_grow_and_rescale_modes():
 # multi-host trainer gates (the ISSUE acceptance scenarios)
 # ---------------------------------------------------------------------------
 
-def _spawn_host(idx, n_hosts, prefix, kv_dir, *, resume=None, flat=False,
+def _spawn_host(idx, n_hosts, prefix, kv_dir, *, resume=None,
                 obs_dir="", chaos_env=None, end_epoch=2, timeout_s=120):
     cmd = [sys.executable, DRIVER, "--fit", prefix,
            "--end-epoch", str(end_epoch),
@@ -365,8 +365,6 @@ def _spawn_host(idx, n_hosts, prefix, kv_dir, *, resume=None, flat=False,
            "--quorum-dir", kv_dir, "--quorum-timeout", str(timeout_s)]
     if resume:
         cmd += ["--resume", resume] if resume != True else ["--resume"]
-    if flat:
-        cmd += ["--flat"]
     if obs_dir:
         cmd += ["--obs-dir", obs_dir]
     env = _subprocess_env(**({"MX_RCNN_CHAOS": chaos_env}
@@ -377,30 +375,29 @@ def _spawn_host(idx, n_hosts, prefix, kv_dir, *, resume=None, flat=False,
 
 
 def _run_host0_inprocess(prefix, kv_dir, monkeypatch, *, resume=False,
-                         flat=False, obs_dir=""):
+                         obs_dir=""):
     """Host 0 runs IN-PROCESS (so its returned params are directly
     comparable to the conftest baselines) while host 1 is a true
     subprocess."""
     monkeypatch.setenv("MXRCNN_SIM_PROCESS_ID", "0")
     monkeypatch.setenv("MXRCNN_SIM_NUM_PROCESSES", "2")
     return driver.run_fit(
-        prefix, resume=resume, flat=flat, obs_dir=obs_dir,
+        prefix, resume=resume, obs_dir=obs_dir,
         over_extra={"resilience.quorum_store_dir": kv_dir,
                     "resilience.quorum_timeout_s": 120.0})
 
 
-def _coordinated_preemption(tmp_path, monkeypatch, flat, baseline):
+def _coordinated_preemption(tmp_path, monkeypatch, baseline):
     prefix = str(tmp_path / "run")
     obs0 = str(tmp_path / "obs")
 
     # leg A: host 1 (subprocess) is chaos-SIGTERM'd mid-epoch-1; host 0
     # (in-process) is never signaled but must drain and stop too.
     kv_a = str(tmp_path / "kv_a")
-    proc1 = _spawn_host(1, 2, prefix, kv_a, flat=flat,
+    proc1 = _spawn_host(1, 2, prefix, kv_a,
                         chaos_env="sigterm_at_step=4")
     with pytest.raises(PreemptionExit) as ei:
-        _run_host0_inprocess(prefix, kv_a, monkeypatch, flat=flat,
-                             obs_dir=obs0)
+        _run_host0_inprocess(prefix, kv_a, monkeypatch, obs_dir=obs0)
     assert ei.value.code == RESUMABLE_RC
     out1, _ = proc1.communicate(timeout=570)
     assert proc1.returncode == RESUMABLE_RC, (proc1.returncode, out1[-2000:])
@@ -419,9 +416,9 @@ def _coordinated_preemption(tmp_path, monkeypatch, flat, baseline):
     # leg B: dual --resume auto (fresh KV namespace — one dir per launch
     # attempt, the documented supervisor contract) -> bit-exact.
     kv_b = str(tmp_path / "kv_b")
-    proc1 = _spawn_host(1, 2, prefix, kv_b, resume="auto", flat=flat)
+    proc1 = _spawn_host(1, 2, prefix, kv_b, resume="auto")
     params_r = _run_host0_inprocess(prefix, kv_b, monkeypatch,
-                                    resume="auto", flat=flat)
+                                    resume="auto")
     out1, _ = proc1.communicate(timeout=570)
     assert proc1.returncode == 0, (proc1.returncode, out1[-2000:])
     _assert_trees_bitexact(baseline, params_r)
@@ -444,16 +441,8 @@ def _assert_trees_bitexact(a, b):
 @pytest.mark.compile_heavy
 def test_coordinated_preemption_two_hosts_tree(tmp_path, monkeypatch,
                                                tree_f32_baseline):
-    _coordinated_preemption(tmp_path, monkeypatch, flat=False,
+    _coordinated_preemption(tmp_path, monkeypatch,
                             baseline=tree_f32_baseline)
-
-
-@pytest.mark.slow
-@pytest.mark.compile_heavy
-def test_coordinated_preemption_two_hosts_flat(tmp_path, monkeypatch,
-                                               flat_f32_baseline):
-    _coordinated_preemption(tmp_path, monkeypatch, flat=True,
-                            baseline=flat_f32_baseline)
 
 
 @pytest.mark.slow

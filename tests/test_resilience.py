@@ -10,8 +10,7 @@ injected here deterministically (resilience/chaos.py) and must be survived:
   timeout row in partial.json), never the sweep (the rc=124 lesson).
 - preemption-safe training: SIGTERM mid-epoch -> emergency checkpoint +
   resumable rc 75, and ``--resume auto`` reaches BIT-exact final params vs
-  an uninterrupted run — in tree and flat (train.flat_params) modes, which
-  also pins the PR 4 checkpoint-interchange claim under interruption.
+  an uninterrupted run, under f32 and bf16 compute.
 - atomic checkpoints: SIGKILL inside the save's crash window leaves only a
   ``*.tmp-*`` dir no resume path ever considers.
 
@@ -509,7 +508,7 @@ def _assert_trees_bitexact(a, b):
                                       err_msg=jax.tree_util.keystr(path))
 
 
-def _parity(tmp_path, monkeypatch, flat, compute="f32", params_u=None):
+def _parity(tmp_path, monkeypatch, compute="f32", params_u=None):
     """SIGTERM at global step 4 (mid-epoch 1 of 2x3) -> PreemptionExit
     rc 75 with a dispatch-tagged emergency save and a `preempt` event;
     --resume auto then reaches params BIT-exact vs uninterrupted
@@ -517,13 +516,13 @@ def _parity(tmp_path, monkeypatch, flat, compute="f32", params_u=None):
     session-scope bf16 one is shared with test_heal.py)."""
     if params_u is None:
         params_u = driver.run_fit(str(tmp_path / "uninterrupted"),
-                                  flat=flat, compute=compute)
+                                  compute=compute)
 
     monkeypatch.setenv(chaos.ENV_VAR, "sigterm_at_step=4")
     chaos.reset()
     obs_dir = str(tmp_path / "obs_interrupted")
     with pytest.raises(PreemptionExit) as ei:
-        driver.run_fit(str(tmp_path / "killed"), flat=flat, obs_dir=obs_dir,
+        driver.run_fit(str(tmp_path / "killed"), obs_dir=obs_dir,
                        compute=compute)
     assert ei.value.code == RESUMABLE_RC
     assert latest_checkpoint(str(tmp_path / "killed")) == (1, 1)
@@ -536,7 +535,7 @@ def _parity(tmp_path, monkeypatch, flat, compute="f32", params_u=None):
     monkeypatch.delenv(chaos.ENV_VAR)
     chaos.reset()
     obs_resumed = str(tmp_path / "obs_resumed")
-    params_r = driver.run_fit(str(tmp_path / "killed"), flat=flat,
+    params_r = driver.run_fit(str(tmp_path / "killed"),
                               resume="auto", obs_dir=obs_resumed,
                               compute=compute)
     _assert_trees_bitexact(params_u, params_r)
@@ -550,27 +549,16 @@ def _parity(tmp_path, monkeypatch, flat, compute="f32", params_u=None):
 
 @pytest.mark.compile_heavy
 def test_kill_resume_parity_tree(tmp_path, monkeypatch, tree_f32_baseline):
-    _parity(tmp_path, monkeypatch, flat=False, params_u=tree_f32_baseline)
+    _parity(tmp_path, monkeypatch, params_u=tree_f32_baseline)
 
 
 @pytest.mark.compile_heavy
-def test_kill_resume_parity_flat(tmp_path, monkeypatch, flat_f32_baseline):
-    """The PR 4 checkpoint-interchange claim under interruption: the
-    emergency save is TREE-form even from flat buffers, and the resumed
-    flat run still matches uninterrupted bit for bit."""
-    _parity(tmp_path, monkeypatch, flat=True, params_u=flat_f32_baseline)
-
-
-@pytest.mark.compile_heavy
-def test_kill_resume_parity_bf16(tmp_path, monkeypatch, bf16_flat_baseline):
-    """graftcast under interruption: compute_dtype=bf16 + flat — the
-    emergency save is f32 TREE-form (masters only; the compute shadow is
-    derived state), the resumed session re-cuts buffers AND re-derives
-    the shadow from the restored masters, and the whole thing is still
-    bit-exact vs an uninterrupted bf16 run (bf16 rounding is
-    deterministic on a fixed backend)."""
-    _parity(tmp_path, monkeypatch, flat=True, compute="bf16",
-            params_u=bf16_flat_baseline)
+def test_kill_resume_parity_bf16(tmp_path, monkeypatch, bf16_baseline):
+    """graftcast under interruption: compute_dtype=bf16 — the emergency
+    save is the f32 state, and the resumed run is still bit-exact vs an
+    uninterrupted bf16 run (bf16 rounding is deterministic on a fixed
+    backend)."""
+    _parity(tmp_path, monkeypatch, compute="bf16", params_u=bf16_baseline)
 
 
 @pytest.mark.compile_heavy

@@ -424,126 +424,9 @@ def test_opt_state_dtype_bf16_slots():
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
 
 
-def test_multi_step_dispatch_matches_sequential_steps():
-    """multi_step_dispatch=2 over step-stacked batches reproduces two
-    sequential single-step dispatches exactly (same per-step rng split),
-    with metrics pooled across the K steps."""
-    cfg1 = _accum_cfg(grad_accum_steps=1)
-    cfgK = _accum_cfg(grad_accum_steps=1, multi_step_dispatch=2)
-    model = build_model(cfg1)
-    params = init_params(model, cfg1, jax.random.PRNGKey(0))
-    tx = build_optimizer(cfg1, params, steps_per_epoch=10)
-    rng = jax.random.PRNGKey(11)
-    b0, b1 = _accum_batch(1), _accum_batch(1)
-    b1 = {**b1, "image": b1["image"] + 0.5}  # distinct step payloads
-
-    multi_step = make_train_step(model, cfgK, donate=False)
-    stacked = {k: jnp.stack([b0[k], b1[k]]) for k in b0}
-    state_k, metrics_k = multi_step(
-        create_train_state(params, tx), stacked, rng)
-
-    single_step = make_train_step(model, cfg1, donate=False)
-    keys = jax.random.split(rng, 2)
-    state_s = create_train_state(params, tx)
-    state_s, m0 = single_step(state_s, b0, keys[0])
-    state_s, m1 = single_step(state_s, b1, keys[1])
-
-    assert int(state_k.step) == 2
-    for a, b in zip(jax.tree.leaves(state_k.params),
-                    jax.tree.leaves(state_s.params)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-5, atol=1e-6)
-    np.testing.assert_allclose(
-        float(metrics_k["TotalLoss"]),
-        (float(m0["TotalLoss"]) + float(m1["TotalLoss"])) / 2, rtol=1e-5)
-
-
-@pytest.mark.slow
-def test_multi_step_dispatch_under_dp_mesh():
-    """multi_step_dispatch composes with the data mesh: stacked batches
-    shard P(None, 'data') and the scan carries the updated state.
-
-    slow: lax.scan over the full fwd+bwd under a mesh is the SPMD
-    partitioner's worst case (same pathology the grad-accum unroll note
-    in train/step.py documents) — ~20 min of compile on CPU. The no-mesh
-    exactness test + the mesh-'1' fit smoke cover the semantics in the
-    fast suite."""
-    if jax.device_count() < 2:
-        pytest.skip("needs 2 devices")
-    cfg = _accum_cfg(grad_accum_steps=1, multi_step_dispatch=2)
-    model = build_model(cfg)
-    params = init_params(model, cfg, jax.random.PRNGKey(0))
-    tx = build_optimizer(cfg, params, steps_per_epoch=10)
-    mesh = create_mesh("2")
-    step = make_train_step(model, cfg, mesh=mesh, donate=False)
-    stacked = {k: jnp.stack([v, v]) for k, v in _accum_batch(2).items()}
-    state, metrics = step(create_train_state(params, tx),
-                          shard_batch(stacked, mesh, stacked=True),
-                          jax.random.PRNGKey(5))
-    assert int(state.step) == 2
-    assert np.isfinite(float(metrics["TotalLoss"]))
-
-
-@pytest.mark.slow
-def test_multi_step_dispatch_composes_with_grad_accum():
-    """multi=2 x accum=2: each scanned step consumes an accum-reshaped
-    batch and performs ONE update from 2 micro-grads — 2 updates per
-    dispatch over 4 images, equal to running the accum step twice.
-    (slow: scan body holds the unrolled double fwd+bwd — heavy compile.)"""
-    cfgA = _accum_cfg()  # accum=2, multi=1
-    cfgAM = _accum_cfg(multi_step_dispatch=2)  # accum=2, multi=2
-    model = build_model(cfgA)
-    params = init_params(model, cfgA, jax.random.PRNGKey(0))
-    tx = build_optimizer(cfgA, params, steps_per_epoch=10)
-    rng = jax.random.PRNGKey(9)
-    b0, b1 = _accum_batch(2), _accum_batch(2)
-    b1 = {**b1, "image": b1["image"] + 0.25}
-
-    multi_step = make_train_step(model, cfgAM, donate=False)
-    stacked = {k: jnp.stack([b0[k], b1[k]]) for k in b0}
-    state_m, metrics_m = multi_step(
-        create_train_state(params, tx), stacked, rng)
-
-    single = make_train_step(model, cfgA, donate=False)
-    keys = jax.random.split(rng, 2)
-    state_s = create_train_state(params, tx)
-    state_s, _ = single(state_s, b0, keys[0])
-    state_s, _ = single(state_s, b1, keys[1])
-
-    assert int(state_m.step) == 2
-    for a, b in zip(jax.tree.leaves(state_m.params),
-                    jax.tree.leaves(state_s.params)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-5, atol=1e-6)
-    assert np.isfinite(float(metrics_m["TotalLoss"]))
-
-
-def test_multi_step_dispatch_fit_smoke(tmp_path):
-    """fit_detector groups the loader stream into K-step dispatches and
-    drops the trailing partial group."""
-    from dataclasses import replace
-
-    from mx_rcnn_tpu.data.datasets.synthetic import SyntheticDataset
-    from mx_rcnn_tpu.tools.train import fit_detector
-
-    cfg = _accum_cfg(grad_accum_steps=1, multi_step_dispatch=2,
-                     flip=False, lr_step=(100,))
-    cfg = cfg.with_updates(
-        image=replace(cfg.image, scales=((64, 64),)))
-    ds = SyntheticDataset("train", num_images=5, image_size=64,
-                          max_objects=1, min_size_frac=3, max_size_frac=2)
-    history = []
-    fit_detector(cfg, ds.gt_roidb(), prefix=str(tmp_path / "msd"),
-                 end_epoch=1, frequent=1000, seed=0, mesh_spec="1",
-                 epoch_callback=lambda e, s, b: history.append(
-                     (int(s.step), b.get()["TotalLoss"])))
-    # 5 loader batches → 2 dispatches of 2 steps; 1 dropped.
-    assert len(history) == 1 and history[0][0] == 4, history
-    assert np.isfinite(history[0][1])
-
-
-def test_grad_accum_fit_smoke(tmp_path):
-    """fit_detector sizes the loader at accum x batch_images and trains."""
+@pytest.fixture(scope="module")
+def accum_fit(tmp_path_factory):
+    """One tiny fit_detector run: what its epoch_callback was handed."""
     from dataclasses import replace
 
     from mx_rcnn_tpu.data.datasets.synthetic import SyntheticDataset
@@ -554,9 +437,33 @@ def test_grad_accum_fit_smoke(tmp_path):
         image=replace(cfg.image, scales=((64, 64),)))
     ds = SyntheticDataset("train", num_images=4, image_size=64,
                           max_objects=1, min_size_frac=3, max_size_frac=2)
-    history = []
-    fit_detector(cfg, ds.gt_roidb(), prefix=str(tmp_path / "ga"),
-                 end_epoch=1, frequent=1000, seed=0,
-                 epoch_callback=lambda e, s, b: history.append(
-                     b.get()["TotalLoss"]))
+    calls = []
+    final = fit_detector(
+        cfg, ds.gt_roidb(), prefix=str(tmp_path_factory.mktemp("ga") / "ga"),
+        end_epoch=1, frequent=1000, seed=0,
+        epoch_callback=lambda e, s, b: calls.append(
+            (e, s, b.get()["TotalLoss"])))
+    return calls, final
+
+
+def test_grad_accum_fit_smoke(accum_fit):
+    """fit_detector sizes the loader at accum x batch_images and trains."""
+    calls, _ = accum_fit
+    history = [loss for _, _, loss in calls]
     assert len(history) == 1 and np.isfinite(history).all(), history
+
+
+def test_epoch_callback_gets_the_train_state(accum_fit):
+    """The callback's state is the loop's TrainState: `.params` is the
+    parameter tree (benchmarks/drivers/train.py reads exactly that) and
+    `.opt_state` the optax state, one step a dispatch."""
+    from mx_rcnn_tpu.train.step import TrainState
+
+    (epoch, state, _), = accum_fit[0]
+    final = accum_fit[1]
+    assert epoch == 0 and isinstance(state, TrainState)
+    assert int(state.step) == 2  # 4 images, accum 2 x batch 1: 2 steps
+    assert (jax.tree.structure(state.params) == jax.tree.structure(final))
+    for a, b in zip(jax.tree.leaves(state.params), jax.tree.leaves(final)):
+        np.testing.assert_array_equal(np.asarray(a), b)
+    assert jax.tree.leaves(state.opt_state)

@@ -247,14 +247,19 @@ def test_streaming_attn_impl_matches_dense(rng):
     assert np.isclose(float(l_s), float(l_d), rtol=1e-4), (l_s, l_d)
 
 
-def test_attn_impl_unknown_value_raises():
+@pytest.mark.parametrize("value", ["dense", "streaming", "stream"])
+def test_attn_impl_is_validated(value):
     """network.attn_impl outside {'dense','streaming'} fails at build
     time for every family (mirrors the sp_mode validation) instead of
-    being silently treated as dense (advisor r5)."""
-    bad = generate_config("resnet50", "synthetic",
-                          **{"network.attn_impl": "flash"})
-    with pytest.raises(ValueError, match="attn_impl"):
-        zoo.build_model(bad)
+    being silently treated as dense (advisor r5): a typo one letter
+    short of a valid value is refused, the two valid values build."""
+    cfg = generate_config("resnet50", "synthetic",
+                          **{"network.attn_impl": value})
+    if value == "stream":
+        with pytest.raises(ValueError, match="attn_impl"):
+            zoo.build_model(cfg)
+    else:
+        assert zoo.build_model(cfg) is not None
 
 
 def test_attn_impl_streaming_superseded_by_sp_warns(caplog):
