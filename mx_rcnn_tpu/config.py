@@ -464,10 +464,14 @@ class ResilienceConfig:
     # not an outage.
     heal_consecutive_max: int = 3
     # Refresh the host-side fallback snapshot every N completed
-    # dispatches (one device_get sync each). 0 = live capture only:
-    # fine when the post-loss state is readable (no donation, or chaos
-    # injection); on real hardware with donated buffers the snapshot is
-    # what bounds the deterministic replay after a mid-step loss.
+    # dispatches. No sync: the snapshot is a deferred read (a device-side
+    # copy of the state enqueued behind dispatch N, fetched while the
+    # next steps run, installed as the fallback once it is there). 0 =
+    # live capture only: fine when the post-loss state is readable (no
+    # donation, or chaos injection); on real hardware with donated
+    # buffers the snapshot is what bounds the deterministic replay after
+    # a mid-step loss: to N + the dispatches a snapshot is in flight
+    # (the depth of the device's queue, 10-12 on the chip).
     heal_snapshot_dispatches: int = 200
     # graftquorum (resilience/quorum.py): multi-host coordination for
     # preemption and heal. Deadline on every barrier / agree wait — a

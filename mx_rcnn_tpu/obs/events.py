@@ -58,6 +58,11 @@ EVENT_TYPES = (
     "heal",        # graftheal: step-time backend loss recovered in-process
                    # (capture mode, downtime_s, devices before/after —
                    # resilience/heal.py)
+    "snapshot",    # graftheal: one periodic host snapshot of the train
+                   # state INSTALLED as the fallback — the position and the
+                   # run's dispatch it was taken at, the dispatches it was
+                   # in flight, the loop's own ms in it, its bytes
+                   # (resilience/heal.py Healer.poll_snapshot)
     "cost",        # graftprof: XLA cost/memory accounting for one
                    # compiled shape bucket (flops, hbm split — obs/costs.py)
     "trace",       # graftprof: one closed jax.profiler capture window

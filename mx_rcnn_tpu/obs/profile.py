@@ -334,13 +334,21 @@ class TraceController:
                     # window spans steps at..at+N-1 (N = trace_steps)
                     self._stop_after = step + self.trace_steps - 1
 
-    def step_completed(self, step: int):
+    def step_completed(self, step: int, outputs=None):
         """Called once per completed dispatch: closes the open window
         when its step budget is spent (a stall window, which has no
-        budget, closes on the first completed step after it)."""
+        budget, closes on the first completed step after it). A dispatch
+        is asynchronous and the loop waits for none of its own, so before
+        a window is closed, and only then, ``outputs`` (optional: device
+        arrays this dispatch returned) are waited for: the capture holds
+        the work of the steps it brackets."""
         with self._lock:
             if self._active_dir is not None and (
                     self._stop_after is None or step >= self._stop_after):
+                if outputs is not None:
+                    import jax
+
+                    jax.block_until_ready(outputs)
                 self._stop_and_emit()
 
     def anomaly_window(self):

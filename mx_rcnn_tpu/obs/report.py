@@ -190,6 +190,7 @@ def summarize(events: List[Dict[str, Any]]) -> Dict[str, Any]:
     # graftpulse: cadenced numerics readings + tripped anomalies
     health_evs = by_type.get("health", ())
     last_health = health_evs[-1] if health_evs else None
+    snapshots = by_type.get("snapshot", ())
     summary: Dict[str, Any] = {
         "run": {k: run_meta.get(k) for k in
                 ("config_digest", "network", "dataset", "mesh",
@@ -276,6 +277,15 @@ def summarize(events: List[Dict[str, Any]]) -> Dict[str, Any]:
                         and e["devices_before"] != e["devices_after"]],
             "last_error": (by_type["heal"][-1].get("error")
                            if by_type.get("heal") else None),
+            # the periodic host snapshots a loss would roll back to: how
+            # many were installed, the longest one was in flight
+            # (dispatches: what it adds to the replay bound) and the most
+            # of the loop's time one took
+            "snapshots": len(snapshots),
+            "snapshot_in_flight_max": max(
+                (e.get("in_flight", 0) for e in snapshots), default=None),
+            "snapshot_loop_ms_max": max(
+                (e.get("loop_ms", 0.0) for e in snapshots), default=None),
         },
         # graftquorum: multi-host coordination rounds — per-host records
         # interleaved by load_events, so `hosts` is how many distinct
@@ -446,6 +456,11 @@ def render(summary: Dict[str, Any]) -> str:
             f"  heal:       {he['count']} in-run recover(ies), "
             f"{he['downtime_s']:.0f}s down{shrink} | last: "
             f"{he['last_error']}")
+    if he.get("snapshots"):
+        lines.append(
+            f"  snapshots:  {he['snapshots']} installed, in flight <= "
+            f"{he['snapshot_in_flight_max']} dispatch(es), <= "
+            f"{he['snapshot_loop_ms_max']:.1f} ms of the loop each")
     da = summary.get("data", {})
     if (da.get("quarantined") or da.get("retries")
             or da.get("worker_deaths") or da.get("cap_trips")):

@@ -16,8 +16,10 @@ The split follows the loop's own structure (tools/train.py::fit_detector):
                   take a per-step host sync, which is exactly the
                   overhead this repo's lazy-drain discipline
                   (train/metrics.py::MetricBag) exists to avoid. The
-                  drain still happens — at Speedometer log boundaries —
-                  so windowed step_ms is honest end-to-end time.
+                  loop reads nothing of its newest dispatch (Speedometer
+                  logs the dispatches already done); back-pressure holds
+                  it to the device's rate, so windowed step_ms is honest
+                  end-to-end time.
 
   place_ms      — inside dispatch: the ``train.place`` span
                   (``shard_batch``, the host→device placement of the
@@ -61,8 +63,9 @@ STEP_SPAN = "train"
 #: iteration's first dispatch and so the one that blocks while the device's
 #: queue is full - back-pressure lands here, not in ``enqueue``), ``place``
 #: (``shard_batch``), ``enqueue`` (the ``step_fn`` call), ``metrics``
-#: (``bag.update`` + Speedometer, which holds the every-``frequent``-steps
-#: host sync), ``checkpoint`` (the epoch-end save)
+#: (``bag.update`` + Speedometer, whose every-``frequent``-steps line reads
+#: only dispatches already done: no host sync), ``checkpoint`` (the
+#: epoch-end save)
 LOOP_SPANS = ("train.next_batch", "train.key", "train.place",
               "train.enqueue", "train.metrics", "train.checkpoint")
 _NO_SPAN = contextlib.nullcontext()

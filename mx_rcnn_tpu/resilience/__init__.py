@@ -41,8 +41,10 @@ from mx_rcnn_tpu.resilience.backend import (
     classify_backend_error,
 )
 from mx_rcnn_tpu.resilience.heal import (
+    DeferredSnapshot,
     HealCarry,
     Healer,
+    compile_tree_copy,
     host_tree_copy,
 )
 from mx_rcnn_tpu.resilience.preempt import (
@@ -63,8 +65,10 @@ __all__ = [
     "BackendUnavailableError",
     "acquire_backend",
     "classify_backend_error",
+    "DeferredSnapshot",
     "HealCarry",
     "Healer",
+    "compile_tree_copy",
     "host_tree_copy",
     "RESUMABLE_RC",
     "PreemptionExit",

@@ -21,6 +21,14 @@ recovery loop (tools/train.py::fit_detector):
    dispatches are re-derived deterministically (epoch order is
    f(seed, epoch), per-dispatch keys fold the global index), so the
    resumed trajectory is the one the uninterrupted run would have taken.
+   The refresh is a DEFERRED read (``DeferredSnapshot``): begun at its
+   dispatch as one device-side copy of the state with its host
+   transfers started, installed at a later dispatch once the copy is
+   there, so the loop never waits on the dispatch it has just made.
+   Until then the earlier snapshot stands: the replay is bounded by
+   ``heal_snapshot_dispatches`` + the dispatches a snapshot is in
+   flight (the depth of the device's queue, 10-12 on the chip), and a
+   snapshot still pending when the loss lands is dropped, not awaited.
 3. **Re-acquire.** Tear the cached backend down (the clear used for the
    CPU-fallback path) and re-acquire through the classified
    retry-with-backoff of ``acquire_backend`` under the SAME
@@ -65,18 +73,44 @@ from mx_rcnn_tpu.resilience.backend import (
 )
 
 
+def _owned(leaf):
+    """One leaf as a host-OWNED numpy array (``np.array`` copies)."""
+    import jax
+    import numpy as np
+
+    return np.array(jax.device_get(leaf))
+
+
 def host_tree_copy(tree):
     """Host-OWNED numpy copies of a pytree — THE heal-carry invariant:
     ``np.array`` of every leaf, never zero-copy views of runtime buffers
     (the backend they came from is about to be torn down, and on the CPU
     client ``device_get`` can alias the live buffer). Every capture/
     fallback site goes through here so the invariant lives in one place.
+    BLOCKING, leaf by leaf: for the callers that need the live state now
+    (``recover``'s capture, the starting fallback); the loop's periodic
+    snapshot is a ``DeferredSnapshot``, which keeps the same invariant.
     jax imported lazily — this module stays importable without it."""
     import jax
-    import numpy as np
 
-    return jax.tree_util.tree_map(
-        lambda x: np.array(jax.device_get(x)), tree)
+    return jax.tree_util.tree_map(_owned, tree)
+
+
+def compile_tree_copy(tree):
+    """ONE device program that copies every leaf of ``tree`` (device
+    arrays) into a fresh buffer of the same sharding, compiled for
+    ``tree``'s types without running. The train step donates its state,
+    so a snapshot that is read after the next dispatch needs buffers the
+    step does not own; this program's inputs are NOT donated, so its
+    outputs cannot alias them. Returns the compiled callable."""
+    import jax
+    import jax.numpy as jnp
+
+    def copy(t):
+        return jax.tree_util.tree_map(jnp.copy, t)
+
+    shardings = jax.tree_util.tree_map(lambda x: x.sharding, tree)
+    return jax.jit(copy, out_shardings=shardings).lower(tree).compile()
 
 
 @dataclass
@@ -95,6 +129,71 @@ class HealCarry:
     epoch: int = 0
     dispatch: int = 0
     bag: Optional[Tuple[Dict[str, float], Dict[str, int]]] = None
+
+
+class DeferredSnapshot:
+    """A host read of the train state BEGUN at one dispatch and FINISHED
+    over later ones. ``trees`` is ``(params, opt_state)`` as device arrays
+    nobody else owns (``compile_tree_copy``'s output, enqueued behind the
+    step whose state it copies); their host transfers start here and run
+    while the device goes on. ``bag`` is a ``MetricBag.fork()``: the sums
+    and the pending device scalars as they stood at that dispatch.
+    ``rebase`` (optional, ``opt_state -> opt_state``) is applied to the
+    host copy: ``_capture``'s schedule-count normalization.
+
+    ``advance()`` is called once per later dispatch and never waits for
+    the device: it turns leaves that are there into host-OWNED copies
+    (``host_tree_copy``'s invariant: ``np.array``, never a view of a
+    runtime buffer) for at most ``POLL_BUDGET_S`` of the loop's time, so
+    what one dispatch pays is bounded whatever the state's size, and
+    returns True once all of it is on the host. ``carry()`` then builds
+    the ``HealCarry`` of the position the snapshot was TAKEN at."""
+
+    #: the loop's time one dispatch may spend on host copies: under what a
+    #: dispatch has to spare on the chip (a 94 ms step against ~40 ms of
+    #: host work), so the device's queue stays as full as it was
+    POLL_BUDGET_S = 0.04
+
+    def __init__(self, trees, *, epoch: int, dispatch: int, bag=None,
+                 rebase: Optional[Callable] = None):
+        import jax
+
+        self.epoch, self.dispatch = int(epoch), int(dispatch)
+        self._bag, self._rebase = bag, rebase
+        leaves, self._treedef = jax.tree_util.tree_flatten(trees)
+        self.nbytes = sum(int(x.nbytes) for x in leaves)
+        for leaf in leaves:
+            leaf.copy_to_host_async()
+        self._device = leaves[::-1]  # still to read, the next one last
+        self._host = []              # read so far, in leaf order
+        #: the Healer's: the run's dispatch count it was taken at, and the
+        #: loop's own seconds spent on it so far
+        self.taken_at, self.loop_s = 0, 0.0
+
+    def advance(self, clock: Callable[[], float] = time.monotonic) -> bool:
+        deadline = clock() + self.POLL_BUDGET_S
+        while self._device:
+            if not self._device[-1].is_ready():
+                return False
+            # dropping the device leaf frees its buffer and the runtime's
+            # host staging copy as the read goes
+            self._host.append(_owned(self._device.pop()))
+            if self._device and clock() >= deadline:
+                return False
+        return self._bag is None or self._bag.ready()
+
+    def carry(self) -> HealCarry:
+        import jax
+
+        params, opt_state = jax.tree_util.tree_unflatten(
+            self._treedef, self._host)
+        if self._rebase is not None:
+            opt_state = self._rebase(opt_state)
+        return HealCarry(
+            params=params, opt_state=opt_state, epoch=self.epoch,
+            dispatch=self.dispatch,
+            bag=(self._bag.snapshot(ready_only=True)
+                 if self._bag is not None else None))
 
 
 class Healer:
@@ -123,6 +222,10 @@ class Healer:
         self._consecutive = 0
         self._fallback: Optional[HealCarry] = None
         self._since_snapshot = 0
+        # The deferred periodic snapshot (at most one in flight), and the
+        # dispatches this run completed (what a snapshot's age counts in).
+        self._pending: Optional[DeferredSnapshot] = None
+        self._dispatches = 0
         self._n_devices: Optional[int] = None
         self._footprint: Optional[int] = None
         self._platform: Optional[str] = None
@@ -157,6 +260,7 @@ class Healer:
         """A dispatch completed — the backend is live again; re-arm the
         consecutive-heal cap."""
         self._consecutive = 0
+        self._dispatches += 1
 
     def set_fallback(self, carry: HealCarry):
         """Install/refresh the standing host snapshot (initial carry,
@@ -165,15 +269,73 @@ class Healer:
 
     def snapshot_due(self) -> bool:
         """True every ``heal_snapshot_dispatches`` completed dispatches
-        (0 disables periodic snapshots — live capture only)."""
-        every = int(getattr(self.rcfg, "heal_snapshot_dispatches", 0))
-        if every <= 0:
+        (0 disables periodic snapshots — live capture only). Due is when
+        a snapshot is BEGUN (``begin_snapshot``); it stands as the
+        fallback some dispatches later (``poll_snapshot``), so the replay
+        after a loss is bounded by this cadence + the dispatches a
+        snapshot is in flight."""
+        if not self.snapshots:
             return False
         self._since_snapshot += 1
-        if self._since_snapshot >= every:
+        if self._since_snapshot >= int(self.rcfg.heal_snapshot_dispatches):
             self._since_snapshot = 0
             return True
         return False
+
+    @property
+    def snapshots(self) -> bool:
+        """Are periodic snapshots on (``heal_snapshot_dispatches`` > 0)?"""
+        return int(getattr(self.rcfg, "heal_snapshot_dispatches", 0)) > 0
+
+    @property
+    def snapshot_pending(self) -> bool:
+        return self._pending is not None
+
+    def begin_snapshot(self, make: Callable[[], DeferredSnapshot]):
+        """Begin the periodic snapshot at the dispatch just made, unless
+        one is still in flight (a second is never begun: the copy it
+        would hold is memory, and the first installs sooner)."""
+        if self._pending is not None:
+            return
+        t0 = self._clock()
+        self._pending = snap = make()
+        snap.taken_at = self._dispatches
+        snap.loop_s = self._clock() - t0
+
+    def poll_snapshot(self):
+        """Called once per dispatch: advance the pending snapshot's read
+        and install it as the fallback once all of it is on the host;
+        until then the earlier fallback stands. Never waits for the
+        device, and takes a bounded part of the loop's time."""
+        snap = self._pending
+        if snap is None:
+            return
+        t0 = self._clock()
+        done = snap.advance(self._clock)
+        if done:
+            self.set_fallback(snap.carry())
+            self._pending = None
+        snap.loop_s += self._clock() - t0
+        if not done:
+            return
+        in_flight = self._dispatches - snap.taken_at
+        loop_ms = snap.loop_s * 1e3
+        logger.info(
+            "graftheal: snapshot taken at dispatch %d (epoch %d dispatch "
+            "%d) installed %d dispatch(es) later: %.1f MB, %.1f ms of the "
+            "loop's time", snap.taken_at, snap.epoch, snap.dispatch,
+            in_flight, snap.nbytes / 1e6, loop_ms)
+        if self.elog is not None and self.elog.enabled:
+            self.elog.emit("snapshot", epoch=snap.epoch,
+                           dispatch=snap.dispatch, taken_at=snap.taken_at,
+                           in_flight=in_flight, loop_ms=round(loop_ms, 3),
+                           bytes=snap.nbytes)
+
+    def drop_snapshot(self):
+        """Forget a snapshot in flight without waiting for it (the
+        session is ending, or the backend it would be read from is going
+        away); the earlier fallback stands."""
+        self._pending = None
 
     # -- the recovery itself ------------------------------------------------
 
@@ -207,6 +369,7 @@ class Healer:
             # as a `heal` event, not a stall dump (reset() below
             # re-arms).
             self.watchdog.pause()
+        self.drop_snapshot()
         mode = "live"
         try:
             carry = capture()

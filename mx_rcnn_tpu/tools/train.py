@@ -36,6 +36,7 @@ from mx_rcnn_tpu.parallel.mesh import (create_mesh, place_replicated,
                                        shard_batch)
 from mx_rcnn_tpu.resilience import (
     CoordinatedStop,
+    DeferredSnapshot,
     FileKVStore,
     HealCarry,
     Healer,
@@ -44,6 +45,7 @@ from mx_rcnn_tpu.resilience import (
     Quorum,
     QuorumExcludedError,
     acquire_backend,
+    compile_tree_copy,
     host_tree_copy,
 )
 from mx_rcnn_tpu.resilience import chaos
@@ -492,7 +494,8 @@ def fit_detector(
     # tear down + re-acquire the backend under the deadline, rebuild the
     # session (possibly on fewer devices) and continue. The initial
     # fallback is a host-owned copy of the starting state, refreshed by
-    # periodic snapshots and by every successful capture.
+    # periodic snapshots (deferred reads: _begin_snapshot) and by every
+    # successful capture.
     healer = None
     if cfg.resilience.heal and n_hosts > 1 and quorum is None:
         # Multi-host heal NEEDS the quorum: one process tearing its
@@ -541,7 +544,7 @@ def fit_detector(
 
     # Per-session device-facing objects, (re)assigned by the session loop
     # below; declared here so the closures and the return path see them.
-    state = bag = None
+    state = bag = copy_state = None
     pos = (carry.epoch, carry.dispatch)
     # Coordinated-stop latch: this host has published its preemption
     # request to the quorum (at most one request per run — the agreed
@@ -574,7 +577,10 @@ def fit_detector(
         """graftheal's in-memory emergency capture: the live train state
         as host-OWNED copies (np.array, never device views —
         the backend they came from is about to be torn down), tagged
-        with its position and the drained metric sums."""
+        with its position and the drained metric sums. BLOCKING: it waits
+        for the newest dispatch, then reads leaf by leaf. For the callers
+        that need the live state now (Healer.recover, HealthMonitor); the
+        loop's periodic snapshot is _begin_snapshot."""
         if state is None:
             raise RuntimeError("no live state to capture yet")
         cap_params = host_tree_copy(state.params)
@@ -591,6 +597,22 @@ def fit_detector(
         return HealCarry(params=cap_params, opt_state=cap_opt,
                          epoch=pos[0], dispatch=pos[1],
                          bag=bag.snapshot() if bag is not None else None)
+
+    def _begin_snapshot() -> DeferredSnapshot:
+        """_capture as a deferred read, for the loop's periodic snapshot:
+        ONE device program enqueued behind the step just dispatched copies
+        parameters and optimizer state into buffers the next (donating)
+        step does not own, their host transfers start, and the position,
+        the schedule rebase and the bag as they stand NOW ride along.
+        Healer.poll_snapshot installs it at a later dispatch, once the
+        values are there; the loop never waits on its newest dispatch."""
+        count = pos[0] * steps_per_epoch + pos[1]
+        return DeferredSnapshot(
+            copy_state((state.params, state.opt_state)),
+            epoch=pos[0], dispatch=pos[1], bag=bag.fork(),
+            # session-relative counters -> absolute, as in _capture
+            rebase=((lambda opt: rebase_schedule_count(opt, count))
+                    if sched_begin else None))
 
     def _honor_preemption(at_epoch: int, at_dispatch: Optional[int],
                           need_save: bool = True):
@@ -673,7 +695,7 @@ def fit_detector(
     try:
         while True:  # one iteration per backend session; graftheal re-enters
             try:
-                state = bag = None
+                state = bag = copy_state = None
                 pos = (carry.epoch, carry.dispatch)
                 if cost_tracker is not None:
                     # New session, possibly a new per-device program
@@ -878,15 +900,20 @@ def fit_detector(
                         pos = (epoch, i + 1)
                         timer.dispatched()
                         with timer.span("train.metrics"):
-                            # Speedometer holds the loop's one host sync,
-                            # every `frequent` dispatches
+                            # Speedometer's line, every `frequent`
+                            # dispatches, reads the means of the
+                            # dispatches already done (the bag's
+                            # ready-only drain): no host sync here
                             bag.update(metrics)
                             speedometer(epoch, i, bag)
                         if tracer is not None:
                             # timer.total_steps increments when the
                             # generator resumes — this dispatch is the
-                            # (+1)th completed.
-                            tracer.step_completed(timer.total_steps + 1)
+                            # (+1)th completed. A window that closes
+                            # here first waits for this dispatch (the
+                            # loop itself never does).
+                            tracer.step_completed(timer.total_steps + 1,
+                                                  outputs=metrics)
                         if monitor is not None:
                             # stores a reference per dispatch; pulls to
                             # host (and runs the tripwires) only at the
@@ -898,8 +925,19 @@ def fit_detector(
                         done = i + 1  # dispatches complete in this epoch
                         if healer is not None:
                             healer.note_progress()
+                            if copy_state is None and healer.snapshots:
+                                # The snapshot's copy program, compiled
+                                # for the state the step RETURNS (its
+                                # types are every later dispatch's), on
+                                # the session's first dispatch: set-up
+                                # pays for it, never the steady state (a
+                                # compile at dispatch 200 would drain the
+                                # queue the deferred read is to spare).
+                                copy_state = compile_tree_copy(
+                                    (state.params, state.opt_state))
+                            healer.poll_snapshot()
                             if healer.snapshot_due():
-                                healer.set_fallback(_capture())
+                                healer.begin_snapshot(_begin_snapshot)
                         if chaos_spec.active:
                             chaos_spec.maybe_sigterm(
                                 epoch * steps_per_epoch + done)
@@ -1058,6 +1096,8 @@ def fit_detector(
                 recorder.dump("crash")
         raise
     finally:
+        if healer is not None:
+            healer.drop_snapshot()  # the session is over: not awaited
         if guard is not None:
             guard.uninstall()
         if watchdog is not None:

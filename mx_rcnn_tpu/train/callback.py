@@ -17,8 +17,12 @@ class Speedometer:
     """Logs the reference-format throughput line and, when a graftscope
     event log is attached, also emits each window as a ``step`` event
     carrying ``samples_per_sec`` (obs/report.py prefers these measured
-    windows: they bracket the MetricBag drain, so they are honest
-    end-to-end throughput)."""
+    windows). The line's metrics are the bag's READY-ONLY means
+    (train/metrics.py): read from dispatches already done, never waited
+    for, so they trail the loop by at most the depth of the device's
+    queue. ``Speed`` is dispatches per host second; with the queue full the
+    loop is held to the device's rate by back-pressure (at ``train.key``,
+    obs/timing.py), so it stays honest end-to-end throughput."""
 
     def __init__(self, batch_size: int, frequent: int = 20, event_log=None):
         self.batch_size = batch_size
@@ -36,7 +40,7 @@ class Speedometer:
                      / (time.monotonic() - self._tic))
             logger.info(
                 "Epoch[%d] Batch [%d]\tSpeed: %.2f samples/sec\t%s",
-                epoch, batch, speed, metrics.format(),
+                epoch, batch, speed, metrics.format(ready_only=True),
             )
             if self.event_log is not None and self.event_log.enabled:
                 self.event_log.emit("step", epoch=epoch, batch=batch,

@@ -298,6 +298,212 @@ def test_healer_snapshot_cadence():
 
 
 # ---------------------------------------------------------------------------
+# the deferred snapshot: begun at its dispatch, installed at a later one
+# ---------------------------------------------------------------------------
+
+class _DeviceLeaf:
+    """A device array's surface as DeferredSnapshot uses it. Reading one
+    that is not ready is the blocking read the loop must not make; what it
+    hands out is a VIEW of a buffer it keeps, as a runtime's is."""
+
+    def __init__(self, value):
+        self._buf = np.asarray(value)
+        self.ready = self.transfer_started = False
+
+    @property
+    def nbytes(self):
+        return self._buf.nbytes
+
+    def copy_to_host_async(self):
+        self.transfer_started = True
+
+    def is_ready(self):
+        return self.ready
+
+    def __array__(self, dtype=None, copy=None):
+        if not self.ready:
+            raise AssertionError("host read of a leaf that is not ready")
+        return self._buf[...]
+
+
+def _pending_snapshot(healer, epoch, dispatch, bag=None, rebase=None):
+    """Begin a snapshot of a two-leaf state + an optax-style count."""
+    trees = ({"w": _DeviceLeaf(np.full(3, 5.0, np.float32))},
+             {"count": _DeviceLeaf(np.int32(2)),
+              "trace": _DeviceLeaf(np.full(3, 0.5, np.float32))})
+    leaves = [trees[0]["w"], trees[1]["count"], trees[1]["trace"]]
+    healer.begin_snapshot(lambda: heal_mod.DeferredSnapshot(
+        trees, epoch=epoch, dispatch=dispatch, bag=bag, rebase=rebase))
+    return leaves
+
+
+def test_deferred_snapshot_installs_once_ready_with_taken_position(
+        monkeypatch, tmp_path):
+    """(i) Until every leaf is there the earlier fallback stands; the one
+    installed then is tagged with the position it was TAKEN at (not the
+    one it was installed at), carries the rebased schedule count and the
+    bag's sums as of that position, and one `snapshot` event says so."""
+    from mx_rcnn_tpu.train.optimizer import rebase_schedule_count
+
+    healer, elog = _hermetic_healer(monkeypatch, tmp_path)
+    first = HealCarry(params={"w": np.zeros(3)}, epoch=0, dispatch=0)
+    healer.set_fallback(first)
+    bag = MetricBag()
+    bag.update({"TotalLoss": 2.0})
+    bag.update({"TotalLoss": 4.0})
+    for _ in range(7):
+        healer.note_progress()
+    leaves = _pending_snapshot(
+        healer, epoch=1, dispatch=7, bag=bag.fork(),
+        rebase=lambda opt: rebase_schedule_count(opt, 107))
+    assert healer.snapshot_pending
+    assert all(leaf.transfer_started for leaf in leaves)
+    bag.update({"TotalLoss": 60.0})  # a later dispatch: not the snapshot's
+    for _ in range(2):
+        healer.note_progress()
+        healer.poll_snapshot()  # never reads a leaf that is not ready
+        assert healer._fallback is first and healer.snapshot_pending
+    leaves[0].ready = leaves[1].ready = True
+    healer.note_progress()
+    healer.poll_snapshot()  # one leaf still out
+    assert healer._fallback is first
+    leaves[2].ready = True
+    healer.note_progress()
+    healer.poll_snapshot()
+    elog.close()
+
+    got = healer._fallback
+    assert not healer.snapshot_pending and got is not first
+    assert (got.epoch, got.dispatch) == (1, 7)
+    np.testing.assert_array_equal(got.params["w"], np.full(3, 5.0))
+    assert int(got.opt_state["count"]) == 107
+    np.testing.assert_array_equal(got.opt_state["trace"], np.full(3, 0.5))
+    assert got.bag == ({**{n: 0.0 for n in bag.names}, "TotalLoss": 6.0},
+                       {**{n: 0 for n in bag.names}, "TotalLoss": 2})
+    (ev,) = [e for e in report.load_events(str(tmp_path))
+             if e["type"] == "snapshot"]
+    assert (ev["epoch"], ev["dispatch"], ev["taken_at"]) == (1, 7, 7)
+    assert ev["in_flight"] == 4 and ev["bytes"] == 12 + 4 + 12
+    assert ev["loop_ms"] >= 0
+    summary = report.summarize(report.load_events(str(tmp_path)))
+    assert summary["heals"]["snapshots"] == 1
+    assert summary["heals"]["snapshot_in_flight_max"] == 4
+    assert "snapshots:  1 installed" in report.render(summary)
+
+
+def test_one_poll_takes_a_bounded_part_of_the_loops_time(monkeypatch):
+    """However large the state, a dispatch pays at most POLL_BUDGET_S (and
+    one leaf) of host copies: the read spreads over the next dispatches
+    and the earlier fallback stands until all of it is on the host."""
+    now = [0.0]
+
+    def clock():  # every look at the clock costs 30 ms
+        now[0] += 0.03
+        return now[0]
+
+    monkeypatch.setattr(heal_mod, "_clear_backend_cache", lambda: None)
+    healer = Healer(ResilienceConfig(), clock=clock)
+    first = HealCarry(params={}, epoch=0, dispatch=0)
+    healer.set_fallback(first)
+    leaves = _pending_snapshot(healer, epoch=0, dispatch=5)
+    for leaf in leaves:
+        leaf.ready = True
+    healer.poll_snapshot()  # 40 ms: two of the three leaves
+    assert healer._fallback is first and healer.snapshot_pending
+    healer.poll_snapshot()
+    assert healer._fallback.dispatch == 5 and not healer.snapshot_pending
+
+
+def test_second_snapshot_is_not_begun_while_one_is_pending(monkeypatch):
+    healer, _ = _hermetic_healer(monkeypatch)
+    leaves = _pending_snapshot(healer, epoch=0, dispatch=3)
+    made = []
+    healer.begin_snapshot(lambda: made.append(1))
+    assert not made and healer.snapshot_pending
+    for leaf in leaves:
+        leaf.ready = True
+    healer.poll_snapshot()
+    assert healer._fallback.dispatch == 3 and not healer.snapshot_pending
+
+
+def test_loss_while_snapshot_pending_falls_back_to_the_earlier_one(
+        monkeypatch, tmp_path):
+    """(ii) A loss with a snapshot in flight: the pending one is dropped,
+    not awaited (its backend is going away), and the rollback is to the
+    snapshot that was standing."""
+    healer, elog = _hermetic_healer(monkeypatch, tmp_path)
+    standing = HealCarry(params={"w": np.zeros(3)}, epoch=1, dispatch=7)
+    healer.set_fallback(standing)
+    leaves = _pending_snapshot(healer, epoch=2, dispatch=1)
+
+    def bad_capture():
+        raise RuntimeError("device_get on a dead backend")
+
+    got = healer.recover(RuntimeError("UNAVAILABLE: gone"), bad_capture)
+    assert got is standing and not healer.snapshot_pending
+    for leaf in leaves:  # too late: nobody is waiting for it any more
+        leaf.ready = True
+    healer.poll_snapshot()
+    elog.close()
+    assert healer._fallback is standing
+    events = report.load_events(str(tmp_path))
+    (ev,) = [e for e in events if e["type"] == "heal"]
+    assert ev["mode"] == "snapshot" and (ev["epoch"], ev["dispatch"]) == (1, 7)
+    assert not [e for e in events if e["type"] == "snapshot"]
+
+
+def test_deferred_snapshot_leaves_are_host_owned():
+    """(iv) Through the real copy program: what is installed is numpy that
+    owns its memory, with no jax array inside, holding the values of the
+    dispatch it was taken at even after the originals are gone (the next,
+    donating step takes them on the chip; here they are deleted)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    # as the loop's state lies: replicated over the mesh (place_replicated)
+    # or, under network.tensor_parallel, partitioned along `model`
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("data", "model"))
+    rep, part = NamedSharding(mesh, P()), NamedSharding(mesh, P("model"))
+    state = ({"w": jax.device_put(
+                  jnp.arange(6, dtype=jnp.float32).reshape(2, 3), part),
+              "b": jax.device_put(jnp.ones(3, jnp.float32), rep)},
+             {"count": jax.device_put(jnp.asarray(4, jnp.int32), rep),
+              "trace": {"w": jax.device_put(
+                  jnp.full((2, 3), 0.25, jnp.float32), part)}})
+    copy = heal_mod.compile_tree_copy(state)
+    copied = copy(state)
+    for a, b in zip(jax.tree_util.tree_leaves(state),
+                    jax.tree_util.tree_leaves(copied)):
+        assert a.sharding == b.sharding
+        assert not ({x.data.unsafe_buffer_pointer()
+                     for x in a.addressable_shards}
+                    & {x.data.unsafe_buffer_pointer()
+                       for x in b.addressable_shards})
+    snap = heal_mod.DeferredSnapshot(copied, epoch=0, dispatch=3)
+    for leaf in jax.tree_util.tree_leaves(state):
+        leaf.delete()
+    jax.block_until_ready(copied)
+    assert snap.advance()
+    carry = snap.carry()
+    leaves = jax.tree_util.tree_leaves((carry.params, carry.opt_state))
+    assert len(leaves) == 4 and snap.nbytes == sum(x.nbytes for x in leaves)
+    for leaf in leaves:
+        assert type(leaf) is np.ndarray and leaf.flags.owndata
+    np.testing.assert_array_equal(
+        carry.params["w"], np.arange(6, dtype=np.float32).reshape(2, 3))
+    assert int(carry.opt_state["count"]) == 4
+    assert carry.bag is None and (carry.epoch, carry.dispatch) == (0, 3)
+
+
+def test_snapshot_event_type_is_schema_legal(tmp_path):
+    elog = EventLog(str(tmp_path / "e.jsonl"))
+    elog.emit("snapshot", taken_at=200, in_flight=11, loop_ms=80.0,
+              bytes=385_000_000)  # raises if the schema missed it
+    elog.close()
+
+
+# ---------------------------------------------------------------------------
 # StallWatchdog.reset after a heal (satellite fix)
 # ---------------------------------------------------------------------------
 
@@ -562,6 +768,48 @@ def test_heal_device_loss_double_loss_parity_tree(tmp_path, monkeypatch,
     # loss fired before the dispatch completing step 4 (epoch 1 of 2x3,
     # dispatch 0): both captures are the last known-good position
     assert [(e["epoch"], e["dispatch"]) for e in heals] == [(1, 0), (1, 0)]
+
+
+@pytest.mark.compile_heavy
+def test_heal_device_loss_rolls_back_to_the_deferred_snapshot(
+        tmp_path, monkeypatch, tree_baseline):
+    """(iii) Through fit_detector, snapshots every 3 dispatches: the one
+    begun behind dispatch 3 (the end of epoch 0) is installed during epoch
+    1; the loss before the dispatch completing step 6 finds the live state
+    unreadable (as donated buffers on a dead backend are), rolls back to
+    that snapshot, replays epoch 0's end and dispatches 4 and 5, and the
+    run still reaches the uninterrupted run's parameters bit for bit."""
+    import sys
+
+    from mx_rcnn_tpu.tools import train as train_mod
+
+    real = train_mod.host_tree_copy
+
+    def unreadable_from_capture(tree):
+        if sys._getframe(1).f_code.co_name == "_capture":
+            raise RuntimeError("donated buffer on a dead backend")
+        return real(tree)
+
+    monkeypatch.setattr(train_mod, "host_tree_copy", unreadable_from_capture)
+    # two dispatches lie between the snapshot and the loss: no budget on
+    # what a dispatch may copy (its own gate is above)
+    monkeypatch.setattr(heal_mod.DeferredSnapshot, "POLL_BUDGET_S", 60.0)
+    monkeypatch.setenv(chaos.ENV_VAR, "device_lost_at_step=6")
+    chaos.reset()
+    obs_dir = str(tmp_path / "obs_healed")
+    params_h = driver.run_fit(
+        str(tmp_path / "healed"), obs_dir=obs_dir,
+        over_extra={"resilience.heal_snapshot_dispatches": 3})
+    events = report.load_events(obs_dir)
+    (ev,) = [e for e in events if e["type"] == "heal"]
+    assert ev["mode"] == "snapshot"
+    assert (ev["epoch"], ev["dispatch"]) == (0, 3)
+    snaps = [e for e in events if e["type"] == "snapshot"]
+    assert (snaps[0]["epoch"], snaps[0]["dispatch"],
+            snaps[0]["taken_at"]) == (0, 3, 3)
+    assert snaps[0]["t_mono"] < ev["t_mono"] and snaps[0]["in_flight"] >= 1
+    assert [e["type"] for e in events].count("crash") == 0
+    _assert_trees_bitexact(tree_baseline, params_h)
 
 
 @pytest.mark.compile_heavy
