@@ -1,0 +1,8 @@
+"""Device ms per step of the ``proposal`` stage (decode, top-k, the NMS
+launches): the pyramid cell's copy of ``stage.proposal_ms.train``, whose
+``workloads`` tests/benchmarks/test_bm_trace_scopes.py pins to C4's two cells."""
+from benchmarks import trace_scopes
+
+
+def read(run):
+    return trace_scopes.stage_ms(run, "proposal")

@@ -1,0 +1,16 @@
+"""Operations a pyramid detector's forward and backward passes REQUIRE per
+image (benchmarks/flops_fpn.py) x images/s/chip of this run, over the chip's
+bf16 peak."""
+from benchmarks import flops_fpn, peaks
+
+
+def read(run):
+    spec = run["spec"]
+    if spec.get("flops") != "fpn_flops":
+        return None  # another family's work is another reader's to count
+    try:
+        peak = peaks.peak(run["device_kind"])
+    except KeyError:
+        return None  # the CPU rehearsal: no published peak, no share of one
+    need = flops_fpn.fpn_flops(spec, "train", spec["train"]["batch_rois"])
+    return 100.0 * need * run["rate"] / peak["bf16_flops"]

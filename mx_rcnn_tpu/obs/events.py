@@ -63,6 +63,9 @@ EVENT_TYPES = (
                    # run's dispatch it was taken at, the dispatches it was
                    # in flight, the loop's own ms in it, its bytes
                    # (resilience/heal.py Healer.poll_snapshot)
+    "roi_levels",  # pyramid families, once a run with obs.enabled: the
+                   # share of the first dispatch's sampled rois that FPN
+                   # Eq. 1 assigns to each pooled level (tools/train.py)
     "cost",        # graftprof: XLA cost/memory accounting for one
                    # compiled shape bucket (flops, hbm split — obs/costs.py)
     "trace",       # graftprof: one closed jax.profiler capture window

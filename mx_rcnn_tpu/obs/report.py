@@ -191,6 +191,7 @@ def summarize(events: List[Dict[str, Any]]) -> Dict[str, Any]:
     health_evs = by_type.get("health", ())
     last_health = health_evs[-1] if health_evs else None
     snapshots = by_type.get("snapshot", ())
+    roi_levels = (by_type.get("roi_levels") or [{}])[-1]
     summary: Dict[str, Any] = {
         "run": {k: run_meta.get(k) for k in
                 ("config_digest", "network", "dataset", "mesh",
@@ -287,6 +288,9 @@ def summarize(events: List[Dict[str, Any]]) -> Dict[str, Any]:
             "snapshot_loop_ms_max": max(
                 (e.get("loop_ms", 0.0) for e in snapshots), default=None),
         },
+        # pyramid families: the first dispatch's sampled rois by the level
+        # FPN Eq. 1 pools each from (P2..P5), None for the others
+        "roi_level_share": roi_levels.get("share"),
         # graftquorum: multi-host coordination rounds — per-host records
         # interleaved by load_events, so `hosts` is how many distinct
         # process stamps the fold saw and `excluded` collects every host
@@ -461,6 +465,11 @@ def render(summary: Dict[str, Any]) -> str:
             f"  snapshots:  {he['snapshots']} installed, in flight <= "
             f"{he['snapshot_in_flight_max']} dispatch(es), <= "
             f"{he['snapshot_loop_ms_max']:.1f} ms of the loop each")
+    if summary.get("roi_level_share"):
+        lines.append("  roi levels: " + ", ".join(
+            f"P{lv} {100 * s:.1f}%" for lv, s in
+            enumerate(summary["roi_level_share"], start=2))
+            + " of the first dispatch's sampled rois")
     da = summary.get("data", {})
     if (da.get("quarantined") or da.get("retries")
             or da.get("worker_deaths") or da.get("cap_trips")):

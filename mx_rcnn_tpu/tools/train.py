@@ -21,7 +21,7 @@ from mx_rcnn_tpu.data.datasets.imdb import filter_roidb, merge_roidb
 from mx_rcnn_tpu.data.feedguard import FeedGuard
 from mx_rcnn_tpu.data.loader import AnchorLoader
 from mx_rcnn_tpu.logger import logger
-from mx_rcnn_tpu.models.zoo import build_model, forward_train, init_params
+from mx_rcnn_tpu.models.zoo import build_model, init_params
 from mx_rcnn_tpu.obs import (
     StallWatchdog,
     StepTimer,
@@ -550,6 +550,9 @@ def fit_detector(
     # request to the quorum (at most one request per run — the agreed
     # boundary is cached by CoordinatedStop.check thereafter).
     stop_requested = False
+    # One `roi_levels` event a run (obs.enabled, pyramid families): read
+    # from the first dispatch's metrics, set-up's one wait for a dispatch.
+    roi_levels_due = obs_log.enabled
 
     def _ckpt_meta(at_epoch: int, at_dispatch: Optional[int],
                    hosts=None):
@@ -825,8 +828,7 @@ def fit_detector(
                 donate = jax.default_backend() != "cpu"
                 step_fn = make_train_step(model, cfg, mesh=mesh,
                                           donate=donate,
-                                          forward_fn=(forward_fn
-                                                      or forward_train),
+                                          forward_fn=forward_fn,
                                           param_specs=param_specs,
                                           health=health_on)
                 # Per-dispatch rng keys are derived from the dispatch's
@@ -906,6 +908,17 @@ def fit_detector(
                             # ready-only drain): no host sync here
                             bag.update(metrics)
                             speedometer(epoch, i, bag)
+                        if roi_levels_due:
+                            roi_levels_due = False
+                            if "RoiLevelShare" in metrics:
+                                share = [round(float(s), 4) for s in
+                                         metrics["RoiLevelShare"]]
+                                obs_log.emit("roi_levels", epoch=epoch,
+                                             dispatch=i + 1, share=share)
+                                logger.info(
+                                    "sampled rois by pyramid level "
+                                    "(P2..P5) at dispatch %d: %s", i + 1,
+                                    share)
                         if tracer is not None:
                             # timer.total_steps increments when the
                             # generator resumes — this dispatch is the

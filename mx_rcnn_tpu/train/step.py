@@ -22,7 +22,7 @@ from flax import struct
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from mx_rcnn_tpu.config import Config
-from mx_rcnn_tpu.models.faster_rcnn import FasterRCNN, forward_train
+from mx_rcnn_tpu.models.faster_rcnn import FasterRCNN
 from mx_rcnn_tpu.obs.profile import stage
 from mx_rcnn_tpu.resilience import chaos
 from mx_rcnn_tpu.train import health as health_mod
@@ -115,6 +115,9 @@ def _metric_parts(aux: Dict[str, jnp.ndarray]) -> Dict[str, tuple]:
         rcnn_correct = (rcnn_pred == aux["rcnn_labels"]) & rcnn_valid
         out["RCNNAcc"] = (jnp.sum(rcnn_correct).astype(jnp.float32),
                           jnp.sum(rcnn_valid).astype(jnp.float32))
+    if "roi_level_counts" in aux:  # pyramid families: a vector, P2..P5
+        counts = aux["roi_level_counts"]
+        out["RoiLevelShare"] = (counts, jnp.sum(counts))
     return out
 
 
@@ -131,7 +134,7 @@ def make_train_step(
     cfg: Config,
     mesh: Optional[Mesh] = None,
     donate: bool = True,
-    forward_fn: Callable = forward_train,
+    forward_fn: Optional[Callable] = None,
     param_specs=None,
     health: bool = False,
 ) -> Callable[[TrainState, Dict[str, jnp.ndarray], jax.Array],
@@ -142,7 +145,8 @@ def make_train_step(
     XLA inserts the gradient all-reduce over ICI (the KVStore replacement).
     Without: plain single-device jit. forward_fn selects the training graph
     (end2end / rpn-only / rcnn-only — the reference's get_*_train symbol
-    variants).
+    variants); None is the model family's own end2end forward
+    (models/zoo.py::forward_train, resolved here: zoo imports every family).
 
     graftcanvas (image.canvas_pack): packed batches shard/accumulate
     UNCHANGED through this machinery — every leaf's leading dim is the
@@ -172,6 +176,9 @@ def make_train_step(
     poisons step K's final gradients IN-GRAPH here, after the accum
     fold — the registered "grad_inject" site, traced in at build time.
     """
+
+    if forward_fn is None:
+        from mx_rcnn_tpu.models.zoo import forward_train as forward_fn
 
     accum = max(1, int(getattr(cfg.train, "grad_accum_steps", 1)))
     # graftpulse chaos: the spec is env-carried and static per process —

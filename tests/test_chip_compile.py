@@ -210,6 +210,38 @@ def test_one_chip_train_step_names_the_kernel(one_chip_mesh, monkeypatch):
     _assert_kernel_named_in_its_stage(hlo)
 
 
+def test_pyramid_step_launches_the_kernel_once_a_level(one_chip_mesh,
+                                                        monkeypatch):
+    """The FPN step as ``make_train_step`` builds it by default (no
+    ``forward_fn``: the family dispatcher), exact top-k, a 128x192 canvas:
+    it compiles for one described chip, and its five per-level NMS are five
+    Mosaic calls named after the kernel, each (images, 1, that level's
+    candidates padded to 128): 256 of P2's 4608, P3's 1152 and P4's 288
+    anchors, all 72 of P5's and all 18 of P6's."""
+    from mx_rcnn_tpu.config import generate_config
+    from mx_rcnn_tpu.models.zoo import build_model
+    from mx_rcnn_tpu.train.step import abstract_step_inputs, make_train_step
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = generate_config("resnet50_fpn", "synthetic", **{
+        "network.proposal_topk": "exact",
+        "train.fpn_rpn_pre_nms_per_level": 256,
+        "train.rpn_post_nms_top_n": 64, "train.batch_rois": 32,
+        "train.max_gt_boxes": 8, "image.scales": ((128, 192),),
+        "image.pad_shape": (128, 192)})
+    model = build_model(cfg)
+    hlo = make_train_step(model, cfg, mesh=one_chip_mesh).lower(
+        *abstract_step_inputs(model, cfg, one_chip_mesh, 2)).compile(
+        ).as_text()
+    shapes = re.findall(
+        rf'%{nms_pallas.KERNEL_NAME}[\w.]* = f32\[(\d+),1,(\d+)\][^\n]*'
+        + KERNEL, hlo)
+    assert sorted(int(n) for _, n in shapes) == [128, 128, 256, 256, 256]
+    assert {int(b) for b, _ in shapes} == {2}
+    _assert_kernel_named_in_its_stage(hlo)
+    assert "all-reduce" not in hlo
+
+
 def _update_fusions(hlo):
     """(name, shapes put out, holds a convolution) of every fused
     computation with an instruction scoped in the ``update`` stage."""

@@ -359,6 +359,24 @@ def test_fpn_dp_parity(rng):
                                    atol=2e-5)
 
 
+def test_train_step_defaults_to_the_family_forward(rng):
+    """``make_train_step(model, cfg)`` with no ``forward_fn`` lowers the
+    pyramid step (the default is models/zoo.py's dispatcher, not the C4
+    forward), and its metrics carry the sampled rois' level shares."""
+    from mx_rcnn_tpu.parallel.mesh import create_mesh
+    from mx_rcnn_tpu.train.step import abstract_step_inputs, make_train_step
+
+    cfg = tiny_cfg()
+    model = zoo.build_model(cfg)
+    mesh = create_mesh("1")
+    step = make_train_step(model, cfg, mesh=mesh, donate=False)
+    args = abstract_step_inputs(model, cfg, mesh, 1)
+    assert "neck" in step.lower(*args).as_text()
+    _, metrics = jax.eval_shape(step, *args)
+    assert metrics["RoiLevelShare"].shape == (len(F.ROI_LEVELS),)
+    assert metrics["TotalLoss"].shape == ()
+
+
 def test_pack_placements_gaps_and_bounds():
     """Shelf packing: every rectangle in bounds, pairwise >=1px separated."""
     shapes = [(40, 64), (20, 32), (10, 16), (5, 8), (3, 4)]

@@ -710,6 +710,18 @@ def test_report_aggregates_synthetic_log():
     assert s2["throughput"]["img_s"] == pytest.approx(2 * 1000.0 / 20.0)
 
 
+def test_report_folds_the_roi_levels_event():
+    """A pyramid run's one ``roi_levels`` event reaches the summary and
+    the rendered report; a run without it (C4) says nothing."""
+    s = report.summarize(_synthetic_events())
+    assert s["roi_level_share"] is None and "roi levels" not in report.render(s)
+    share = [0.885, 0.0984, 0.0129, 0.0037]
+    s = report.summarize(_synthetic_events() + [
+        {"type": "roi_levels", "epoch": 0, "dispatch": 1, "share": share}])
+    assert s["roi_level_share"] == share
+    assert "roi levels: P2 88.5%, P3 9.8%, P4 1.3%, P5 0.4%" in report.render(s)
+
+
 def test_report_cli_roundtrip(tmp_path):
     log = open_event_log(str(tmp_path / "run"))
     log.emit("run_meta", batch_size=1)
