@@ -37,7 +37,7 @@ from mx_rcnn_tpu.ops.canvas import rois_by_plane
 from mx_rcnn_tpu.ops.proposal import generate_proposals
 from mx_rcnn_tpu.ops.roi_align import roi_align, roi_pool
 from mx_rcnn_tpu.targets.rcnn_targets import sample_rois
-from mx_rcnn_tpu.targets.rpn_targets import assign_anchor
+from mx_rcnn_tpu.targets.rpn_targets import assign_anchors
 from mx_rcnn_tpu.train.precision import island, model_dtype
 
 
@@ -197,23 +197,20 @@ def _backbone_rpn(model: FasterRCNN, params, images: jnp.ndarray, cfg: Config,
 
 def _assign_anchors_batch(anchors, gt_boxes, gt_valid, im_info, rng,
                           cfg: Config):
-    """vmapped assign_anchor over per-image rows (train-mode RPN
-    targets). Rows may be bucketed (im_info (B, 3)) or graftcanvas
-    packed ((B, 5) placement rows in canvas coordinates)."""
-    b = gt_boxes.shape[0]
+    """assign_anchors over per-image rows (train-mode RPN targets), one
+    key an image. Rows may be bucketed (im_info (B, 3)) or graftcanvas
+    packed ((B, 5) placement rows in canvas coordinates). Every family
+    that labels anchors comes through here (models/fpn.py too)."""
     with stage("rpn_targets"):
-        return jax.vmap(
-            partial(
-                assign_anchor,
-                rpn_batch_size=cfg.train.rpn_batch_size,
-                rpn_fg_fraction=cfg.train.rpn_fg_fraction,
-                positive_overlap=cfg.train.rpn_positive_overlap,
-                negative_overlap=cfg.train.rpn_negative_overlap,
-                allowed_border=cfg.train.rpn_allowed_border,
-                clobber_positives=cfg.train.rpn_clobber_positives,
-            ),
-            in_axes=(None, 0, 0, 0, 0),
-        )(anchors, gt_boxes, gt_valid, im_info, jax.random.split(rng, b))
+        return assign_anchors(
+            anchors, gt_boxes, gt_valid, im_info,
+            jax.random.split(rng, gt_boxes.shape[0]),
+            rpn_batch_size=cfg.train.rpn_batch_size,
+            rpn_fg_fraction=cfg.train.rpn_fg_fraction,
+            positive_overlap=cfg.train.rpn_positive_overlap,
+            negative_overlap=cfg.train.rpn_negative_overlap,
+            allowed_border=cfg.train.rpn_allowed_border,
+            clobber_positives=cfg.train.rpn_clobber_positives)
 
 
 def forward_train(
@@ -368,6 +365,8 @@ def forward_train(
         "rcnn_logits": cls_logits,
         "rcnn_labels": labels,
         "num_fg": jnp.sum(samples.fg_mask),
+        # gt slots walked / padded, kept positives / negatives
+        "rpn_target_counts": island(rpn_t.counts),
     }
     return total, aux
 

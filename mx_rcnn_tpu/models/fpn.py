@@ -37,6 +37,7 @@ from flax import linen as nn
 
 from mx_rcnn_tpu.config import Config
 from mx_rcnn_tpu.models.backbones import ResNetStages
+from mx_rcnn_tpu.models.faster_rcnn import _assign_anchors_batch
 from mx_rcnn_tpu.models.losses import rcnn_losses, rpn_losses
 from mx_rcnn_tpu.models.rpn import RPNHead
 from mx_rcnn_tpu.obs.profile import stage
@@ -47,7 +48,6 @@ from mx_rcnn_tpu.ops.nms import nms_dispatch
 from mx_rcnn_tpu.ops.proposal import _decode_one_image
 from mx_rcnn_tpu.ops.roi_align import roi_align
 from mx_rcnn_tpu.targets.rcnn_targets import sample_rois
-from mx_rcnn_tpu.targets.rpn_targets import assign_anchor
 from mx_rcnn_tpu.train.precision import island, model_dtype
 
 Dtype = Any
@@ -632,20 +632,8 @@ def forward_train(
         np.concatenate([anchors[lv] for lv in RPN_LEVELS], axis=0))
 
     k_anchor, k_sample, k_dummy = jax.random.split(rng, 3)
-    with stage("rpn_targets"):
-        rpn_t = jax.vmap(
-            partial(
-                assign_anchor,
-                rpn_batch_size=cfg.train.rpn_batch_size,
-                rpn_fg_fraction=cfg.train.rpn_fg_fraction,
-                positive_overlap=cfg.train.rpn_positive_overlap,
-                negative_overlap=cfg.train.rpn_negative_overlap,
-                allowed_border=cfg.train.rpn_allowed_border,
-                clobber_positives=cfg.train.rpn_clobber_positives,
-            ),
-            in_axes=(None, 0, 0, 0, 0),
-        )(anchors_cat, gt_boxes, gt_valid, im_info,
-          jax.random.split(k_anchor, b))
+    rpn_t = _assign_anchors_batch(anchors_cat, gt_boxes, gt_valid, im_info,
+                                  k_anchor, cfg)
 
     with stage("rpn_loss"):
         rpn_logits, rpn_deltas = _concat_level_outputs(rpn_out, a)
@@ -714,6 +702,8 @@ def forward_train(
         "rcnn_logits": cls_logits,
         "rcnn_labels": labels,
         "num_fg": jnp.sum(samples.fg_mask),
+        # gt slots walked / padded, kept positives / negatives
+        "rpn_target_counts": island(rpn_t.counts),
         # how many sampled rois Eq. 1 sends to each of ROI_LEVELS
         "roi_level_counts": island(jnp.sum(
             (roi_levels(samples.rois)[..., None] == jnp.asarray(ROI_LEVELS))

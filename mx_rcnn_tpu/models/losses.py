@@ -48,7 +48,13 @@ def softmax_ce_with_ignore(logits: jnp.ndarray, labels: jnp.ndarray) -> tuple:
     valid = labels >= 0
     safe = jnp.maximum(labels, 0)
     logp = jax.nn.log_softmax(island(logits), axis=-1)
-    ce = -jnp.take_along_axis(logp, safe[:, None], axis=-1)[:, 0]
+    # The label's log-probability by a dense select over the classes, not
+    # `take_along_axis`: that is a gather of one element an example, which
+    # the chip does an element at a time (28 ms over a pyramid's 2.2 M
+    # anchors a step; PERF.md section 6, PR 33). One term of the sum is not
+    # zero, so the value is the gathered one to the bit.
+    at_label = safe[:, None] == jnp.arange(logits.shape[-1])
+    ce = -jnp.sum(jnp.where(at_label, logp, 0.0), axis=-1)
     ce = jnp.where(valid, ce, 0.0)
     count = jnp.maximum(jnp.sum(island(valid)), 1.0)
     return jnp.sum(ce) / count, ce, valid

@@ -63,6 +63,11 @@ EVENT_TYPES = (
                    # run's dispatch it was taken at, the dispatches it was
                    # in flight, the loop's own ms in it, its bytes
                    # (resilience/heal.py Healer.poll_snapshot)
+    "rpn_targets", # once a run with obs.enabled: how far the first
+                   # dispatch's anchor labelling engaged — gt slots walked
+                   # (the loop's trip count: the most valid boxes of any
+                   # image) of the slots padded, kept positives and
+                   # negatives of the batch (tools/train.py)
     "roi_levels",  # pyramid families, once a run with obs.enabled: the
                    # share of the first dispatch's sampled rois that FPN
                    # Eq. 1 assigns to each pooled level (tools/train.py)

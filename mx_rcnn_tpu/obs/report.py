@@ -192,6 +192,7 @@ def summarize(events: List[Dict[str, Any]]) -> Dict[str, Any]:
     last_health = health_evs[-1] if health_evs else None
     snapshots = by_type.get("snapshot", ())
     roi_levels = (by_type.get("roi_levels") or [{}])[-1]
+    rpn_targets = (by_type.get("rpn_targets") or [None])[-1]
     summary: Dict[str, Any] = {
         "run": {k: run_meta.get(k) for k in
                 ("config_digest", "network", "dataset", "mesh",
@@ -291,6 +292,8 @@ def summarize(events: List[Dict[str, Any]]) -> Dict[str, Any]:
         # pyramid families: the first dispatch's sampled rois by the level
         # FPN Eq. 1 pools each from (P2..P5), None for the others
         "roi_level_share": roi_levels.get("share"),
+        "rpn_targets": rpn_targets and {k: rpn_targets.get(k) for k in (
+            "slots_walked", "slots_padded", "kept_pos", "kept_neg")},
         # graftquorum: multi-host coordination rounds — per-host records
         # interleaved by load_events, so `hosts` is how many distinct
         # process stamps the fold saw and `excluded` collects every host
@@ -465,6 +468,13 @@ def render(summary: Dict[str, Any]) -> str:
             f"  snapshots:  {he['snapshots']} installed, in flight <= "
             f"{he['snapshot_in_flight_max']} dispatch(es), <= "
             f"{he['snapshot_loop_ms_max']:.1f} ms of the loop each")
+    if summary.get("rpn_targets"):
+        rt = summary["rpn_targets"]
+        lines.append(
+            f"  rpn targets: walked {rt['slots_walked']} of "
+            f"{rt['slots_padded']} gt slots, kept {rt['kept_pos']} "
+            f"positives and {rt['kept_neg']} negatives at the first "
+            f"dispatch")
     if summary.get("roi_level_share"):
         lines.append("  roi levels: " + ", ".join(
             f"P{lv} {100 * s:.1f}%" for lv, s in

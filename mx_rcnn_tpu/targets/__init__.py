@@ -7,5 +7,5 @@ the graph as a Python CustomOp, serializing every training step through the
 host). Here both run inside the jitted train step.
 """
 
-from mx_rcnn_tpu.targets.rpn_targets import assign_anchor
+from mx_rcnn_tpu.targets.rpn_targets import assign_anchor, assign_anchors
 from mx_rcnn_tpu.targets.rcnn_targets import sample_rois

@@ -550,9 +550,10 @@ def fit_detector(
     # request to the quorum (at most one request per run — the agreed
     # boundary is cached by CoordinatedStop.check thereafter).
     stop_requested = False
-    # One `roi_levels` event a run (obs.enabled, pyramid families): read
-    # from the first dispatch's metrics, set-up's one wait for a dispatch.
-    roi_levels_due = obs_log.enabled
+    # One `rpn_targets` event a run and, for pyramid families, one
+    # `roi_levels` (obs.enabled): read from the first dispatch's metrics,
+    # set-up's one wait for a dispatch.
+    first_dispatch_due = obs_log.enabled
 
     def _ckpt_meta(at_epoch: int, at_dispatch: Optional[int],
                    hosts=None):
@@ -908,8 +909,22 @@ def fit_detector(
                             # ready-only drain): no host sync here
                             bag.update(metrics)
                             speedometer(epoch, i, bag)
-                        if roi_levels_due:
-                            roi_levels_due = False
+                        if first_dispatch_due:
+                            first_dispatch_due = False
+                            if "RpnTargetCounts" in metrics:
+                                walked, padded, kept_pos, kept_neg = (
+                                    round(float(c)) for c in
+                                    metrics["RpnTargetCounts"])
+                                obs_log.emit(
+                                    "rpn_targets", epoch=epoch,
+                                    dispatch=i + 1, slots_walked=walked,
+                                    slots_padded=padded, kept_pos=kept_pos,
+                                    kept_neg=kept_neg)
+                                logger.info(
+                                    "anchor labelling at dispatch %d: "
+                                    "walked %d of %d gt slots, kept %d "
+                                    "positives and %d negatives", i + 1,
+                                    walked, padded, kept_pos, kept_neg)
                             if "RoiLevelShare" in metrics:
                                 share = [round(float(s), 4) for s in
                                          metrics["RoiLevelShare"]]

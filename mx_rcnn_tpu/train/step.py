@@ -115,6 +115,8 @@ def _metric_parts(aux: Dict[str, jnp.ndarray]) -> Dict[str, tuple]:
         rcnn_correct = (rcnn_pred == aux["rcnn_labels"]) & rcnn_valid
         out["RCNNAcc"] = (jnp.sum(rcnn_correct).astype(jnp.float32),
                           jnp.sum(rcnn_valid).astype(jnp.float32))
+    if "rpn_target_counts" in aux:  # targets/rpn_targets.py::RpnTargets
+        out["RpnTargetCounts"] = (aux["rpn_target_counts"], one)
     if "roi_level_counts" in aux:  # pyramid families: a vector, P2..P5
         counts = aux["roi_level_counts"]
         out["RoiLevelShare"] = (counts, jnp.sum(counts))

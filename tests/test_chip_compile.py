@@ -210,19 +210,15 @@ def test_one_chip_train_step_names_the_kernel(one_chip_mesh, monkeypatch):
     _assert_kernel_named_in_its_stage(hlo)
 
 
-def test_pyramid_step_launches_the_kernel_once_a_level(one_chip_mesh,
-                                                        monkeypatch):
+@functools.lru_cache(maxsize=None)
+def _tiny_pyramid_step_hlo(mesh):
     """The FPN step as ``make_train_step`` builds it by default (no
-    ``forward_fn``: the family dispatcher), exact top-k, a 128x192 canvas:
-    it compiles for one described chip, and its five per-level NMS are five
-    Mosaic calls named after the kernel, each (images, 1, that level's
-    candidates padded to 128): 256 of P2's 4608, P3's 1152 and P4's 288
-    anchors, all 72 of P5's and all 18 of P6's."""
+    ``forward_fn``: the family dispatcher), exact top-k, two images on a
+    128x192 canvas. Call it with ``jax.default_backend`` patched to "tpu"."""
     from mx_rcnn_tpu.config import generate_config
     from mx_rcnn_tpu.models.zoo import build_model
     from mx_rcnn_tpu.train.step import abstract_step_inputs, make_train_step
 
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = generate_config("resnet50_fpn", "synthetic", **{
         "network.proposal_topk": "exact",
         "train.fpn_rpn_pre_nms_per_level": 256,
@@ -230,9 +226,18 @@ def test_pyramid_step_launches_the_kernel_once_a_level(one_chip_mesh,
         "train.max_gt_boxes": 8, "image.scales": ((128, 192),),
         "image.pad_shape": (128, 192)})
     model = build_model(cfg)
-    hlo = make_train_step(model, cfg, mesh=one_chip_mesh).lower(
-        *abstract_step_inputs(model, cfg, one_chip_mesh, 2)).compile(
-        ).as_text()
+    return make_train_step(model, cfg, mesh=mesh).lower(
+        *abstract_step_inputs(model, cfg, mesh, 2)).compile().as_text()
+
+
+def test_pyramid_step_launches_the_kernel_once_a_level(one_chip_mesh,
+                                                        monkeypatch):
+    """The pyramid step compiles for one described chip, and its five
+    per-level NMS are five Mosaic calls named after the kernel, each
+    (images, 1, that level's candidates padded to 128): 256 of P2's 4608,
+    P3's 1152 and P4's 288 anchors, all 72 of P5's and all 18 of P6's."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    hlo = _tiny_pyramid_step_hlo(one_chip_mesh)
     shapes = re.findall(
         rf'%{nms_pallas.KERNEL_NAME}[\w.]* = f32\[(\d+),1,(\d+)\][^\n]*'
         + KERNEL, hlo)
@@ -240,6 +245,77 @@ def test_pyramid_step_launches_the_kernel_once_a_level(one_chip_mesh,
     assert {int(b) for b, _ in shapes} == {2}
     _assert_kernel_named_in_its_stage(hlo)
     assert "all-reduce" not in hlo
+
+
+def _moved_over_every_anchor(hlo, stage_name, images, anchors):
+    """The gathers and scatters scoped in ``stage_name`` that move a row an
+    anchor: a gather's result, a scatter's updates, with the per-image or
+    the flat anchor count among their dimensions. (A scatter of the 128
+    kept positives' targets INTO a zero (anchors, 4) moves 128 rows.) The
+    compiler leaves some of them without an ``op_name``: those count for
+    the stages their fused computation's other instructions name."""
+    from mx_rcnn_tpu.obs.profile import stage_of
+
+    dims_of = {name: [int(d) for d in dims.split(",") if d]
+               for name, dims in re.findall(
+                   r"^\s*(?:ROOT )?(%[\w.-]+) = \w+\[([\d,]*)\]", hlo, re.M)}
+    found = []
+    for body in re.findall(r"^%[\w.-]+ [^\n]*\{\n(.*?)^\}", hlo, re.S | re.M):
+        around = {stage_of(p) for p in re.findall(r'op_name="([^"]*)"', body)}
+        for name, opcode, operands, rest in re.findall(
+                r"^\s*(?:ROOT )?(%[\w.-]+) = \S+ (gather|scatter)\(([^)]*)\)"
+                r"([^\n]*)", body, re.M):
+            own = re.search(r'op_name="([^"]*)"', rest)
+            if stage_name not in ({stage_of(own.group(1))} if own else around):
+                continue
+            moved = (dims_of[name] if opcode == "gather" else
+                     dims_of[re.findall(r"%[\w.-]+", operands)[-1]])
+            if {anchors, images * anchors} & set(moved):
+                found.append((name, opcode, moved))
+    return found
+
+
+@pytest.mark.parametrize("family", ["c4", "pyramid"])
+def test_anchor_labelling_moves_no_row_an_anchor(one_chip_mesh, monkeypatch,
+                                                 family):
+    """``targets/rpn_targets.py`` as the chip's compiler leaves it, in the
+    tiny C4 step (8x8 cells x 9 = 576 anchors an image) and the pyramid step
+    (6138 over P2-P6): the overlaps are walked a gt slot at a time by a
+    ``while`` scoped in the stage, and no gather or scatter there moves a
+    row for every anchor — the ranking's inverse permutation and the
+    matched box of every anchor (13 ms each at 279,279 anchors x 8 images,
+    PERF.md section 6, PR 33) are gone. ``rpn_loss`` picks the label's
+    log-probability by a dense select, no gather either."""
+    from mx_rcnn_tpu.obs.profile import stage_of
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    hlo, anchors = ((_tiny_step_hlo(one_chip_mesh, 2), 8 * 8 * 9)
+                    if family == "c4" else
+                    (_tiny_pyramid_step_hlo(one_chip_mesh),
+                     3 * (32 * 48 + 16 * 24 + 8 * 12 + 4 * 6 + 2 * 3)))
+    for stage_name in ("rpn_targets", "rpn_loss"):
+        assert not _moved_over_every_anchor(hlo, stage_name, 2, anchors)
+    loops = [path for path in re.findall(
+        r'^\s*%[\w.-]+ = [^\n]*? while\([^\n]*op_name="([^"]*)"', hlo, re.M)
+        if stage_of(path) == "rpn_targets"]
+    assert loops, "no while loop scoped in rpn_targets"
+
+
+def test_labelling_over_a_data_mesh_exchanges_one_scalar(data_mesh,
+                                                         monkeypatch):
+    """Four images on four devices: the labelling's loop runs to the most
+    valid boxes of ANY image of the step, so the devices agree on its trip
+    count by one all-reduce of a scalar; nothing else of the stage crosses
+    devices (each labels its own images' anchors)."""
+    from mx_rcnn_tpu.obs.profile import stage_of
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    crossed = [(shape, opcode) for shape, opcode, path in re.findall(
+        r'^\s*(?:ROOT )?%[\w.-]+ = (\S+) (all-gather|all-reduce|all-to-all|'
+        r'collective-permute)[\w-]*\([^\n]*op_name="([^"]*)"',
+        _tiny_step_hlo(data_mesh, 4), re.M) if stage_of(path) == "rpn_targets"]
+    assert len(crossed) == 1, crossed
+    assert crossed[0][0].startswith("s32[]") and crossed[0][1] == "all-reduce"
 
 
 def _update_fusions(hlo):

@@ -722,6 +722,20 @@ def test_report_folds_the_roi_levels_event():
     assert "roi levels: P2 88.5%, P3 9.8%, P4 1.3%, P5 0.4%" in report.render(s)
 
 
+def test_report_folds_the_rpn_targets_event():
+    """The run's one ``rpn_targets`` event reaches the summary and the
+    rendered report; a log without it says nothing."""
+    s = report.summarize(_synthetic_events())
+    assert s["rpn_targets"] is None and "rpn targets" not in report.render(s)
+    s = report.summarize(_synthetic_events() + [
+        {"type": "rpn_targets", "epoch": 0, "dispatch": 1, "slots_walked": 5,
+         "slots_padded": 100, "kept_pos": 212, "kept_neg": 1836}])
+    assert s["rpn_targets"] == {"slots_walked": 5, "slots_padded": 100,
+                                "kept_pos": 212, "kept_neg": 1836}
+    assert ("rpn targets: walked 5 of 100 gt slots, kept 212 positives and "
+            "1836 negatives") in report.render(s)
+
+
 def test_report_cli_roundtrip(tmp_path):
     log = open_event_log(str(tmp_path / "run"))
     log.emit("run_meta", batch_size=1)
@@ -808,6 +822,15 @@ def test_fit_detector_obs_enabled_and_report(tmp_path):
     trace = next(e for e in events if e["type"] == "trace")
     assert trace["reason"] == "step 2"
     assert trace["summary"] is None or trace["summary"]["events"] > 0
+
+    # ONE rpn_targets event a run, read at the first dispatch: the loop
+    # over gt slots walked the image's boxes (1-2 here), not the 8 padded
+    labelled = [e for e in events if e["type"] == "rpn_targets"]
+    assert len(labelled) == 1 and labelled[0]["dispatch"] == 1
+    assert 1 <= labelled[0]["slots_walked"] <= 2
+    assert labelled[0]["slots_padded"] == 8
+    assert 1 <= labelled[0]["kept_pos"] <= 128
+    assert labelled[0]["kept_pos"] + labelled[0]["kept_neg"] <= 256
 
     meta = next(e for e in events if e["type"] == "run_meta")
     assert meta["batch_size"] == 1 and meta["steps_per_epoch"] == 4
