@@ -47,7 +47,7 @@ from mx_rcnn_tpu.ops.canvas import rois_by_plane
 from mx_rcnn_tpu.ops.nms import nms_dispatch
 from mx_rcnn_tpu.ops.proposal import _decode_one_image
 from mx_rcnn_tpu.ops.roi_align import roi_align
-from mx_rcnn_tpu.targets.rcnn_targets import sample_rois
+from mx_rcnn_tpu.targets.rcnn_targets import fg_rois_per_image, sample_rois
 from mx_rcnn_tpu.train.precision import island, model_dtype
 
 Dtype = Any
@@ -585,6 +585,72 @@ def _concat_level_outputs(rpn_out, num_anchors: int):
             jnp.concatenate(deltas_all, axis=1))
 
 
+def _level_counts(rois, valid):
+    """(B, R, 4) rois, (B, R) which of them count -> how many Eq. 1 sends
+    to each of ROI_LEVELS, float32."""
+    return island(jnp.sum(
+        (roi_levels(rois)[..., None] == jnp.asarray(ROI_LEVELS))
+        & valid[..., None], axis=(0, 1)))
+
+
+def mask_branch(model, params, pyramid, samples, gt_boxes, gt_masks,
+                windows, cfg: Config):
+    """The mask loss (He et al. 2017, section 3) over the FOREGROUND block
+    of the sampled rois, and the block's counts.
+
+    ``sample_rois`` lays an image's foreground out as a prefix of its slots
+    (``is_fg_slot = slots < n_fg``, ``n_fg <= round(fg_fraction *
+    batch_rois)``), so the rois the recipe gives the branch are a static
+    slice: pooling, head, targets and loss run over that block (128 of 512
+    slots at the published sizes) and no slot outside it could have
+    entered the loss. Over all slots the 14x14 pooling alone does not fit
+    a v5e at 8 images (PERF.md section 6, PR 34).
+
+    Returns ``(loss, counts)``: the mean per-pixel sigmoid cross-entropy
+    of each live roi's ground-truth class's map, averaged over the batch's
+    live rois; ``counts`` (7,) = live rois an image (min, mean, max) and
+    how many of them Eq. 1 sends to each of ``ROI_LEVELS``.
+    """
+    from mx_rcnn_tpu.targets.mask_targets import mask_targets_for_rois
+
+    b = samples.rois.shape[0]
+    n = fg_rois_per_image(cfg.train.batch_rois, cfg.train.fg_fraction)
+    rois = samples.rois[:, :n]
+    live = (samples.valid & samples.fg_mask)[:, :n]
+    with stage("mask_align"):
+        pooled = pyramid_roi_align(pyramid, rois, live, model.mask_pool_size,
+                                   windows=windows)
+    with stage("mask_head"):
+        logits = model.apply(params, pooled, method="mask_forward")
+    m_res = logits.shape[1]
+    with stage("mask_targets"):
+        # gt_masks are BOX-frame, so the canvas shift cancels: rois and
+        # gt boxes are both canvas-coordinate on a packed batch.
+        targets = jax.vmap(
+            partial(mask_targets_for_rois, resolution=m_res)
+        )(rois, samples.matched_gt[:, :n], gt_boxes, gt_masks)  # (B, n, m, m)
+        targets = targets.reshape(b * n, m_res, m_res)
+    with stage("mask_loss"):
+        # The class's map by a dense select over the classes, not
+        # `take_along_axis` (a gather of one element a cell, and a scatter
+        # going back: an element at a time on the chip, as
+        # models/losses.py::softmax_ce_with_ignore found). One term of the
+        # sum is not zero, so the value is the gathered one to the bit.
+        cls = samples.labels[:, :n].reshape(-1)  # a live roi's is > 0
+        at_cls = cls[:, None] == jnp.arange(logits.shape[-1])
+        per_roi = jnp.sum(
+            jnp.where(at_cls[:, None, None, :], logits, 0.0), axis=-1)
+        bce = optax_sigmoid_bce(per_roi, targets)
+        fg = island(live.reshape(-1))
+        loss = (jnp.sum(jnp.mean(bce, axis=(1, 2)) * fg)
+                / jnp.maximum(jnp.sum(fg), 1.0))
+    an_image = jnp.sum(island(live), axis=1)
+    counts = jnp.concatenate([
+        jnp.stack([jnp.min(an_image), jnp.mean(an_image),
+                   jnp.max(an_image)]), _level_counts(rois, live)])
+    return loss, counts
+
+
 def forward_train(
     model: FPNFasterRCNN,
     params,
@@ -704,36 +770,13 @@ def forward_train(
         "num_fg": jnp.sum(samples.fg_mask),
         # gt slots walked / padded, kept positives / negatives
         "rpn_target_counts": island(rpn_t.counts),
-        # how many sampled rois Eq. 1 sends to each of ROI_LEVELS
-        "roi_level_counts": island(jnp.sum(
-            (roi_levels(samples.rois)[..., None] == jnp.asarray(ROI_LEVELS))
-            & samples.valid[..., None], axis=(0, 1))),
+        "roi_level_counts": _level_counts(samples.rois, samples.valid),
     }
 
     if model.use_mask:
-        from mx_rcnn_tpu.targets.mask_targets import mask_targets_for_rois
-
-        mask_pooled = pyramid_roi_align(
-            pyramid, samples.rois, samples.valid & samples.fg_mask,
-            model.mask_pool_size, windows=windows)
-        mask_logits = model.apply(params, mask_pooled,
-                                  method="mask_forward")
-        m_res = mask_logits.shape[1]
-        # gt_masks are BOX-frame, so the canvas shift cancels: rois and
-        # gt boxes are both canvas-coordinate on a packed batch.
-        targets = jax.vmap(
-            partial(mask_targets_for_rois, resolution=m_res)
-        )(samples.rois, samples.matched_gt, gt_boxes,
-          gt_masks)  # (B, R, m, m)
-        targets = targets.reshape(b * r, m_res, m_res)
-        fg = (samples.fg_mask & samples.valid).reshape(-1)
-        cls_sel = jnp.maximum(labels, 0)
-        per_roi = jnp.take_along_axis(
-            mask_logits, cls_sel[:, None, None, None], axis=-1)[..., 0]
-        bce = optax_sigmoid_bce(per_roi, targets)
-        denom = jnp.maximum(jnp.sum(island(fg)), 1.0)
-        mask_loss = jnp.sum(
-            jnp.mean(bce, axis=(1, 2)) * island(fg)) / denom
+        mask_loss, aux["mask_roi_counts"] = mask_branch(
+            model, params, pyramid, samples, gt_boxes, gt_masks, windows,
+            cfg)
         total = total + mask_loss
         aux["mask_loss"] = mask_loss
 

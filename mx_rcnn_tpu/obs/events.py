@@ -71,6 +71,10 @@ EVENT_TYPES = (
     "roi_levels",  # pyramid families, once a run with obs.enabled: the
                    # share of the first dispatch's sampled rois that FPN
                    # Eq. 1 assigns to each pooled level (tools/train.py)
+    "mask_rois",   # the mask branch, once a run with obs.enabled: the live
+                   # foreground rois of the first dispatch's `slots` branch
+                   # slots an image (min / mean / max over the images) and
+                   # their share on each pooled level (tools/train.py)
     "cost",        # graftprof: XLA cost/memory accounting for one
                    # compiled shape bucket (flops, hbm split — obs/costs.py)
     "trace",       # graftprof: one closed jax.profiler capture window

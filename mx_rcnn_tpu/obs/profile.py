@@ -50,12 +50,25 @@ STAGES = ("backbone", "neck", "rpn_head", "rpn_targets", "rpn_loss",
 # transforms: "FasterRCNN.box_head" and "dynamic_update_slice" are neither
 _STAGE_RX = re.compile(r"(?<![\w.\-])(" + "|".join(STAGES) + r")(?![\w.\-])")
 
+#: The mask branch's scopes (Mask R-CNN, ViTDet's mask preset), in program
+#: order: a second closed list beside ``STAGES`` and not part of it, so
+#: that ``stage_of`` and every reader of ``STAGES`` see the branch as
+#: before it had names - its pooling as ``roi_align`` (the scope
+#: ``pyramid_roi_align`` opens inside ``mask_align``), the rest unscoped.
+#: ``branch_of`` reads them.
+BRANCH_STAGES = ("mask_align", "mask_head", "mask_targets", "mask_loss")
+
+_BRANCH_RX = re.compile(
+    r"(?<![\w.\-])(" + "|".join(BRANCH_STAGES) + r")(?![\w.\-])")
+
+
 def stage(name: str):
-    """``jax.named_scope`` for one of ``STAGES``: trace-time metadata on
-    the ops traced under it, forward and (through ``jvp``/``transpose``)
-    backward. Changes no instruction."""
-    if name not in STAGES:
-        raise ValueError(f"{name!r} is not one of STAGES {STAGES}")
+    """``jax.named_scope`` for one of ``STAGES`` or ``BRANCH_STAGES``:
+    trace-time metadata on the ops traced under it, forward and (through
+    ``jvp``/``transpose``) backward. Changes no instruction."""
+    if name not in STAGES and name not in BRANCH_STAGES:
+        raise ValueError(f"{name!r} is not one of STAGES {STAGES} or "
+                         f"BRANCH_STAGES {BRANCH_STAGES}")
     import jax
 
     return jax.named_scope(name)
@@ -64,6 +77,13 @@ def stage(name: str):
 def stage_of(path: str) -> Optional[str]:
     """The innermost stage in an op's scope path, or None."""
     hits = _STAGE_RX.findall(path)
+    return hits[-1] if hits else None
+
+
+def branch_of(path: str) -> Optional[str]:
+    """The innermost of ``BRANCH_STAGES`` in an op's scope path, or None:
+    asked beside ``stage_of``, not in its place."""
+    hits = _BRANCH_RX.findall(path)
     return hits[-1] if hits else None
 
 

@@ -193,6 +193,7 @@ def summarize(events: List[Dict[str, Any]]) -> Dict[str, Any]:
     snapshots = by_type.get("snapshot", ())
     roi_levels = (by_type.get("roi_levels") or [{}])[-1]
     rpn_targets = (by_type.get("rpn_targets") or [None])[-1]
+    mask_rois = (by_type.get("mask_rois") or [None])[-1]
     summary: Dict[str, Any] = {
         "run": {k: run_meta.get(k) for k in
                 ("config_digest", "network", "dataset", "mesh",
@@ -294,6 +295,10 @@ def summarize(events: List[Dict[str, Any]]) -> Dict[str, Any]:
         "roi_level_share": roi_levels.get("share"),
         "rpn_targets": rpn_targets and {k: rpn_targets.get(k) for k in (
             "slots_walked", "slots_padded", "kept_pos", "kept_neg")},
+        # the mask branch: the first dispatch's live rois of its slots
+        "mask_rois": mask_rois and {k: mask_rois.get(k) for k in (
+            "slots", "per_image_min", "per_image_mean", "per_image_max",
+            "share")},
         # graftquorum: multi-host coordination rounds — per-host records
         # interleaved by load_events, so `hosts` is how many distinct
         # process stamps the fold saw and `excluded` collects every host
@@ -480,6 +485,14 @@ def render(summary: Dict[str, Any]) -> str:
             f"P{lv} {100 * s:.1f}%" for lv, s in
             enumerate(summary["roi_level_share"], start=2))
             + " of the first dispatch's sampled rois")
+    if summary.get("mask_rois"):
+        mr = summary["mask_rois"]
+        lines.append(
+            f"  mask rois:  {mr['per_image_min']} / {mr['per_image_mean']} / "
+            f"{mr['per_image_max']} (min / mean / max an image) of "
+            f"{mr['slots']} branch slots live at the first dispatch; "
+            + ", ".join(f"P{lv} {100 * s:.1f}%" for lv, s in
+                        enumerate(mr["share"], start=2)))
     da = summary.get("data", {})
     if (da.get("quarantined") or da.get("retries")
             or da.get("worker_deaths") or da.get("cap_trips")):

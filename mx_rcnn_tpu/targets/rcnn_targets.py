@@ -50,6 +50,13 @@ def _ranked_candidates(mask: jnp.ndarray, key) -> tuple:
     return order, count
 
 
+def fg_rois_per_image(batch_rois: int, fg_fraction: float) -> int:
+    """How many of an image's sampled slots may hold foreground rois: the
+    leading block of ``sample_rois``' slots, and the block the mask branch
+    runs over (models/fpn.py::mask_branch)."""
+    return round(fg_fraction * batch_rois)
+
+
 def sample_rois(
     rois: jnp.ndarray,
     roi_valid: jnp.ndarray,
@@ -88,7 +95,7 @@ def sample_rois(
     fg_cand = cand_valid & (max_iou >= fg_thresh)
     bg_cand = cand_valid & (max_iou < bg_thresh_hi) & (max_iou >= bg_thresh_lo)
 
-    fg_per_image = int(round(fg_fraction * batch_rois))
+    fg_per_image = fg_rois_per_image(batch_rois, fg_fraction)
     fg_order, fg_count = _ranked_candidates(fg_cand, k_fg)
     bg_order, bg_count = _ranked_candidates(bg_cand, k_bg)
     n_fg = jnp.minimum(fg_count, fg_per_image)

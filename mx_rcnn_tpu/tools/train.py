@@ -50,6 +50,7 @@ from mx_rcnn_tpu.resilience import (
 )
 from mx_rcnn_tpu.resilience import chaos
 from mx_rcnn_tpu.resilience import quorum as quorum_lib
+from mx_rcnn_tpu.targets.rcnn_targets import fg_rois_per_image
 from mx_rcnn_tpu.train.callback import Speedometer
 from mx_rcnn_tpu.train.checkpoint import (
     checkpoint_meta,
@@ -550,9 +551,9 @@ def fit_detector(
     # request to the quorum (at most one request per run — the agreed
     # boundary is cached by CoordinatedStop.check thereafter).
     stop_requested = False
-    # One `rpn_targets` event a run and, for pyramid families, one
-    # `roi_levels` (obs.enabled): read from the first dispatch's metrics,
-    # set-up's one wait for a dispatch.
+    # One `rpn_targets` event a run, for pyramid families one `roi_levels`
+    # and with the mask branch one `mask_rois` (obs.enabled): read from the
+    # first dispatch's metrics, set-up's one wait for a dispatch.
     first_dispatch_due = obs_log.enabled
 
     def _ckpt_meta(at_epoch: int, at_dispatch: Optional[int],
@@ -934,6 +935,24 @@ def fit_detector(
                                     "sampled rois by pyramid level "
                                     "(P2..P5) at dispatch %d: %s", i + 1,
                                     share)
+                            if "MaskRoiCounts" in metrics:
+                                counts = [float(c) for c in
+                                          metrics["MaskRoiCounts"]]
+                                live = max(sum(counts[3:]), 1.0)
+                                mask_rois = dict(
+                                    slots=fg_rois_per_image(
+                                        cfg.train.batch_rois,
+                                        cfg.train.fg_fraction),
+                                    per_image_min=round(counts[0]),
+                                    per_image_mean=round(counts[1], 2),
+                                    per_image_max=round(counts[2]),
+                                    share=[round(c / live, 4)
+                                           for c in counts[3:]])
+                                obs_log.emit("mask_rois", epoch=epoch,
+                                             dispatch=i + 1, **mask_rois)
+                                logger.info(
+                                    "mask branch at dispatch %d: %s",
+                                    i + 1, mask_rois)
                         if tracer is not None:
                             # timer.total_steps increments when the
                             # generator resumes — this dispatch is the

@@ -30,7 +30,11 @@ from mx_rcnn_tpu.config import Config
 
 
 def trainable_mask(params, patterns: Sequence[str]):
-    """True for trainable leaves; False where any pattern is a path substring.
+    """True for trainable leaves; False where any pattern is the PREFIX of
+    a segment of the leaf's path (the reference's ``fixed_param_prefix``,
+    a module at a time: ``conv0`` fixes ``features/conv0/kernel`` and not
+    the mask head's ``mask_conv0`` - as a bare substring it did, and the
+    Mask presets never trained that layer; PERF.md section 6, PR 34).
 
     Frozen-BN params (gamma/beta/moving_*) are always frozen in this
     framework (reference: use_global_stats + fixed gamma/beta).
@@ -46,7 +50,8 @@ def trainable_mask(params, patterns: Sequence[str]):
         leaf = keys[-1] if keys else ""
         if leaf in ("gamma", "beta"):
             return False
-        return not any(pat in joined for pat in patterns)
+        return not any(str(k).startswith(pat) for k in keys
+                       for pat in patterns)
 
     return jax.tree_util.tree_map_with_path(lambda p, _: decide(p), params)
 

@@ -80,6 +80,10 @@ def abstract_step_inputs(model, cfg: Config, mesh: Mesh, n_images: int):
         "gt_boxes": jax.ShapeDtypeStruct((n_images, g, 4), jnp.float32),
         "gt_classes": jax.ShapeDtypeStruct((n_images, g), jnp.int32),
         "gt_valid": jax.ShapeDtypeStruct((n_images, g), jnp.bool_)}
+    if cfg.network.use_mask:  # box-frame masks, as data/loader.py serves them
+        m = cfg.train.mask_gt_resolution
+        batch["gt_masks"] = jax.ShapeDtypeStruct((n_images, g, m, m),
+                                                 jnp.uint8)
     return (spec(jax.eval_shape(fresh_state, jax.random.PRNGKey(0)), repl),
             spec(batch, NamedSharding(mesh, P("data"))),
             spec(jax.eval_shape(lambda: jax.random.PRNGKey(0)), repl))
@@ -120,6 +124,8 @@ def _metric_parts(aux: Dict[str, jnp.ndarray]) -> Dict[str, tuple]:
     if "roi_level_counts" in aux:  # pyramid families: a vector, P2..P5
         counts = aux["roi_level_counts"]
         out["RoiLevelShare"] = (counts, jnp.sum(counts))
+    if "mask_roi_counts" in aux:  # models/fpn.py::mask_branch
+        out["MaskRoiCounts"] = (aux["mask_roi_counts"], one)
     return out
 
 

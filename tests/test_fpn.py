@@ -303,6 +303,99 @@ def test_mask_forward_train(rng):
     assert float(aux["mask_loss"]) > 0
 
 
+def _mask_loss_over_every_slot(model, params, pyramid, samples, gt_boxes,
+                               gt_masks):
+    """The branch as it stood before PR 34, kept here only: pooling, head,
+    targets and loss over ALL sampled slots, the loss masked by
+    ``fg_mask``, the class's map by a gather."""
+    b, r = samples.rois.shape[:2]
+    live = samples.valid & samples.fg_mask
+    pooled = F.pyramid_roi_align(pyramid, samples.rois, live,
+                                 model.mask_pool_size)
+    logits = model.apply(params, pooled, method="mask_forward")
+    m = logits.shape[1]
+    targets = jax.vmap(
+        lambda ro, g, gb, gm: mask_targets_for_rois(ro, g, gb, gm,
+                                                    resolution=m)
+    )(samples.rois, samples.matched_gt, gt_boxes, gt_masks)
+    labels = jnp.where(samples.valid, samples.labels, -1).reshape(-1)
+    per_roi = jnp.take_along_axis(
+        logits, jnp.maximum(labels, 0)[:, None, None, None], axis=-1)[..., 0]
+    bce = F.optax_sigmoid_bce(per_roi, targets.reshape(b * r, m, m))
+    fg = live.reshape(-1).astype(jnp.float32)
+    return (jnp.sum(jnp.mean(bce, axis=(1, 2)) * fg)
+            / jnp.maximum(jnp.sum(fg), 1.0))
+
+
+@pytest.fixture(scope="module")
+def mask_branch_pair():
+    """Both forms jitted once at the published slot counts (512 slots an
+    image, 128 of them the branch's), on a small seeded pyramid, float32."""
+    from mx_rcnn_tpu.targets.rcnn_targets import RoiSamples
+
+    cfg = tiny_cfg(mask=True, **{"train.batch_rois": 512,
+                                 "train.compute_dtype": "f32"})
+    model = zoo.build_model(cfg)
+    params = zoo.init_params(model, cfg, jax.random.PRNGKey(0))
+    rs = np.random.RandomState(7)
+    pyramid = {lv: jnp.asarray(rs.randn(1, 128 >> lv, 128 >> lv, 256)
+                               .astype(np.float32)) for lv in F.ROI_LEVELS}
+    tl = rs.uniform(0, 90, (1, 512, 2))
+    # sides of 4-120 px: Eq. 1 sends these to P2 and P3
+    rois = jnp.asarray(np.concatenate(
+        [tl, np.minimum(tl + rs.uniform(4, 120, (1, 512, 2)), 127)],
+        axis=-1).astype(np.float32))
+    batch = tiny_batch(rs, mask=True)
+    classes = jnp.asarray(rs.randint(1, model.num_classes, (1, 512)))
+    matched = jnp.asarray(rs.randint(0, 2, (1, 512)).astype(np.int32))
+
+    def samples_of(n_fg, valid):
+        fg = (jnp.arange(512) < n_fg)[None] & valid
+        return RoiSamples(rois=rois, labels=jnp.where(fg, classes, 0),
+                          bbox_targets=None, bbox_weights=None, valid=valid,
+                          fg_mask=fg, matched_gt=matched)
+
+    def both(n_fg, valid):
+        s = samples_of(n_fg, valid)
+        gb, gm = jnp.asarray(batch["gt_boxes"]), jnp.asarray(batch["gt_masks"])
+
+        def prefix(p2):
+            return F.mask_branch(model, params, {**pyramid, 2: p2}, s,
+                                 gb, gm, None, cfg)
+
+        def every(p2):
+            return _mask_loss_over_every_slot(
+                model, params, {**pyramid, 2: p2}, s, gb, gm)
+
+        (lp, counts), gp = jax.value_and_grad(prefix, has_aux=True)(pyramid[2])
+        le, ge = jax.value_and_grad(every)(pyramid[2])
+        return lp, gp, counts, le, ge
+
+    return jax.jit(both)
+
+
+@pytest.mark.parametrize("n_fg", [0, 1, 128])
+def test_mask_loss_over_the_foreground_prefix_equals_every_slot(
+        n_fg, mask_branch_pair):
+    """The sampler lays the foreground out as a prefix of the slots, so the
+    branch over the first round(fg_fraction * batch_rois) = 128 of 512 slots
+    gives the loss (and the gradient into P2) of the branch over every slot
+    with the loss masked: to float32 round-off (a sum of 128 terms against
+    one of 512, 384 of them zeros; the dense select against the gather is
+    exact). One slot inside the prefix is invalid: both forms leave it
+    out."""
+    valid = jnp.ones((1, 512), bool).at[0, 0].set(n_fg < 128)
+    lp, gp, counts, le, ge = mask_branch_pair(n_fg, valid)
+    live = n_fg if n_fg < 128 else 127
+    assert float(le) > 0 or live == 0
+    np.testing.assert_allclose(float(lp), float(le), rtol=1e-6, atol=0)
+    np.testing.assert_allclose(np.asarray(gp), np.asarray(ge), rtol=1e-5,
+                               atol=1e-7 * float(np.abs(ge).max() + 1e-30))
+    counts = np.asarray(counts)
+    np.testing.assert_array_equal(counts[:3], [live] * 3)  # one image
+    assert counts[3:].sum() == live and (live == 0 or counts[3] > 0)
+
+
 def test_mask_inference_contract(rng):
     cfg = tiny_cfg(mask=True)
     model = zoo.build_model(cfg)

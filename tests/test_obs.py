@@ -518,8 +518,39 @@ def test_stage_of_reads_the_innermost_stage_of_a_scope_path():
         stage("backward")
 
 
+def test_branch_of_reads_the_mask_branchs_scopes_beside_the_stages():
+    """``BRANCH_STAGES`` is a second closed list: ``stage`` opens its
+    scopes, ``branch_of`` reads them, and ``stage_of`` sees a path that
+    holds one exactly as it did before the branch had names - the pooling
+    as ``roi_align`` (``pyramid_roi_align``'s scope inside ``mask_align``),
+    the rest as no stage."""
+    from mx_rcnn_tpu.obs.profile import (BRANCH_STAGES, STAGES, branch_of,
+                                         stage, stage_of)
+
+    assert BRANCH_STAGES == ("mask_align", "mask_head", "mask_targets",
+                             "mask_loss")
+    assert not set(BRANCH_STAGES) & set(STAGES)
+    pooled = "jit(step)/transpose(jvp(mask_align))/roi_align/dot_general"
+    assert (branch_of(pooled), stage_of(pooled)) == ("mask_align",
+                                                     "roi_align")
+    head = ("jit(step)/jvp(mask_head)/FPNFasterRCNN.mask_forward/mask_head/"
+            "mask_conv0/conv_general_dilated")
+    assert (branch_of(head), stage_of(head)) == ("mask_head", None)
+    assert branch_of("jit(step)/jvp(mask_loss)/log1p") == "mask_loss"
+    assert branch_of("jit(step)/mask_targets/vmap(dot_general)") == \
+        "mask_targets"
+    # a whole segment, as a stage is
+    assert branch_of("jit(step)/jvp(box_head)/unmask_head2/dot") is None
+    assert branch_of("jit(step)/jvp(roi_align)/dot_general") is None
+    for name in BRANCH_STAGES:
+        stage(name)
+    with pytest.raises(ValueError, match="BRANCH_STAGES"):
+        stage("mask")
+
+
 @pytest.mark.compile_heavy
-@pytest.mark.parametrize("network", ["resnet50", "resnet50_fpn"])
+@pytest.mark.parametrize("network", ["resnet50", "resnet50_fpn",
+                                     "resnet50_fpn_mask"])
 def test_lowered_train_step_carries_every_stage(network):
     """The tiny C4 and FPN train steps, lowered as fit_detector builds
     them, name every stage the family uses in their ops' metadata:
@@ -529,7 +560,8 @@ def test_lowered_train_step_carries_every_stage(network):
     import re
 
     from mx_rcnn_tpu.models.zoo import build_model, forward_train
-    from mx_rcnn_tpu.obs.profile import STAGES, stage_of
+    from mx_rcnn_tpu.obs.profile import (BRANCH_STAGES, STAGES, branch_of,
+                                         stage_of)
     from mx_rcnn_tpu.parallel.mesh import create_mesh
     from mx_rcnn_tpu.train.step import abstract_step_inputs, make_train_step
 
@@ -553,6 +585,18 @@ def test_lowered_train_step_carries_every_stage(network):
         "rpn_targets", "proposal", "roi_sample", "update"}
     # the update is no part of the differentiated function
     assert any(p.startswith("jit(step)/update/") for p in paths)
+    # the mask branch's four scopes, where there is a mask branch: the
+    # targets carry no gradient; its pooling is still a `roi_align` to
+    # every reader of STAGES
+    branch = set(BRANCH_STAGES) if network.endswith("_mask") else set()
+    assert {branch_of(p) for p in paths
+            if "transpose(" not in p} - {None} == branch
+    assert {branch_of(p) for p in paths
+            if "transpose(jvp(" in p} - {None} == branch - {"mask_targets"}
+    assert {stage_of(p) for p in paths
+            if branch_of(p) == "mask_align"} <= {"roi_align"}
+    assert {stage_of(p) for p in paths if branch_of(p) in (
+        "mask_head", "mask_targets", "mask_loss")} <= {None}
 
 
 def test_watchdog_stall_arms_trace_window(tmp_path):
@@ -734,6 +778,21 @@ def test_report_folds_the_rpn_targets_event():
                                 "kept_pos": 212, "kept_neg": 1836}
     assert ("rpn targets: walked 5 of 100 gt slots, kept 212 positives and "
             "1836 negatives") in report.render(s)
+
+
+def test_report_folds_the_mask_rois_event():
+    """A mask run's one ``mask_rois`` event reaches the summary and the
+    rendered report; a run without the branch says nothing."""
+    s = report.summarize(_synthetic_events())
+    assert s["mask_rois"] is None and "mask rois" not in report.render(s)
+    ev = {"slots": 128, "per_image_min": 9, "per_image_mean": 31.25,
+          "per_image_max": 64, "share": [0.9, 0.08, 0.02, 0.0]}
+    s = report.summarize(_synthetic_events() + [
+        dict(ev, type="mask_rois", epoch=0, dispatch=1)])
+    assert s["mask_rois"] == ev
+    assert ("mask rois:  9 / 31.25 / 64 (min / mean / max an image) of 128 "
+            "branch slots live at the first dispatch; P2 90.0%, P3 8.0%, "
+            "P4 2.0%, P5 0.0%") in report.render(s)
 
 
 def test_report_cli_roundtrip(tmp_path):

@@ -467,3 +467,42 @@ def test_epoch_callback_gets_the_train_state(accum_fit):
     for a, b in zip(jax.tree.leaves(state.params), jax.tree.leaves(final)):
         np.testing.assert_array_equal(np.asarray(a), b)
     assert jax.tree.leaves(state.opt_state)
+
+
+@pytest.mark.parametrize("network", ["resnet50", "resnet50_fpn_mask", "vgg"])
+def test_a_fixed_pattern_is_the_prefix_of_a_path_segment(network):
+    """``fixed_param_patterns`` name modules as the reference's
+    ``fixed_param_prefix`` did: ``conv0`` fixes the stem's
+    ``features/conv0`` and NOT the mask head's ``mask_conv0`` (as a bare
+    substring of the path it did, and the Mask presets never trained that
+    layer); every other leaf of every family is decided as before."""
+    from mx_rcnn_tpu.models.zoo import build_model, init_params
+    from mx_rcnn_tpu.train.optimizer import effective_fixed_patterns
+
+    cfg = generate_config(network, "synthetic")
+    model = build_model(cfg)
+    abstract = jax.eval_shape(lambda k: init_params(model, cfg, k),
+                              jax.random.PRNGKey(0))
+    pats = effective_fixed_patterns(cfg)
+    flat = jax.tree_util.tree_leaves_with_path(trainable_mask(abstract, pats))
+    got = {"/".join(str(getattr(k, "key", k)) for k in path): v
+           for path, v in flat}
+
+    def as_a_substring(path):
+        if "moving_" in path or path.rsplit("/", 1)[-1] in ("gamma", "beta"):
+            return False
+        return not any(p in path for p in pats)
+
+    moved = {k for k, v in got.items() if v != as_a_substring(k)}
+    if network.endswith("_mask"):
+        assert moved == {"params/mask_head/mask_conv0/kernel",
+                         "params/mask_head/mask_conv0/bias"}
+        assert all(v for k, v in got.items() if "/mask_head/" in k)
+        assert sum("/mask_head/" in k for k in got) == 12
+    else:
+        assert not moved
+    frozen = [k for k, v in got.items() if not v]
+    assert frozen and all(v for k, v in got.items() if "/rpn/" in k)
+    if network != "vgg":
+        assert not got["params/features/conv0/kernel"]
+        assert not any(v for k, v in got.items() if "/stage1/" in k)
