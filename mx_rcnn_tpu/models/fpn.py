@@ -20,9 +20,9 @@ per-level budget), NMS'd within each level, and the top post_nms of the
 score-ranked union is taken (Detectron-lineage semantics; the joint
 union-NMS variant stays available via fpn_nms_per_level=False) — every
 shape is compile-time fixed either way.
-ROI-to-level assignment computes the matmul pool on EVERY level and selects
-by mask: static shapes at 4x the pooling (pyramid_roi_align has what that
-costs on the chip).
+ROI-to-level assignment pools each roi ONCE from a canvas of all four levels,
+with bilinear weights that are zero outside the roi's own level: static
+shapes, one pair of contractions (pyramid_roi_align).
 """
 
 from __future__ import annotations
@@ -46,7 +46,8 @@ from mx_rcnn_tpu.ops.boxes import bbox_pred, clip_boxes
 from mx_rcnn_tpu.ops.canvas import rois_by_plane
 from mx_rcnn_tpu.ops.nms import nms_dispatch
 from mx_rcnn_tpu.ops.proposal import _decode_one_image
-from mx_rcnn_tpu.ops.roi_align import roi_align
+from mx_rcnn_tpu.ops.roi_align import (contract_weights, roi_align,
+                                       roi_align_weights)
 from mx_rcnn_tpu.targets.rcnn_targets import fg_rois_per_image, sample_rois
 from mx_rcnn_tpu.train.precision import island, model_dtype
 
@@ -513,6 +514,13 @@ def roi_levels(rois: jnp.ndarray, k0: int = 4, canonical: float = 224.0
     return jnp.clip(k, ROI_LEVELS[0], ROI_LEVELS[-1]).astype(jnp.int32)
 
 
+def roi_canvas(pyramid: Dict[int, jnp.ndarray]):
+    """(canvas (B, Hc, Wc, C), placements): the pooled levels in the one
+    map ``pyramid_roi_align`` contracts against. No gap: nothing convolves
+    over it, and a roi's weights are zero outside its level's rectangle."""
+    return pack_levels([pyramid[lv] for lv in ROI_LEVELS], gap=0)
+
+
 def pyramid_roi_align(
     pyramid: Dict[int, jnp.ndarray],
     rois: jnp.ndarray,
@@ -522,14 +530,19 @@ def pyramid_roi_align(
 ) -> jnp.ndarray:
     """(B, R, 4) rois → (B·R, P, P, C) pooled from each roi's FPN level.
 
-    Static-shape strategy: pool every roi from every ROI level and
-    mask-select, 4x the pooling a data-dependent partition would do. On a
-    v5e at the published sizes (8 images of 832x1344, 512 rois each) that
-    is 59.6 ms of a 292.6 ms step, the third-largest stage, while Eq. 1
-    sends 88.5 % of the sampled rois to P2 and 0.4 % to P5 (the cell
-    ``fpn_r101_train``, builder's chip run, PR 32: PERF.md sections 5 and
-    6). Each level's pool keeps the rois grouped by image
-    (ops/roi_align.py), so none of them crosses images.
+    Each roi is pooled ONCE, from ``roi_canvas``: the four levels stacked
+    into one map (at 832x1344, P2's 208x336 over a shelf of P3, P4, P5:
+    312x336; other pyramids open more shelves). A roi's bilinear weights
+    are built against its Eq. 1 level alone, in that level's coordinates
+    (so a border sample clamps as ``roi_align`` of that level clamps it),
+    laid at the level's rows and columns of the canvas, and are zero
+    everywhere else and for an invalid roi: the pooled values are
+    ``roi_align``'s of the assigned level, the other terms products with
+    an exact zero. Shapes are static and the cost is the same whatever
+    the levels' split: one pair of contractions at the canvas's width
+    (PERF.md sections 5 and 6, PR 36; pooling from every level and
+    selecting was 4x the poolings and 59.6 of the pyramid cell's 211 ms).
+    The rois stay grouped by image (ops/roi_align.py).
 
     graftcanvas: on a packed batch the pyramid holds PLANES, I images each
     in row order (ops/canvas.py::rois_by_plane), and `windows` (B, 4)
@@ -538,17 +551,21 @@ def pyramid_roi_align(
     """
     b, r = rois.shape[0], rois.shape[1]
     with stage("roi_align"):
-        grouped, win = rois_by_plane(pyramid[ROI_LEVELS[0]].shape[0], rois,
-                                     windows)
+        canvas, places = roi_canvas(pyramid)
+        hc, wc = canvas.shape[1:3]
+        grouped, win = rois_by_plane(canvas.shape[0], rois, windows)
         levels = roi_levels(grouped)
-        out = None
-        for lv in ROI_LEVELS:
-            pooled = roi_align(pyramid[lv], grouped, pool_size,
-                               1.0 / (2 ** lv), windows=win)
-            sel = (levels == lv)[..., None, None, None].astype(pooled.dtype)
-            out = pooled * sel if out is None else out + pooled * sel
-        out = out.reshape(b * r, *out.shape[2:])
-        return out * roi_valid.reshape(b * r, 1, 1, 1).astype(out.dtype)
+        live = roi_valid.reshape(levels.shape)
+        wy = wx = 0.0
+        whole = ((0, 0),) * 3  # (B, R, P): only the map's axis is padded
+        for lv, (y, x, h, w) in zip(ROI_LEVELS, places):
+            ly, lx = roi_align_weights(grouped, (h, w), pool_size,
+                                       1.0 / (2 ** lv), windows=win)
+            on = ((levels == lv) & live)[..., None, None].astype(ly.dtype)
+            wy = wy + jnp.pad(ly * on, whole + ((y, hc - y - h),))
+            wx = wx + jnp.pad(lx * on, whole + ((x, wc - x - w),))
+        out = contract_weights(wy, wx, canvas)
+        return out.reshape(b * r, *out.shape[2:])
 
 
 # ---------------------------------------------------------------------------
@@ -771,6 +788,11 @@ def forward_train(
         # gt slots walked / padded, kept positives / negatives
         "rpn_target_counts": island(rpn_t.counts),
         "roi_level_counts": _level_counts(samples.rois, samples.valid),
+        # the pooling's static form: the canvas's rows and columns, and
+        # the pairs of contractions a pyramid_roi_align call makes
+        "roi_pooling_form": island(jnp.asarray(
+            jax.eval_shape(lambda p: roi_canvas(p)[0], pyramid).shape[1:3]
+            + (1,))),
     }
 
     if model.use_mask:

@@ -70,7 +70,10 @@ EVENT_TYPES = (
                    # negatives of the batch (tools/train.py)
     "roi_levels",  # pyramid families, once a run with obs.enabled: the
                    # share of the first dispatch's sampled rois that FPN
-                   # Eq. 1 assigns to each pooled level (tools/train.py)
+                   # Eq. 1 assigns to each pooled level, and the pooling's
+                   # static form: the `canvas` (rows, columns) the levels
+                   # are stacked into and the `poolings` a call makes of
+                   # each roi (models/fpn.py::pyramid_roi_align)
     "mask_rois",   # the mask branch, once a run with obs.enabled: the live
                    # foreground rois of the first dispatch's `slots` branch
                    # slots an image (min / mean / max over the images) and

@@ -293,6 +293,10 @@ def summarize(events: List[Dict[str, Any]]) -> Dict[str, Any]:
         # pyramid families: the first dispatch's sampled rois by the level
         # FPN Eq. 1 pools each from (P2..P5), None for the others
         "roi_level_share": roi_levels.get("share"),
+        # ... and the form of the pooling: the canvas (rows, columns) the
+        # levels are stacked into, the poolings a call makes of each roi
+        "roi_pooling": ({k: roi_levels[k] for k in ("canvas", "poolings")}
+                        if "canvas" in roi_levels else None),
         "rpn_targets": rpn_targets and {k: rpn_targets.get(k) for k in (
             "slots_walked", "slots_padded", "kept_pos", "kept_neg")},
         # the mask branch: the first dispatch's live rois of its slots
@@ -485,6 +489,11 @@ def render(summary: Dict[str, Any]) -> str:
             f"P{lv} {100 * s:.1f}%" for lv, s in
             enumerate(summary["roi_level_share"], start=2))
             + " of the first dispatch's sampled rois")
+    if summary.get("roi_pooling"):
+        rp = summary["roi_pooling"]
+        lines.append(
+            f"  roi pooling: {rp['poolings']} a call of each roi, from a "
+            f"canvas of {rp['canvas'][0]}x{rp['canvas'][1]} cells")
     if summary.get("mask_rois"):
         mr = summary["mask_rois"]
         lines.append(

@@ -26,6 +26,12 @@ PERF.md sections 5 and 6 carry the measured share of the train step (PR 26;
 the masked loop over every image of the batch that this replaced was 56 % of
 the one-chip step and 84 % of the four-chip one: ledger, PR 25).
 
+The op is two parts: ``roi_align_weights`` builds ``Wy`` / ``Wx`` of rois
+against ONE map, and ``contract_weights`` contracts weights against a map.
+``roi_align`` is the one after the other (C4's single map); the pyramid heads
+(models/fpn.py::pyramid_roi_align) lay each roi's weights, built against its
+own level, on a canvas of all the levels and contract once.
+
 - ``roi_align``: bilinear sampling, ``sampling_ratio`` points per bin axis,
   average-pooled (He et al. Mask R-CNN semantics; ``aligned=True`` applies the
   -0.5 half-pixel correction of Detectron2, default False matches the classic
@@ -38,6 +44,8 @@ the one-chip step and 84 % of the four-chip one: ledger, PR 25).
 """
 
 from __future__ import annotations
+
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -94,7 +102,25 @@ def roi_align(
 
     Returns: (B, R, P, P, C), features.dtype.
     """
-    _, h, w, _ = features.shape
+    wy, wx = roi_align_weights(rois, features.shape[1:3], output_size,
+                               spatial_scale, sampling_ratio, aligned,
+                               windows)
+    return contract_weights(wy, wx, features)
+
+
+def roi_align_weights(
+    rois: jnp.ndarray,
+    extent: Tuple[int, int],
+    output_size: int,
+    spatial_scale: float,
+    sampling_ratio: int = 2,
+    aligned: bool = False,
+    windows: jnp.ndarray = None,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The bilinear weights of ``roi_align``'s rois against ONE map of
+    ``extent`` (H, W) cells at ``spatial_scale``: ``wy (B, R, P, H)`` and
+    ``wx (B, R, P, W)``, float32. Arguments as ``roi_align``'s."""
+    h, w = extent
     p = output_size
     s = sampling_ratio
     offset = 0.5 if aligned else 0.0
@@ -117,8 +143,14 @@ def roi_align(
         return wy, wx
 
     in_axes = (0, None if windows is None else 0)
-    wy, wx = jax.vmap(jax.vmap(one_roi_weights, in_axes=in_axes),
-                      in_axes=in_axes)(rois, windows)
+    return jax.vmap(jax.vmap(one_roi_weights, in_axes=in_axes),
+                    in_axes=in_axes)(rois, windows)
+
+
+def contract_weights(wy: jnp.ndarray, wx: jnp.ndarray,
+                     features: jnp.ndarray) -> jnp.ndarray:
+    """``wy (B, R, P, H)``, ``wx (B, R, Q, W)`` against ``features
+    (B, H, W, C)`` -> (B, R, P, Q, C), features.dtype: over H, then W."""
     dt = features.dtype
     # The image axis is a batch dimension of both operands: no roi meets
     # another image's map, and a batch sharded over a mesh axis stays put.

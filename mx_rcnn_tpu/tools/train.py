@@ -929,12 +929,19 @@ def fit_detector(
                             if "RoiLevelShare" in metrics:
                                 share = [round(float(s), 4) for s in
                                          metrics["RoiLevelShare"]]
+                                *canvas, poolings = (
+                                    round(float(c)) for c in
+                                    metrics["RoiPoolingForm"])
                                 obs_log.emit("roi_levels", epoch=epoch,
-                                             dispatch=i + 1, share=share)
+                                             dispatch=i + 1, share=share,
+                                             canvas=canvas,
+                                             poolings=poolings)
                                 logger.info(
                                     "sampled rois by pyramid level "
-                                    "(P2..P5) at dispatch %d: %s", i + 1,
-                                    share)
+                                    "(P2..P5) at dispatch %d, each pooled "
+                                    "%d time(s) a call from a canvas of "
+                                    "%dx%d cells: %s", i + 1, poolings,
+                                    *canvas, share)
                             if "MaskRoiCounts" in metrics:
                                 counts = [float(c) for c in
                                           metrics["MaskRoiCounts"]]

@@ -124,6 +124,8 @@ def _metric_parts(aux: Dict[str, jnp.ndarray]) -> Dict[str, tuple]:
     if "roi_level_counts" in aux:  # pyramid families: a vector, P2..P5
         counts = aux["roi_level_counts"]
         out["RoiLevelShare"] = (counts, jnp.sum(counts))
+        # static: the pooling canvas's rows, columns, poolings a call
+        out["RoiPoolingForm"] = (aux["roi_pooling_form"], one)
     if "mask_roi_counts" in aux:  # models/fpn.py::mask_branch
         out["MaskRoiCounts"] = (aux["mask_roi_counts"], one)
     return out

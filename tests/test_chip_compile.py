@@ -406,6 +406,25 @@ def test_one_chip_roi_align_is_one_batched_contraction(one_chip_mesh,
         2 * n for n in _ROI_ALIGN_OUT}
 
 
+def test_pyramid_step_pools_each_roi_once_from_the_canvas(one_chip_mesh,
+                                                          monkeypatch):
+    """The tiny pyramid step (2 images of 128x192, 32 rois each, 7 bins,
+    256 channels): P2's 32x48 over a shelf of P3, P4, P5 is a 48x48
+    canvas, and the ``roi_align`` stage holds ONE forward contraction over
+    H, canvas-wide, where pooling level by level held four; no array in
+    the program is ``(B, R, P, W_l, C)`` at P3's, P4's or P5's own width."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    hlo = _tiny_pyramid_step_hlo(one_chip_mesh)
+    ops = _roi_align_ops(hlo)
+    forward_h = [n for o, n, path in ops if o == "convolution"
+                 and path.endswith(_H_CONTRACTION)]
+    assert forward_h == [2 * 32 * 7 * 48 * 256], forward_h
+    a_level_wide = {2 * 32 * 7 * w * 256 for w in (24, 12, 6)}
+    assert not [(o, n) for o, n, _ in ops if n in a_level_wide]
+    assert not re.findall(r"\w+\[2,32,7,(?:24|12|6),256\]", hlo)
+    assert re.findall(r"bf16\[2,48,48,256\]", hlo)
+
+
 def test_mask_step_at_the_cells_sizes_fits_one_chip(one_chip_mesh,
                                                     monkeypatch):
     """Mask R-CNN on ResNet-101-FPN at the sizes the cell
@@ -416,8 +435,11 @@ def test_mask_step_at_the_cells_sizes_fits_one_chip(one_chip_mesh,
     branch runs over the sampler's foreground block, 128 of the 512 slots:
     no array is (8, 512, 14 bins, ...) - over every slot P2's intermediate
     of the pooling alone is 9.19 GB and the step is refused (PERF.md section
-    6, PR 34) - and the loss picks its class's map without a gather. The one
-    full-size compile of this file: about two minutes."""
+    6, PR 34) - and the loss picks its class's map without a gather. Both
+    poolings contract against the 312x336 canvas of the four levels, once:
+    their intermediates are P2's width, 336, and none is 168, 84 or 42
+    wide (PR 36). The one full-size compile of this file: about two
+    minutes."""
     from benchmarks import manifest
     from benchmarks.drivers.train import _program_config
     from mx_rcnn_tpu.models.zoo import build_model
@@ -447,6 +469,9 @@ def test_mask_step_at_the_cells_sizes_fits_one_chip(one_chip_mesh,
     assert not re.findall(rf"\w+\[{images},{slots},{bins},[\d,]*\]", hlo)
     assert not re.findall(rf"\w+\[{images * slots},{bins},{bins},\d+\]", hlo)
     assert re.findall(rf"bf16\[{images},{block},{bins},{bins},256\]", hlo)
+    pooled_at = rf"\w+\[{images},(?:{block},{bins}|{slots},7),(\d+),256\]"
+    assert {int(w) for w in re.findall(pooled_at, hlo)} - {7, bins} == {336}
+    assert re.findall(rf"bf16\[{images},312,336,256\]", hlo)
     paths = set(re.findall(r'op_name="([^"]*)"', hlo))
     assert {branch_of(p) for p in paths} - {None} == set(BRANCH_STAGES)
     moved = [ln for ln in hlo.splitlines()

@@ -121,6 +121,102 @@ def test_pyramid_roi_align_selects_assigned_level(rng):
                                    rtol=1e-5, atol=1e-5)
 
 
+_EVEN = ((32, 48), (16, 24), (8, 12), (4, 6))   # a 128x192 image, halved
+_ODD = ((13, 9), (7, 5), (4, 3), (2, 2))         # halving rounds up
+# the side of a roi, px: Eq. 1 sends < 112 to P2, >= 448 to P5
+_SPLITS = {"all_on_p2": (8, 100), "none_on_p2": (120, 900),
+           "spread": (8, 900), "all_invalid": (8, 900)}
+_POOLINGS = (
+    [(split, bins, dt, _EVEN, 1) for split in _SPLITS for bins in (7, 14)
+     for dt in ("bfloat16", "float32")]
+    + [("spread", bins, dt, _EVEN, 2) for bins in (7, 14)
+       for dt in ("bfloat16", "float32")]
+    + [("spread", bins, dt, _ODD, 1) for bins in (7, 14)
+       for dt in ("bfloat16", "float32")])
+
+
+@pytest.mark.parametrize(
+    "split,bins,dtype,sizes,per_plane", _POOLINGS,
+    ids=[f"{s}-{b}-{d}-{'odd' if z is _ODD else 'even'}-{i}_a_plane"
+         for s, b, d, z, i in _POOLINGS])
+def test_pyramid_roi_align_is_roi_align_of_the_assigned_level(
+        split, bins, dtype, sizes, per_plane):
+    """One pooling from the canvas of all levels gives, roi by roi, what
+    ``roi_align`` of the roi's Eq. 1 level gives, and zeros for an invalid
+    roi: the values AND the gradient to each level's map, bfloat16 to the
+    bit (the added terms are products with an exact zero), float32 to the
+    order of a sum. Over the levels' splits, 7 and 14 bins, a packed batch
+    (two images a plane, each clamped to its own placement window) and a
+    pyramid whose halving rounds up (``_ODD`` packs onto three shelves)."""
+    from mx_rcnn_tpu.ops.canvas import rois_by_plane
+
+    dt = jnp.dtype(dtype)
+    rs = np.random.RandomState(bins + len(split))
+    planes, r = 2, 24
+    b = planes * per_plane
+    pyramid = {lv: jnp.asarray(rs.randn(planes, h, w, 8), dt)
+               for lv, (h, w) in zip(F.ROI_LEVELS, sizes)}
+    (_, wc), places = F.pack_placements(list(sizes), gap=0)
+    assert wc == sizes[0][1]
+    assert len({y for y, _, _, _ in places}) == (3 if sizes is _ODD else 2)
+    ih, iw = 4 * sizes[0][0], 4 * sizes[0][1] // per_plane
+    side = rs.uniform(*_SPLITS[split], size=(b, r))
+    cy, cx = rs.uniform(0, ih, (b, r)), rs.uniform(0, iw, (b, r))
+    windows = None
+    if per_plane > 1:  # image i of a plane lies i * iw px to the right
+        x0 = iw * (np.arange(b) % per_plane).astype(np.float32)
+        windows = jnp.asarray(np.stack(
+            [np.zeros(b), x0, np.full(b, ih), np.full(b, iw)], axis=1),
+            jnp.float32)
+        cx = cx + x0[:, None]
+    rois = jnp.asarray(np.stack([cx - side / 2, cy - side / 2,
+                                 cx + side / 2, cy + side / 2], axis=-1),
+                       jnp.float32)
+    valid = jnp.asarray(rs.uniform(size=(b, r)) > 0.2
+                        if split != "all_invalid" else np.zeros((b, r), bool))
+    levels = np.asarray(F.roi_levels(rois))
+    on_p2 = (levels == 2).mean()
+    assert {"all_on_p2": on_p2 == 1, "none_on_p2": on_p2 == 0}.get(
+        split, 0 < on_p2 < 1 and len(np.unique(levels)) == 4)
+
+    def level_by_level(pyr):
+        grouped, win = rois_by_plane(planes, rois, windows)
+        out = 0.0
+        for lv in F.ROI_LEVELS:
+            pooled = roi_align(pyr[lv], grouped, bins, 1.0 / 2 ** lv,
+                               windows=win).reshape(b, r, bins, bins, -1)
+            mine = (F.roi_levels(rois) == lv) & valid
+            out = out + jnp.where(mine[..., None, None, None], pooled, 0)
+        return out.reshape(b * r, bins, bins, -1)
+
+    def once(pyr):
+        return F.pyramid_roi_align(pyr, rois, valid, bins, windows=windows)
+
+    def value_and_map_grads(pool):
+        def weighed(pyr):
+            out = pool(pyr)
+            wave = jnp.cos(jnp.arange(out.size, dtype=jnp.float32))
+            return jnp.sum(out.astype(jnp.float32)
+                           * wave.reshape(out.shape)), out
+        (_, out), grads = jax.jit(
+            jax.value_and_grad(weighed, has_aux=True))(pyramid)
+        return [np.asarray(a, np.float32)
+                for a in [out] + [grads[lv] for lv in F.ROI_LEVELS]]
+
+    got, want = value_and_map_grads(once), value_and_map_grads(level_by_level)
+    assert got[0].shape == (b * r, bins, bins, 8)
+    if split == "all_invalid":
+        assert not got[0].any() and not any(g.any() for g in got[1:])
+    else:
+        assert np.abs(want[0]).max() > 0.5
+    for g, w in zip(got, want):
+        if dt == jnp.bfloat16:
+            np.testing.assert_array_equal(g, w)
+        else:
+            np.testing.assert_allclose(
+                g, w, rtol=0, atol=1e-6 * max(1.0, np.abs(w).max()))
+
+
 def test_per_level_nms_union_suppression():
     """Direct check of the per-level scope on constructed candidates:
     same-level near-duplicates ARE suppressed, cross-level near-duplicates

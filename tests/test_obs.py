@@ -764,6 +764,15 @@ def test_report_folds_the_roi_levels_event():
         {"type": "roi_levels", "epoch": 0, "dispatch": 1, "share": share}])
     assert s["roi_level_share"] == share
     assert "roi levels: P2 88.5%, P3 9.8%, P4 1.3%, P5 0.4%" in report.render(s)
+    # the pooling's form rides on the same event (a log from before PR 36
+    # has none, and says nothing of it)
+    assert s["roi_pooling"] is None and "roi pooling" not in report.render(s)
+    s = report.summarize(_synthetic_events() + [
+        {"type": "roi_levels", "epoch": 0, "dispatch": 1, "share": share,
+         "canvas": [312, 336], "poolings": 1}])
+    assert s["roi_pooling"] == {"canvas": [312, 336], "poolings": 1}
+    assert ("roi pooling: 1 a call of each roi, from a canvas of 312x336 "
+            "cells") in report.render(s)
 
 
 def test_report_folds_the_rpn_targets_event():

@@ -187,3 +187,30 @@ def test_grouped_roi_align_reads_each_rois_own_image(b, with_windows):
         others = noise.at[i].set(feat[i])
         np.testing.assert_array_equal(np.asarray(pool(others)[i]),
                                       np.asarray(out[i]))
+
+
+@pytest.mark.parametrize("aligned", [False, True], ids=["classic", "aligned"])
+@pytest.mark.parametrize("with_windows", [False, True],
+                         ids=["whole_map", "windows"])
+def test_weights_and_contraction_are_roi_align_split_in_two(with_windows,
+                                                            aligned):
+    """``roi_align`` is ``contract_weights`` of ``roi_align_weights``: the
+    weights of rois against one map are float32 ``(B, R, P, H)`` and
+    ``(B, R, P, W)``, each sample point's hat weights sum to one inside the
+    map (or the window), and contracting them gives ``roi_align``'s values
+    to the bit (models/fpn.py lays such weights on a canvas of levels)."""
+    from mx_rcnn_tpu.ops.roi_align import contract_weights, roi_align_weights
+
+    feat, rois, windows = _grouped_case(2, with_windows, seed=5)
+    per_roi = (None if windows is None
+               else jnp.repeat(windows[:, None], rois.shape[1], axis=1))
+    wy, wx = roi_align_weights(rois, feat.shape[1:3], 3, 1 / 16,
+                               aligned=aligned, windows=per_roi)
+    assert wy.shape == (2, 5, 3, feat.shape[1]) and wy.dtype == jnp.float32
+    assert wx.shape == (2, 5, 3, feat.shape[2]) and wx.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(wy.sum(-1)), 1.0, rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(wx.sum(-1)), 1.0, rtol=1e-6)
+    np.testing.assert_array_equal(
+        np.asarray(contract_weights(wy, wx, feat)),
+        np.asarray(roi_align(feat, rois, 3, 1 / 16, aligned=aligned,
+                             windows=per_roi)))
