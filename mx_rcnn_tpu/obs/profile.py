@@ -343,7 +343,11 @@ class TraceController:
     def before_step(self, step: int):
         """Called just before dispatching global step ``step``: opens the
         armed window so the capture INCLUDES step ``trace_at_step`` —
-        step 1 (the compile-heavy first dispatch) is capturable too."""
+        step 1 (the compile-heavy first dispatch) is capturable too.
+        Nothing armed (``_arm_at`` changes here alone): one int compare,
+        no lock."""
+        if not self._arm_at or step < self._arm_at:
+            return
         with self._lock:
             if self._active_dir is not None:
                 return
@@ -361,7 +365,11 @@ class TraceController:
         is asynchronous and the loop waits for none of its own, so before
         a window is closed, and only then, ``outputs`` (optional: device
         arrays this dispatch returned) are waited for: the capture holds
-        the work of the steps it brackets."""
+        the work of the steps it brackets. No window open: one attribute
+        check, no lock (a stall window opened meanwhile by the watchdog's
+        thread is closed at the next completed step)."""
+        if self._active_dir is None:
+            return
         with self._lock:
             if self._active_dir is not None and (
                     self._stop_after is None or step >= self._stop_after):

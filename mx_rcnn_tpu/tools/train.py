@@ -556,6 +556,42 @@ def fit_detector(
     # first dispatch's metrics, set-up's one wait for a dispatch.
     first_dispatch_due = obs_log.enabled
 
+    def _first_dispatch_counters(epoch: int, i: int, metrics):
+        """The counter events of the first dispatch's metrics (a wait for
+        that dispatch: set-up's, once a run)."""
+        if "RpnTargetCounts" in metrics:
+            walked, padded, kept_pos, kept_neg = (
+                round(float(c)) for c in metrics["RpnTargetCounts"])
+            obs_log.emit("rpn_targets", epoch=epoch, dispatch=i + 1,
+                         slots_walked=walked, slots_padded=padded,
+                         kept_pos=kept_pos, kept_neg=kept_neg)
+            logger.info("anchor labelling at dispatch %d: walked %d of %d "
+                        "gt slots, kept %d positives and %d negatives",
+                        i + 1, walked, padded, kept_pos, kept_neg)
+        if "RoiLevelShare" in metrics:
+            share = [round(float(s), 4) for s in metrics["RoiLevelShare"]]
+            *canvas, poolings = (round(float(c))
+                                 for c in metrics["RoiPoolingForm"])
+            obs_log.emit("roi_levels", epoch=epoch, dispatch=i + 1,
+                         share=share, canvas=canvas, poolings=poolings)
+            logger.info("sampled rois by pyramid level (P2..P5) at "
+                        "dispatch %d, each pooled %d time(s) a call from a "
+                        "canvas of %dx%d cells: %s", i + 1, poolings,
+                        *canvas, share)
+        if "MaskRoiCounts" in metrics:
+            counts = [float(c) for c in metrics["MaskRoiCounts"]]
+            live = max(sum(counts[3:]), 1.0)
+            mask_rois = dict(
+                slots=fg_rois_per_image(cfg.train.batch_rois,
+                                        cfg.train.fg_fraction),
+                per_image_min=round(counts[0]),
+                per_image_mean=round(counts[1], 2),
+                per_image_max=round(counts[2]),
+                share=[round(c / live, 4) for c in counts[3:]])
+            obs_log.emit("mask_rois", epoch=epoch, dispatch=i + 1,
+                         **mask_rois)
+            logger.info("mask branch at dispatch %d: %s", i + 1, mask_rois)
+
     def _ckpt_meta(at_epoch: int, at_dispatch: Optional[int],
                    hosts=None):
         """The topology sidecar (train/checkpoint.py::META_NAME): what a
@@ -869,14 +905,9 @@ def fit_detector(
                     # recorded.
                     for i, batch in timer.iterate(epoch, batches,
                                                   start=skip):
-                        if chaos_spec.active:
-                            # chaos site "train_dispatch": the injected
-                            # device loss (device_lost_at_step) fires
-                            # before the dispatch that would complete
-                            # optimizer step K.
-                            chaos_spec.fire(
-                                "train_dispatch",
-                                step=epoch * steps_per_epoch + i + 1)
+                        # Every statement of the body lies in ONE phase
+                        # of obs/timing.py::LOOP_SPANS, so that a device
+                        # idle under the profiler has a named cause.
                         with timer.span("train.key"):
                             # the iteration's first device dispatch: with
                             # the device's queue full it is HERE that the
@@ -885,24 +916,34 @@ def fit_detector(
                                 rng, epoch * steps_per_epoch + i)
                         with timer.span("train.place"):
                             sharded = shard_batch(batch, mesh)
-                        if cost_tracker is not None:
-                            # One AOT cost capture per compiled shape
-                            # bucket (dict lookup otherwise) — the
-                            # `cost` event behind per-bucket MFU.
-                            cost_tracker.observe(step_fn, state, sharded,
-                                                 k)
-                        if tracer is not None:
-                            # Pre-dispatch arming: the window must
-                            # INCLUDE step trace_at_step (even step 1).
-                            tracer.before_step(timer.total_steps + 1)
+                        with timer.span("train.observe"):
+                            if chaos_spec.active:
+                                # chaos site "train_dispatch": the
+                                # injected device loss
+                                # (device_lost_at_step) fires before the
+                                # dispatch that would complete optimizer
+                                # step K.
+                                chaos_spec.fire(
+                                    "train_dispatch",
+                                    step=epoch * steps_per_epoch + i + 1)
+                            if cost_tracker is not None:
+                                # One AOT cost capture per compiled shape
+                                # bucket (dict lookup otherwise) — the
+                                # `cost` event behind per-bucket MFU.
+                                cost_tracker.observe(step_fn, state,
+                                                     sharded, k)
+                            if tracer is not None:
+                                # Pre-dispatch arming: the window must
+                                # INCLUDE step trace_at_step (even step 1).
+                                tracer.before_step(timer.total_steps + 1)
                         with timer.span("train.enqueue"):
                             if health_on:
                                 state, metrics, pulse = step_fn(
                                     state, sharded, k)
                             else:
                                 state, metrics = step_fn(state, sharded, k)
-                        pos = (epoch, i + 1)
-                        timer.dispatched()
+                            pos = (epoch, i + 1)
+                            timer.dispatched()
                         with timer.span("train.metrics"):
                             # Speedometer's line, every `frequent`
                             # dispatches, reads the means of the
@@ -910,109 +951,67 @@ def fit_detector(
                             # ready-only drain): no host sync here
                             bag.update(metrics)
                             speedometer(epoch, i, bag)
-                        if first_dispatch_due:
-                            first_dispatch_due = False
-                            if "RpnTargetCounts" in metrics:
-                                walked, padded, kept_pos, kept_neg = (
-                                    round(float(c)) for c in
-                                    metrics["RpnTargetCounts"])
-                                obs_log.emit(
-                                    "rpn_targets", epoch=epoch,
-                                    dispatch=i + 1, slots_walked=walked,
-                                    slots_padded=padded, kept_pos=kept_pos,
-                                    kept_neg=kept_neg)
-                                logger.info(
-                                    "anchor labelling at dispatch %d: "
-                                    "walked %d of %d gt slots, kept %d "
-                                    "positives and %d negatives", i + 1,
-                                    walked, padded, kept_pos, kept_neg)
-                            if "RoiLevelShare" in metrics:
-                                share = [round(float(s), 4) for s in
-                                         metrics["RoiLevelShare"]]
-                                *canvas, poolings = (
-                                    round(float(c)) for c in
-                                    metrics["RoiPoolingForm"])
-                                obs_log.emit("roi_levels", epoch=epoch,
-                                             dispatch=i + 1, share=share,
-                                             canvas=canvas,
-                                             poolings=poolings)
-                                logger.info(
-                                    "sampled rois by pyramid level "
-                                    "(P2..P5) at dispatch %d, each pooled "
-                                    "%d time(s) a call from a canvas of "
-                                    "%dx%d cells: %s", i + 1, poolings,
-                                    *canvas, share)
-                            if "MaskRoiCounts" in metrics:
-                                counts = [float(c) for c in
-                                          metrics["MaskRoiCounts"]]
-                                live = max(sum(counts[3:]), 1.0)
-                                mask_rois = dict(
-                                    slots=fg_rois_per_image(
-                                        cfg.train.batch_rois,
-                                        cfg.train.fg_fraction),
-                                    per_image_min=round(counts[0]),
-                                    per_image_mean=round(counts[1], 2),
-                                    per_image_max=round(counts[2]),
-                                    share=[round(c / live, 4)
-                                           for c in counts[3:]])
-                                obs_log.emit("mask_rois", epoch=epoch,
-                                             dispatch=i + 1, **mask_rois)
-                                logger.info(
-                                    "mask branch at dispatch %d: %s",
-                                    i + 1, mask_rois)
-                        if tracer is not None:
-                            # timer.total_steps increments when the
-                            # generator resumes — this dispatch is the
-                            # (+1)th completed. A window that closes
-                            # here first waits for this dispatch (the
-                            # loop itself never does).
-                            tracer.step_completed(timer.total_steps + 1,
-                                                  outputs=metrics)
-                        if monitor is not None:
-                            # stores a reference per dispatch; pulls to
-                            # host (and runs the tripwires) only at the
-                            # obs.health_every cadence. A tripped wire
-                            # raises NumericsAnomaly out of the loop
-                            # AFTER saving the known-good checkpoint.
-                            monitor.observe(pulse, epoch=epoch,
-                                            dispatch=i + 1)
-                        done = i + 1  # dispatches complete in this epoch
-                        if healer is not None:
-                            healer.note_progress()
-                            if copy_state is None and healer.snapshots:
-                                # The snapshot's copy program, compiled
-                                # for the state the step RETURNS (its
-                                # types are every later dispatch's), on
-                                # the session's first dispatch: set-up
-                                # pays for it, never the steady state (a
-                                # compile at dispatch 200 would drain the
-                                # queue the deferred read is to spare).
-                                copy_state = compile_tree_copy(
-                                    (state.params, state.opt_state))
-                            healer.poll_snapshot()
-                            if healer.snapshot_due():
-                                healer.begin_snapshot(_begin_snapshot)
-                        if chaos_spec.active:
-                            chaos_spec.maybe_sigterm(
-                                epoch * steps_per_epoch + done)
-                        if stopper is not None:
-                            # Coordinated preemption (graftquorum): the
-                            # signaled host PROPOSES its next boundary;
-                            # every host folds in its own floor and ALL
-                            # of them drain to the agreed max before the
-                            # one barrier+publish in _honor_preemption.
-                            # The un-signaled steady state costs one
-                            # store read per dispatch.
-                            gdone = epoch * steps_per_epoch + done
-                            if (guard is not None and guard.requested
-                                    and not stop_requested):
-                                stopper.request(gdone)
-                                stop_requested = True
-                            agreed = stopper.check(gdone)
-                            if agreed is not None and gdone >= agreed:
+                        with timer.span("train.snapshot"):
+                            if healer is not None:
+                                healer.note_progress()
+                                if copy_state is None and healer.snapshots:
+                                    # The snapshot's copy program,
+                                    # compiled for the state the step
+                                    # RETURNS (its types are every later
+                                    # dispatch's), on the session's first
+                                    # dispatch: set-up pays for it, never
+                                    # the steady state (a compile at
+                                    # dispatch 200 would drain the queue
+                                    # the deferred read is to spare).
+                                    copy_state = compile_tree_copy(
+                                        (state.params, state.opt_state))
+                                healer.poll_snapshot()
+                                if healer.snapshot_due():
+                                    healer.begin_snapshot(_begin_snapshot)
+                        with timer.span("train.observe"):
+                            if first_dispatch_due:
+                                first_dispatch_due = False
+                                _first_dispatch_counters(epoch, i, metrics)
+                            if tracer is not None:
+                                # timer.total_steps increments when the
+                                # generator resumes — this dispatch is
+                                # the (+1)th completed. A window that
+                                # closes here first waits for this
+                                # dispatch (the loop itself never does).
+                                tracer.step_completed(
+                                    timer.total_steps + 1, outputs=metrics)
+                            if monitor is not None:
+                                # stores a reference per dispatch; pulls
+                                # to host (and runs the tripwires) only
+                                # at the obs.health_every cadence. A
+                                # tripped wire raises NumericsAnomaly out
+                                # of the loop AFTER saving the known-good
+                                # checkpoint.
+                                monitor.observe(pulse, epoch=epoch,
+                                                dispatch=i + 1)
+                            done = i + 1  # dispatches complete this epoch
+                            if chaos_spec.active:
+                                chaos_spec.maybe_sigterm(
+                                    epoch * steps_per_epoch + done)
+                            if stopper is not None:
+                                # Coordinated preemption (graftquorum):
+                                # the signaled host PROPOSES its next
+                                # boundary; every host folds in its own
+                                # floor and ALL of them drain to the
+                                # agreed max before the one
+                                # barrier+publish in _honor_preemption.
+                                # The un-signaled steady state costs one
+                                # store read per dispatch.
+                                gdone = epoch * steps_per_epoch + done
+                                if (guard is not None and guard.requested
+                                        and not stop_requested):
+                                    stopper.request(gdone)
+                                    stop_requested = True
+                                agreed = stopper.check(gdone)
+                                if agreed is not None and gdone >= agreed:
+                                    _honor_preemption(epoch, done)
+                            elif guard is not None and guard.requested:
                                 _honor_preemption(epoch, done)
-                        elif guard is not None and guard.requested:
-                            _honor_preemption(epoch, done)
                     # pos stays at (epoch, <last dispatch>) until the
                     # epoch-end work below completes: a heal landing
                     # inside this window then REPLAYS the whole block
@@ -1150,6 +1149,7 @@ def fit_detector(
                 recorder.dump("crash")
         raise
     finally:
+        timer.close()  # the collector hook goes with the loop
         if healer is not None:
             healer.drop_snapshot()  # the session is over: not awaited
         if guard is not None:

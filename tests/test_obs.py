@@ -12,6 +12,7 @@ compile-count fields; with obs disabled no file is written and the
 MetricBag lazy-drain discipline is untouched.
 """
 
+import gc
 import json
 import os
 import subprocess
@@ -148,6 +149,7 @@ def test_step_timer_phase_split(tmp_path):
         seen.append((i, batch["i"]))
         time.sleep(0.01)
         timer.dispatched()
+    assert not timer._hooks  # the epoch's collector hook went with it
     log.close()
     assert seen == [(0, 0), (1, 1), (2, 2)]
     steps = [e for e in report.load_events(str(tmp_path))
@@ -199,13 +201,15 @@ def _host_events(trace_dir):
 def test_step_timer_spans_land_on_the_profilers_clock(tmp_path):
     """Under a profiler trace the host plane holds the loop's phases:
     ``train.next_batch`` between the step annotations, ``train.key`` /
-    ``train.place`` / ``train.enqueue`` / ``train.metrics`` inside them,
-    on one thread,
+    ``train.place`` / ``train.observe`` / ``train.enqueue`` /
+    ``train.metrics`` / ``train.snapshot`` inside them, on one thread,
+    a ``train.gc`` collection nested in the phase it interrupted,
     with ``step_num`` rising as the step events' ``step`` does; the same
-    clock reads put ``place_ms`` and ``enqueue_ms`` into the event."""
+    clock reads put every phase's ``<phase>_ms`` into the event, and the
+    phases tile the iteration."""
     import jax
 
-    from mx_rcnn_tpu.obs.timing import LOOP_SPANS, STEP_SPAN
+    from mx_rcnn_tpu.obs.timing import GC_SPAN, LOOP_SPANS, STEP_SPAN
 
     log = open_event_log(str(tmp_path / "obs"))
     timer = StepTimer(log)
@@ -217,15 +221,22 @@ def test_step_timer_spans_land_on_the_profilers_clock(tmp_path):
                     pass
                 with timer.span("train.place"):
                     time.sleep(0.02)
+                with timer.span("train.observe"):
+                    pass
                 with timer.span("train.enqueue"):
                     time.sleep(0.01)
-                timer.dispatched()
+                    timer.dispatched()
                 with timer.span("train.metrics"):
                     time.sleep(0.002)
+                with timer.span("train.snapshot"):
+                    gc.collect(1)
+                with timer.span("train.observe"):
+                    pass
             with timer.span("train.checkpoint"):
                 pass
     finally:
         jax.profiler.stop_trace()
+        timer.close()
     log.close()
 
     ours = [e for e in _host_events(str(tmp_path / "trace"))
@@ -235,20 +246,32 @@ def test_step_timer_spans_land_on_the_profilers_clock(tmp_path):
                    key=lambda e: e[2])
     assert [e[4]["step_num"] for e in steps] == [1, 2, 3, 4]
     inside = {name: [e for e in ours if e[1] == name] for name in LOOP_SPANS}
-    # 2 x (2 batches + the exhausted next()), 4 bodies, 2 epoch ends
-    assert [len(inside[n]) for n in LOOP_SPANS] == [6, 4, 4, 4, 4, 2]
-    for name in LOOP_SPANS[1:5]:
-        for e, st in zip(sorted(inside[name], key=lambda e: e[2]), steps):
+    # 2 x (2 batches + the exhausted next()), 4 bodies (two observe spans
+    # each), 2 epoch ends
+    assert [len(inside[n]) for n in LOOP_SPANS] == [6, 4, 4, 4, 4, 2, 8, 4]
+    for name in LOOP_SPANS[1:5] + LOOP_SPANS[6:]:
+        for e, st in zip(sorted(inside[name], key=lambda e: e[2])[::len(
+                inside[name]) // 4], steps):
             assert st[2] <= e[2] and e[3] <= st[3]  # nested in its step
     for e in inside["train.next_batch"] + inside["train.checkpoint"]:
         assert not any(st[2] < e[3] and e[2] < st[3] for st in steps)
+    gcs = [e for e in _host_events(str(tmp_path / "trace"))
+           if e[1] == GC_SPAN]
+    assert sum(any(s[2] <= g[2] and g[3] <= s[3]
+                   for s in inside["train.snapshot"]) for g in gcs) >= 4
 
     events = [e for e in report.load_events(str(tmp_path / "obs"))
               if e["type"] == "step"]
     assert [e["step"] for e in events] == [1, 2, 3, 4]
+    phases = ("key_ms", "place_ms", "observe_ms", "enqueue_ms",
+              "metrics_ms", "snapshot_ms")
     for e in events:
         assert e["place_ms"] >= 18.0 and e["enqueue_ms"] >= 8.0
         assert e["place_ms"] + e["enqueue_ms"] <= e["dispatch_ms"] + 0.5
+        assert e["gc_ms"] > 0 and "checkpoint_ms" not in e
+        assert e["gc_ms"] <= e["step_ms"]
+        tiled = e["data_wait_ms"] + sum(e[k] for k in phases)
+        assert e["step_ms"] - 2.0 <= tiled <= e["step_ms"] + 0.01
     with pytest.raises(ValueError, match="LOOP_SPANS"):
         timer.span("train.lunch")
 
@@ -273,6 +296,107 @@ def test_step_timer_disabled_makes_no_annotation(monkeypatch):
             pass
         timer.dispatched()
     assert i == 4 and timer.total_steps == 0
+
+
+def test_step_timer_disabled_registers_no_collector_hook(monkeypatch):
+    """With the null sink no ``gc.callbacks`` hook is registered, even for
+    the new phases' names and a collection inside the loop: zero
+    annotations, and ``close()`` has nothing to take away."""
+    import jax.profiler
+
+    def boom(*a, **k):
+        raise AssertionError("an annotation was created with obs off")
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", boom)
+    before = list(gc.callbacks)
+    timer = StepTimer(NullEventLog())
+    for _ in timer.iterate(0, [{"x": 1}, {"x": 2}]):
+        with timer.span("train.observe"), timer.span("train.snapshot"):
+            gc.collect(1)
+        assert gc.callbacks == before
+    timer.close()
+    assert gc.callbacks == before
+
+
+def test_step_timer_collector_hook_lives_with_the_loop(tmp_path):
+    """An enabled timer registers ONE hook while an epoch's ``iterate``
+    runs (set-up and the epoch's end pay nothing); ``close()`` removes a
+    hook that an error left behind. A young collection counts into
+    ``gc_ms`` without an annotation, an older one with one."""
+    from mx_rcnn_tpu.obs.timing import GC_SPAN
+
+    log = open_event_log(str(tmp_path))
+    timer = StepTimer(log)
+    before = list(gc.callbacks)
+    for epoch in range(2):
+        for _ in timer.iterate(epoch, [{"x": 1}]):
+            assert len(gc.callbacks) == len(before) + 1
+            with timer.span("train.snapshot"):
+                gc.collect(0)
+        assert gc.callbacks == before
+    left = timer.iterate(2, [{"x": 1}, {"x": 2}])
+    next(left)                      # an error leaves the loop here
+    healed = timer.iterate(2, [{"x": 3}])
+    next(healed)                    # the next session's loop begins
+    assert len(gc.callbacks) == len(before) + 2
+    timer.close()
+    assert gc.callbacks == before
+    left.close(), healed.close()    # collected later: nothing to remove
+    timer.close()
+    assert gc.callbacks == before
+    log.close()
+    steps = [e for e in report.load_events(str(tmp_path))
+             if e["type"] == "step"]
+    assert len(steps) >= 2 and all(e["gc_ms"] > 0 for e in steps[:2])
+    spent = {}
+    from mx_rcnn_tpu.obs.timing import _Collector
+
+    hook = _Collector(spent)
+    hook("start", {"generation": 0})
+    assert hook.span is None
+    hook("stop", {"generation": 0})
+    hook("start", {"generation": 2})
+    assert hook.span is not None and hook.span.name == GC_SPAN
+    hook("stop", {"generation": 2})
+    assert hook.span is None and spent[GC_SPAN] > 0
+
+
+def test_trace_controller_unarmed_takes_no_lock(tmp_path):
+    """Nothing armed and no window open: ``before_step`` and
+    ``step_completed`` are one compare each and never touch the lock the
+    watchdog's thread shares."""
+    from mx_rcnn_tpu.obs.profile import TraceController
+
+    class NoLock:
+        def __enter__(self):
+            raise AssertionError("the lock was taken")
+
+        def __exit__(self, *exc):
+            return False
+
+    log = open_event_log(str(tmp_path))
+    tc = TraceController(log, str(tmp_path / "trace"), trace_at_step=5,
+                         trace_steps=1)
+    real, tc._lock = tc._lock, NoLock()
+    try:
+        for step in range(1, 5):
+            tc.before_step(step)      # armed for step 5: not yet
+            tc.step_completed(step)   # no window open
+        tc._lock = real
+        tc.before_step(5)             # opens the armed window, locked
+        assert tc._active_dir is not None
+        tc.step_completed(5)
+        assert tc._active_dir is None and tc._arm_at == 0
+        tc._lock = NoLock()
+        tc.before_step(6)
+        tc.step_completed(6)
+    finally:
+        tc._lock = real
+        tc.close()
+        log.close()
+    traces = [e for e in report.load_events(str(tmp_path))
+              if e["type"] == "trace"]
+    assert len(traces) == 1 and traces[0]["reason"] == "step 5"
 
 
 # ---------------------------------------------------------------------------
@@ -910,8 +1034,13 @@ def test_fit_detector_obs_enabled_and_report(tmp_path):
     for e in timed:
         assert e["data_wait_ms"] >= 0 and e["step_ms"] > 0
         assert "dispatch_ms" in e
+        # every phase of the loop body, each iteration (obs/timing.py)
+        assert {"key_ms", "place_ms", "observe_ms", "enqueue_ms",
+                "metrics_ms", "snapshot_ms"} <= set(e)
         assert e["canvas"] == [128, 128]
         assert e["pad_waste"] == 0.0  # 128px content on a 128 canvas
+    # a collection met somewhere in the first dispatch's tracing
+    assert "gc_ms" in timed[0]
     epochs = [e for e in events if e["type"] == "epoch"]
     assert epochs[0]["epoch"] == 0
     assert "TotalLoss" in epochs[0]["metrics"]
