@@ -56,6 +56,9 @@ def check_spec(cfg, spec: dict):
     for k, have in pairs:
         if manifest.tuples(spec[k]) != have:
             bad.append((k, spec[k], have))
+    if optimizer_of(spec["train"]) != cfg.train.optimizer:
+        bad.append(("train.optimizer", optimizer_of(spec["train"]),
+                    cfg.train.optimizer))
     if bad:
         raise SystemExit(f"configuration file and program disagree: {bad}")
 
@@ -78,17 +81,83 @@ def _flat(tree) -> dict:
     return {weights.path_of(p): np.asarray(v) for p, v in flat}
 
 
-def _trace_leaves(opt_state) -> dict:
-    """The momentum slots of the optimizer state, by parameter path."""
+def optimizer_of(train: dict) -> str:
+    """The configuration's optimizer, ``spec.train.optimizer``: ``sgd``
+    where the file names none."""
+    return train.get("optimizer", "sgd")
+
+
+# the slot of the state that holds the first gradient after one step
+SLOT = {"sgd": "trace", "adamw": "mu"}
+
+
+def _slot_leaves(opt_state, slot: str) -> dict:
+    """One slot of the optimizer state, by parameter path."""
     import jax
 
     flat, _ = jax.tree_util.tree_flatten_with_path(opt_state)
     out = {}
     for p, v in flat:
         keys = [str(getattr(k, "key", getattr(k, "name", k))) for k in p]
-        if "trace" in keys and "params" in keys:
+        if "params" in keys and slot in keys[:keys.index("params")]:
             out["/".join(keys[keys.index("params") + 1:])] = np.asarray(v)
     return out
+
+
+def program_b1(cfg) -> float:
+    """The first moment's decay of the program's own AdamW, read off one
+    update of ``build_optimizer(cfg)`` over a one-leaf tree: from a zero
+    state, ``mu = (1 - b1) g``. The gradient is a power of two under any
+    clip, so the quotient is the program's ``1 - b1`` to float32."""
+    import jax.numpy as jnp
+
+    from mx_rcnn_tpu.train.optimizer import build_optimizer
+
+    g = 2.0 ** -20
+    params = {"params": {"first_moment_probe": jnp.zeros((1,), jnp.float32)}}
+    tx = build_optimizer(cfg, params)
+    _, state = tx.update({"params": {"first_moment_probe": jnp.full(
+        (1,), g, jnp.float32)}}, tx.init(params), params)
+    mu = _slot_leaves(state, SLOT["adamw"])["first_moment_probe"]
+    return 1.0 - float(mu[0]) / g
+
+
+def first_gradient(opt_state, w0: dict, spec: dict, b1=None) -> dict:
+    """The first gradient as the optimizer got it (after its clip), by
+    parameter path, read from its state after the first step from a zero
+    state, whichever optimizer the configuration names:
+
+    - ``sgd`` (``optax.clip``, ``add_decayed_weights``, momentum): the
+      momentum trace is the clipped gradient plus the coupled decay, so
+      ``trace - wd * w0``;
+    - ``adamw`` (``clip_by_global_norm``, then ``adamw``): the first moment
+      is ``(1 - b1)`` times the clipped gradient; the decay is decoupled and
+      never enters it. ``b1`` is the program's (``program_b1``).
+    """
+    kind = optimizer_of(spec["train"])
+    slots = _slot_leaves(opt_state, SLOT[kind])
+    if not slots:
+        raise RuntimeError(f"no {SLOT[kind]!r} slot in the optimizer state: "
+                           f"not the {kind} the configuration names")
+    if kind == "adamw":
+        return {p: np.asarray(m, np.float32) / (1.0 - b1)
+                for p, m in slots.items()}
+    wd = spec["train"]["wd"]
+    return {p: t - wd * np.asarray(w0[p]) for p, t in slots.items()}
+
+
+def clip_like(grads: dict, train: dict) -> dict:
+    """A gradient clipped as the configuration's optimizer clips it:
+    elementwise for ``sgd`` (``optax.clip``), by the global norm of the
+    trainable leaves for ``adamw`` (``optax.clip_by_global_norm``)."""
+    to = train["clip_gradient"]
+    if optimizer_of(train) == "adamw":
+        norm = np.float32(np.sqrt(sum(np.sum(np.square(v, dtype=np.float64))
+                                      for v in grads.values())))
+        if norm < to:
+            return dict(grads)
+        return {k: v / norm * np.float32(to) for k, v in grads.items()}
+    return {k: np.clip(v, -to, to) for k, v in grads.items()}
 
 
 def identify(batch: dict, roidb: list):
@@ -145,8 +214,9 @@ def reference_batch(ref, rows, roidb, spec):
 def follow(ref, spec, seed, prog_seed, batches, precision="f32",
            rows=None, frozen_state=False):
     """The reference through the checked steps. Returns per-step losses, the
-    first gradient as the optimizer gets it (clipped), the parameters'
-    change after the last step, and the reference's raw first gradient."""
+    first gradient as the optimizer gets it (clipped as the configuration's
+    optimizer clips), the parameters' change after the last step, and the
+    reference's raw first gradient."""
     import jax
 
     params = weights.make(seed, ref.param_shapes(spec))
@@ -154,14 +224,13 @@ def follow(ref, spec, seed, prog_seed, batches, precision="f32",
     start = {k: np.asarray(v) for k, v in trainer.train.items()}
     root = jax.random.PRNGKey(prog_seed + 1)
     losses, first, raw = [], None, None
-    clip_to = spec["train"]["clip_gradient"]
     for e, batch in enumerate(batches):
         key = jax.random.fold_in(root, e * window.EPOCH_LEN)
         loss, _, grads = trainer.grads(batch, key, rows=rows)
         losses.append(loss)
         if first is None:
             raw = {k: np.asarray(v) for k, v in grads.items()}
-            first = {k: np.clip(v, -clip_to, clip_to) for k, v in raw.items()}
+            first = clip_like(raw, spec["train"])
         if not frozen_state:
             trainer.update(grads)
     change = {k: np.asarray(v) - start[k] for k, v in trainer.train.items()}
@@ -273,7 +342,7 @@ def run(ctx: dict) -> dict:
         first["loss"].append(float(bag.get()["TotalLoss"]))
         mark(f"checked_step_{epoch + 1}")
         if epoch == 0:
-            first["trace1"] = _trace_leaves(state.opt_state)
+            first["state1"] = jax.device_get(state.opt_state)
         if epoch == k - 1:
             first["params"] = _flat(state.params)
 
@@ -295,12 +364,12 @@ def run(ctx: dict) -> dict:
 
     # what the timed path produced in its first steps, against the reference
     w0 = weights.make(seed, ref.param_shapes(spec))
-    wd = spec["train"]["wd"]
-    prog = {"loss": first["loss"],
-            "grad1": {p: t - wd * np.asarray(w0[p])
-                      for p, t in first["trace1"].items()},
+    b1 = (program_b1(cfg) if optimizer_of(spec["train"]) == "adamw"
+          else None)
+    grad1 = first_gradient(first.pop("state1"), w0, spec, b1)
+    prog = {"loss": first["loss"], "grad1": grad1,
             "change": {p: first["params"][p] - np.asarray(w0[p])
-                       for p in first["trace1"]}}
+                       for p in grad1}}
     del w0
     rows = [identify(b, raw) for b in w.first_batches]
     batches = [reference_batch(ref, r, raw, spec) for r in rows]

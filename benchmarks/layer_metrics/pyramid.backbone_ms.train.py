@@ -1,6 +1,6 @@
 """Device ms per step of the ops scoped ``backbone`` and ``neck``, forward and
-backward: the pyramid cell's copy of ``stage.backbone_ms.train``, whose
-``workloads`` tests/benchmarks/test_bm_trace_scopes.py pins to C4's two cells."""
+backward: the pyramid cells' copy of ``stage.backbone_ms.train``, which C4's
+cells read."""
 from benchmarks import trace_scopes
 
 

@@ -1,6 +1,5 @@
 """Device ms per step of ``roi_sample`` + ``box_head`` + ``rcnn_loss``: the
-pyramid cell's copy of ``stage.box_head_ms.train``, whose ``workloads``
-tests/benchmarks/test_bm_trace_scopes.py pins to C4's two cells."""
+pyramid cells' copy of ``stage.box_head_ms.train``, which C4's cells read."""
 from benchmarks import trace_scopes
 
 

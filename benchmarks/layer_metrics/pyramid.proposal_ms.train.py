@@ -1,6 +1,6 @@
 """Device ms per step of the ``proposal`` stage (decode, top-k, the NMS
-launches): the pyramid cell's copy of ``stage.proposal_ms.train``, whose
-``workloads`` tests/benchmarks/test_bm_trace_scopes.py pins to C4's two cells."""
+launches): the pyramid cells' copy of ``stage.proposal_ms.train``, which C4's
+cells read."""
 from benchmarks import trace_scopes
 
 

@@ -1,6 +1,5 @@
 """Device ms per step of ``rpn_head`` + ``rpn_targets`` + ``rpn_loss``: the
-pyramid cell's copy of ``stage.rpn_ms.train``, whose ``workloads``
-tests/benchmarks/test_bm_trace_scopes.py pins to C4's two cells."""
+pyramid cells' copy of ``stage.rpn_ms.train``, which C4's cells read."""
 from benchmarks import trace_scopes
 
 

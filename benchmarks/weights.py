@@ -14,7 +14,14 @@ BN); what the offline sandbox cannot load is assumed, and listed under
 - frozen BN: identity statistics, except that ``bn0`` carries the pixels'
   variance (64**2), as a pretrained stem does, and each block's last BN
   (``bn3``) has gamma 0.25, so that activations stay O(1..10) through the 33
-  residual blocks in bfloat16.
+  residual blocks in bfloat16;
+- the transformer trees' leaves, each at its published initializer:
+  ``scale`` (LayerNorm, and GroupNorm, which flax names alike) 1.0;
+  ``pos_embed`` normal with std 0.02 (ViT's absolute positions, as
+  ``models/vit.py`` initializes them); ``rel_pos_h`` / ``rel_pos_w`` 0
+  (ViTDet's decomposed relative positions, ``rel_pos_zero_init``, Li et al.
+  2022); ``query_embed`` normal with std 1.0 (DETR's object queries, a
+  ``torch.nn.Embedding``, Carion et al. 2020).
 """
 
 from __future__ import annotations
@@ -26,6 +33,15 @@ import jax.numpy as jnp
 
 HEAD_STD = {"rpn_cls_score": 0.01, "rpn_bbox_pred": 0.01,
             "cls_score": 0.01, "bbox_pred": 0.001}
+# leaves drawn from a normal of a fixed std, by leaf name
+NORMAL_STD = {"pos_embed": 0.02, "query_embed": 1.0}
+# leaves of a constant value, by leaf name
+CONSTANT = {"scale": 1.0, "rel_pos_h": 0.0, "rel_pos_w": 0.0}
+
+
+def _normal(key, path: str, shape, std: float):
+    k = jax.random.fold_in(key, zlib.crc32(path.encode()) & 0x7FFFFFFF)
+    return std * jax.random.normal(k, shape, jnp.float32)
 
 
 def seed_key(seed: int):
@@ -46,8 +62,11 @@ def _leaf(key, path: str, shape):
             for d in shape[:-1]:
                 fan_in *= d
             std = (2.0 / fan_in) ** 0.5
-        k = jax.random.fold_in(key, zlib.crc32(path.encode()) & 0x7FFFFFFF)
-        return std * jax.random.normal(k, shape, jnp.float32)
+        return _normal(key, path, shape, std)
+    if leaf in NORMAL_STD:
+        return _normal(key, path, shape, NORMAL_STD[leaf])
+    if leaf in CONSTANT:
+        return jnp.full(shape, CONSTANT[leaf], jnp.float32)
     if leaf == "gamma":
         return jnp.full(shape, 0.25 if module == "bn3" else 1.0, jnp.float32)
     if leaf == "moving_var":

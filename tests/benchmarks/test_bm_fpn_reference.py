@@ -99,10 +99,12 @@ def test_proposals_are_the_same_boxes_in_the_same_order(both):
 
 def test_roi_levels_and_pooled_features(both):
     """The reference's own sampled rois given to the program: Eq. 1 sends
-    each to the same level (integers: equal), and the program's dense
-    contraction over all four levels, mask-selected, gives what four gathered
-    taps a sample point on the one level give. 2e-4 of the largest pooled
-    value: tent weights against gathered taps, float32 both."""
+    each to the same level (integers: equal), and the program's pooling from
+    the four levels stacked in one canvas, each roi's weights laid at its own
+    level's rows and columns (at this size the dense form: one pair of
+    contractions), gives what four gathered taps a sample point on the one
+    level give. 2e-4 of the largest pooled value: tent weights against
+    gathered taps, float32 both."""
     fpn, spec = both["fpn"], both["spec"]
     rois = jnp.stack([p["sampled"] for p in both["parts"]])
     ok = jnp.stack([p["sampled_ok"] for p in both["parts"]])

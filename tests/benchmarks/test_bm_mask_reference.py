@@ -153,10 +153,12 @@ def test_the_branch_runs_over_the_same_rois_with_the_same_targets(both):
 
 
 def test_pooled_14x14_features(both):
-    """The reference's own foreground rois given to the program: its dense
-    contraction over all four levels, mask-selected, at 14x14 bins gives what
-    four gathered taps a sample point on the one level give. 2e-4 of the
-    largest pooled value: tent weights against gathered taps, float32 both."""
+    """The reference's own foreground rois given to the program: its pooling
+    from the four levels stacked in one canvas, each roi's weights laid at
+    its own level's rows and columns, at 14x14 bins (the dense form: one
+    pair of contractions), gives what four gathered taps a sample point on
+    the one level give. 2e-4 of the largest pooled value: tent weights
+    against gathered taps, float32 both."""
     fpn, spec, model = both["fpn"], both["spec"], both["model"]
     rois = jnp.stack([p["mask_rois"] for p in both["parts"]])
     live = jnp.stack([p["mask_live"] for p in both["parts"]])

@@ -129,11 +129,23 @@ def test_silent_where_no_taps_kernel_ran(case):
         assert read(_run(FPN, dense)) is None
 
 
+def the_taps_metric_holds(bm):
+    """Listed for the two cells whose box pooling runs by taps, as the
+    kernels' roofline share of the model stages' layer."""
+    m, = [m for m in bm["per_layer"] if m["name"] == "roi_align_roofline.taps"]
+    assert {"fpn_r101_train", "mask_r101_train"} <= set(m["workloads"])
+    assert not {"c4_r101_train", "c4_r101_train_dp4"} & set(m["workloads"])
+    assert (m["unit"], m["better"], m["source"], m["layer"], m["moves"]) == (
+        "%", "higher", "device_trace", "model stages", "train_img_per_s_chip")
+
+
+def test_the_manifest_lists_it_for_the_pyramid_cells():
+    the_taps_metric_holds(manifest.load())
+
+
 def test_the_reader_names_the_programs_kernels():
     """The reader matches the two kernels by the names the program gives
-    them. (It is not in ``BENCHMARK.json`` yet: an entry appended there
-    would end ``per_layer`` after PR 37's four loop metrics, which
-    ``test_bm_trace_idle.py`` pins as the last four; PERF.md section 7.)"""
+    them."""
     from mx_rcnn_tpu.ops import roi_align_pallas
 
     assert _reader().KERNELS == (roi_align_pallas.KERNEL_NAME,

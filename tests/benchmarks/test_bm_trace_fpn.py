@@ -128,11 +128,13 @@ def test_silent_where_there_is_nothing_to_read(name, traced):
                                                    modules)})) is None
 
 
-def test_the_manifest_lists_the_new_metrics_for_this_cell_only():
-    bm = manifest.load()
+def the_pyramid_metrics_hold(bm):
+    """Each of the cell's metrics lists it, whatever other cells a list
+    holds (the mask cell, since it runs the same pyramid; a cell appended
+    later); the metrics of C4's step and of the mesh still leave it out."""
     by_name = {m["name"]: m for m in bm["per_layer"]}
     for name in NEW:
-        assert by_name[name]["workloads"] == ["fpn_r101_train"]
+        assert "fpn_r101_train" in by_name[name]["workloads"]
         assert by_name[name]["moves"] == "train_img_per_s_chip"
     for name in ("step.mfu.train", "nms_roofline",
                  "allreduce.exposed_ms.train"):
@@ -140,7 +142,11 @@ def test_the_manifest_lists_the_new_metrics_for_this_cell_only():
     listed = {m["name"] for m in manifest.metrics_of(bm, "per_layer",
                                                      "fpn_r101_train")}
     assert set(NEW) <= listed and "step.device_ms.train" in listed
-    # pinned to C4's cells by tests/benchmarks/test_bm_trace_scopes.py
+    # the cell reads the stages by its own copies, the pyramid.* readers
     assert not {n for n in listed if n.startswith("stage.")}
     assert {m["layer"] for m in bm["per_layer"] if m["name"] in NEW} <= {
         m["layer"] for m in bm["per_layer"] if m["name"] not in NEW}
+
+
+def test_the_manifest_lists_the_new_metrics_for_this_cell_only():
+    the_pyramid_metrics_hold(manifest.load())

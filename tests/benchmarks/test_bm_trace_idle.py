@@ -188,14 +188,20 @@ def test_the_copies_are_the_programs_names():
     assert not ts.HARNESS_SPAN.match(timing.GC_SPAN)
 
 
-def test_the_manifest_lists_the_four_for_every_cell():
-    cells = [w["name"] for w in BM["workloads"]]
-    got = {m["name"]: m for m in BM["per_layer"] if m["name"] in NAMES}
+def the_four_hold(bm):
+    """The four in their order among ``per_layer``, wherever they stand in
+    it, each listing every cell (``test_bm_manifest_room.py`` holds a
+    manifest with a cell and a metric appended to it)."""
+    cells = [w["name"] for w in bm["workloads"]]
+    got = {m["name"]: m for m in bm["per_layer"] if m["name"] in NAMES}
     assert list(got) == list(NAMES)
-    assert [m["name"] for m in BM["per_layer"][-4:]] == list(NAMES)
     for m in got.values():
         assert m["workloads"] == cells and m["layer"] == "train loop"
         assert m["moves"] == "train_img_per_s_chip"
         assert m["source"] == "program_span" and m["better"] == "lower"
         assert not m["name"].startswith(("stage.", "loop.place",
                                          "loop.enqueue", "loop.host_bound"))
+
+
+def test_the_manifest_lists_the_four_for_every_cell():
+    the_four_hold(BM)

@@ -140,6 +140,68 @@ def test_silent_where_there_is_nothing_to_read(name, parts):
     assert read(run) is None    # no xplane file under the run's directory
 
 
+def _detector_part(at):
+    """What a mask step runs besides the fixture's ops, 4.5 ms laid in the
+    idle after each of its steps: the neck 0.5 ms, the RPN head 0.5, the
+    proposal stage's five per-level NMS launches 0.2 each, and the box
+    pooling's two taps kernels, 1 ms forward and 1.5 backward."""
+    call = 'custom-call(%a), custom_call_target="tpu_custom_call"'
+    nms = "jit(step)/jvp(proposal)/shard_map/nms_sweep/pallas_call"
+    half = MS // 2
+    return [
+        ("%fusion.30 = bf16[2,64,96,256] x", at, half,
+         "jit(step)/jvp(FPNFasterRCNN.extract)/neck/neck/lateral2/conv"),
+        ("%fusion.31 = bf16[2,64,96,256] x", at + half, half,
+         "jit(step)/jvp(rpn_head)/conv"),
+        *[(f"%nms_sweep.{i} = f32[8,1,{w}]{{2,1,0}} {call}",
+           at + MS + i * MS // 5, MS // 5, nms)
+          for i, w in enumerate([2048] * 4 + [896])],
+        (f"%roi_align_taps.1 = bf16[8,512,7,7,256]{{4,3,2,1,0}} {call}",
+         at + 2 * MS, MS, "jit(step)/jvp(roi_align)/pallas_call"),
+        (f"%roi_align_taps_grad.1 = bf16[8,104832,256]{{2,1,0}} {call}",
+         at + 3 * MS, 3 * half,
+         "jit(step)/transpose(jvp(roi_align))/pallas_call")]
+
+
+# a step of the fixture with its detector part: each pyramid reader the
+# mask cell lists, and what it reads
+PYRAMID = {"pyramid.backbone_ms.train": 20.5, "pyramid.neck_ms.train": 0.5,
+           "pyramid.rpn_ms.train": 0.5, "pyramid.proposal_ms.train": 1.0,
+           "pyramid.roi_align_ms.train": 12.0 + 2.5,
+           "pyramid.box_head_ms.train": 2.0,
+           "proposal.nms_kernel_ms.train": 1.0,
+           "nms_roofline.per_level": 100 * 16 * (4 * 2000 ** 2 + 819 ** 2)
+           / 197e12 * 8 * 2 / 2e-3,
+           "roi_align_roofline.taps": 100 * 2 * 1_064_304_640 / 819e9 / 5e-3}
+
+
+@pytest.mark.parametrize("name,want", sorted(PYRAMID.items()))
+def test_the_pyramid_readers_read_the_mask_cell(name, want, parts):
+    """On the fixture as it is, the readers that have something there read
+    it (``pyramid.roi_align_ms.train`` is both poolings: the box head's 5 ms
+    and the branch's 7); with the detector part a mask step runs besides,
+    each reads its number, and none is None."""
+    dev, host, modules = parts
+    as_is = _reader(name).read(_run(dev, host, modules))
+    if name in ("pyramid.neck_ms.train", "proposal.nms_kernel_ms.train",
+                "nms_roofline.per_level", "roi_align_roofline.taps"):
+        assert as_is is None        # the fixture has no such op
+    else:
+        assert as_is == pytest.approx(want - {
+            "pyramid.backbone_ms.train": 0.5, "pyramid.rpn_ms.train": 0.5,
+            "pyramid.proposal_ms.train": 1.0,
+            "pyramid.roi_align_ms.train": 2.5}.get(name, 0.0))
+    (d, evs), = dev.items()
+    whole = {d: sorted(evs + _detector_part(54 * MS)
+                       + _detector_part(115 * MS), key=lambda e: e[1])}
+    steps = {d: [("jit_step(1)", 0, 60 * MS), ("jit_step(1)", 60 * MS,
+                                               60 * MS)]}
+    got = _reader(name).read(_run(whole, host, steps))
+    assert got is not None and got == pytest.approx(want)
+    assert name in {m["name"] for m in manifest.metrics_of(
+        manifest.load(), "per_layer", CELL)}
+
+
 def test_four_readers_parse_the_trace_once(monkeypatch, parts, tmp_path):
     """The fold is cached on the run under a key of its own, beside
     ``trace_scopes``' and not in its place."""
@@ -158,7 +220,10 @@ def test_four_readers_parse_the_trace_once(monkeypatch, parts, tmp_path):
 def test_the_manifest_lists_the_cells_metrics():
     """Only what belongs to this cell: a later PR may append cells,
     configurations and workloads to any list without an edit here."""
-    bm = manifest.load()
+    the_mask_metrics_hold(manifest.load())
+
+
+def the_mask_metrics_hold(bm):
     by_name = {m["name"]: m for m in bm["per_layer"]}
     for name in NEW:
         assert CELL in by_name[name]["workloads"]
@@ -166,7 +231,11 @@ def test_the_manifest_lists_the_cells_metrics():
     assert [by_name[n]["source"] for n in NEW] == ["device_trace"] * 4 + [
         "host_clock"]
     listed = {m["name"] for m in manifest.metrics_of(bm, "per_layer", CELL)}
-    assert set(NEW) | set(APPENDED) <= listed
+    assert set(NEW) | set(APPENDED) | set(PYRAMID) <= listed
+    # the unscoped share would count the branch's head and loss as
+    # unscoped, and the pyramid's MFU leaves the branch's work out
+    assert not {"pyramid.unscoped_share.train",
+                "step.mfu.train.pyramid"} & listed
     assert {m["layer"] for m in bm["per_layer"] if m["name"] in NEW} <= {
         m["layer"] for m in bm["per_layer"] if m["name"] not in NEW}
     e2e = {e["name"]: e for e in bm["end_to_end"]}
