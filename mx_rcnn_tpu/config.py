@@ -40,6 +40,8 @@ class NetworkConfig:
     # Backbone normalization: "frozen_bn" (reference parity — REQUIRES
     # pretrained statistics to be restored) or "group" (GroupNorm; the
     # stable choice for from-scratch training, see models/backbones.py).
+    # On ViTDet, whose trunk is LayerNorm'd by construction, the norm of
+    # the pyramid's heads: "layer" (the recipe's LN), or "group".
     norm: str = "frozen_bn"
     # Stop-gradient freeze cut: 0 = none, 1 = stem, 2 = stem+stage1
     # (reference fixed_param_prefix default). Use 0 when training from
@@ -61,6 +63,13 @@ class NetworkConfig:
     use_mask: bool = False
     mask_pool_size: int = 14
     mask_resolution: int = 28
+    # ViTDet's pyramid heads, where its recipe departs from the FPN
+    # paper's (the FPN presets' heads are the paper's and read neither):
+    # the RPN head's 3x3 convolutions (2), and the box head's 3x3
+    # convolutions before ONE fully connected layer (4conv1fc: 4; 0 = the
+    # two-FC head).
+    rpn_convs: int = 1
+    box_head_convs: int = 0
     # ViTDet (stretch config; models/vit.py).
     use_vit: bool = False
     vit_patch: int = 16
@@ -68,6 +77,9 @@ class NetworkConfig:
     vit_depth: int = 12
     vit_heads: int = 12
     vit_window: int = 8  # local-attention window (tokens per side)
+    # Stochastic depth: the drop rate rises linearly from 0 at the first
+    # block to this at the last (per image, each residual branch).
+    vit_drop_path: float = 0.0
     # Sequence-parallel attention for the global blocks (long context,
     # ops/ring_attention.py); needs a mesh at model build time.
     # use_ring_attention selects the ppermute ring; sp_mode overrides the
@@ -156,6 +168,12 @@ class TrainConfig:
     momentum: float = 0.9
     wd: float = 0.0005
     clip_gradient: float = 5.0
+    # AdamW only: layer-wise learning-rate decay (ViTDet's
+    # get_vit_lr_decay_rate; 1.0 = none) and the leaves weight decay
+    # skips ("norm": every LayerNorm/GroupNorm leaf; else a leaf name
+    # such as "pos_embed"). train/optimizer.py.
+    layer_decay: float = 1.0
+    no_decay: tuple = ()
     # "sgd" (reference parity: SGD+momentum, elementwise clip) or "adamw"
     # (the transformer families — DETR/ViTDet train with AdamW + global-
     # norm clip per their papers; SGD barely converges there).
@@ -593,18 +611,17 @@ _NETWORK_PRESETS: Mapping[str, Mapping[str, Any]] = {
         name="resnet101_fpn_mask", depth=101, use_fpn=True, roi_pool_size=7,
         anchor_scales=(8,), use_mask=True, proposal_topk="approx",
     ),
-    "vitdet_b": dict(
-        name="vitdet_b", use_vit=True, roi_pool_size=7, anchor_scales=(8,),
-        vit_dim=768, vit_depth=12, vit_heads=12, vit_window=8,
-        norm="group",  # detector-side norms; the ViT itself uses LayerNorm
-        proposal_topk="approx",
-    ),
-    "vitdet_b_mask": dict(
-        name="vitdet_b_mask", use_vit=True, roi_pool_size=7,
-        anchor_scales=(8,), use_mask=True,
-        vit_dim=768, vit_depth=12, vit_heads=12, vit_window=8,
-        norm="group", proposal_topk="approx",
-    ),
+    # ViTDet-B (Li et al. 2022; Detectron2 projects/ViTDet
+    # mask_rcnn_vitdet_b_100ep.py): window 14 with the four global
+    # blocks, decomposed relative positions, the LN pyramid, a two-conv
+    # RPN head, the 4conv1fc LN box head, the LN mask head, exact top-k.
+    **{name: dict(
+        name=name, use_vit=True, roi_pool_size=7, anchor_scales=(8,),
+        use_mask=name.endswith("_mask"),
+        vit_dim=768, vit_depth=12, vit_heads=12, vit_window=14,
+        vit_drop_path=0.1, norm="layer", rpn_convs=2, box_head_convs=4,
+        fixed_param_patterns=(), proposal_topk="exact",
+    ) for name in ("vitdet_b", "vitdet_b_mask")},
     "detr_r50": dict(name="detr_r50", depth=50, use_detr=True),
 }
 
@@ -615,19 +632,26 @@ _NETWORK_PRESETS: Mapping[str, Mapping[str, Any]] = {
 # loader transposes them for portrait batches and squares only the rare
 # mixed-orientation seam batch (loader.resolve_pad_bucket) — square-only
 # covers would waste ~60% of the conv FLOPs on landscape COCO batches.
+# ViTDet: the longest side scaled to 1024 in a 1024x1024 canvas (the
+# recipe's test-time ResizeShortestEdge(1024, max_size=1024); training's
+# large-scale jitter crops to the same canvas), MAE's ImageNet RGB
+# normalisation.
 _IMAGE_PRESETS: Mapping[str, Mapping[str, Any]] = {
-    name: dict(
+    **{name: dict(
         scales=((640, 1066), (800, 1333)),
         pad_shapes=((672, 1088), (832, 1344)),
         pad_shape=(1344, 1344),
-    )
-    for name in ("resnet50_fpn", "resnet101_fpn",
-                 "resnet50_fpn_mask", "resnet101_fpn_mask")
+    ) for name in ("resnet50_fpn", "resnet101_fpn",
+                   "resnet50_fpn_mask", "resnet101_fpn_mask")},
+    **{name: dict(scales=((1024, 1024),), pad_shape=(1024, 1024),
+                  pixel_means=(123.675, 116.28, 103.53),
+                  pixel_stds=(58.395, 57.12, 57.375))
+       for name in ("vitdet_b", "vitdet_b_mask")},
 }
 
 # Per-network TrainConfig presets: the transformer families train with
-# AdamW + global-norm clip 0.1 at transformer learning rates (Carion et
-# al. §4: AdamW 1e-4, clip 0.1; ViTDet likewise AdamW) — SGD+momentum at
+# AdamW at transformer learning rates (Carion et al. §4: AdamW 1e-4,
+# global-norm clip 0.1; ViTDet: AdamW, no clip) — SGD+momentum at
 # detector rates barely converges there. The LR schedules are the papers'
 # too (DETR: 300 epochs, ÷10 at 200; ViTDet: ~100 epochs, ÷10 at 88/96) —
 # inheriting the SGD default lr_step=(7,) would silently decimate the LR
@@ -635,8 +659,11 @@ _IMAGE_PRESETS: Mapping[str, Mapping[str, Any]] = {
 _TRAIN_PRESETS: Mapping[str, Mapping[str, Any]] = {
     "detr_r50": dict(optimizer="adamw", lr=1e-4, clip_gradient=0.1,
                      wd=1e-4, lr_step=(200,), end_epoch=300),
-    **{name: dict(optimizer="adamw", lr=1e-4, clip_gradient=0.1,
-                  wd=1e-4, lr_step=(88, 96), end_epoch=100)
+    # ViTDet: lr 1e-4 at the recipe's 64 images a step, weight decay 0.1
+    # off the norms and pos_embed, layer-wise decay 0.7, no clipping.
+    **{name: dict(optimizer="adamw", lr=1e-4, clip_gradient=float("inf"),
+                  wd=0.1, layer_decay=0.7, no_decay=("norm", "pos_embed"),
+                  lr_step=(88, 96), end_epoch=100)
        for name in ("vitdet_b", "vitdet_b_mask")},
 }
 

@@ -71,11 +71,15 @@ class FrozenBatchNorm(nn.Module):
 
 
 def make_norm(norm: str, features: int, dtype: Dtype, name: str):
-    """Norm-layer factory: "frozen_bn" (reference parity) or "group"."""
+    """Norm-layer factory: "frozen_bn" (reference parity), "group", or
+    "layer" (LayerNorm over the channels, eps 1e-6: ViTDet's heads)."""
     if norm == "frozen_bn":
         return FrozenBatchNorm(features, dtype=dtype, name=name)
     if norm == "group":
         return nn.GroupNorm(num_groups=min(32, features), dtype=dtype,
+                            param_dtype=jnp.float32, name=name)
+    if norm == "layer":
+        return nn.LayerNorm(epsilon=1e-6, dtype=dtype,
                             param_dtype=jnp.float32, name=name)
     raise ValueError(f"unknown norm {norm!r}")
 

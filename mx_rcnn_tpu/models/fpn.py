@@ -29,7 +29,7 @@ taps (pyramid_roi_align; ops/roi_align_pallas.py).
 from __future__ import annotations
 
 from functools import partial
-from typing import Any, Dict, List, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -37,7 +37,7 @@ import numpy as np
 from flax import linen as nn
 
 from mx_rcnn_tpu.config import Config
-from mx_rcnn_tpu.models.backbones import ResNetStages
+from mx_rcnn_tpu.models.backbones import ResNetStages, make_norm
 from mx_rcnn_tpu.models.faster_rcnn import _assign_anchors_batch
 from mx_rcnn_tpu.models.losses import rcnn_losses, rpn_losses
 from mx_rcnn_tpu.models.rpn import RPNHead
@@ -136,24 +136,55 @@ class TwoFCHead(nn.Module):
         return x
 
 
+class ConvFCHead(nn.Module):
+    """Box head of ``convs`` 3x3 convolutions, each normed (bias-free) and
+    ReLU'd, then ONE fully connected layer (Wu et al.'s 4conv1fc;
+    ViTDet's box head with ``norm="layer"``)."""
+
+    convs: int = 4
+    channels: int = 256
+    width: int = 1024
+    norm: str = "layer"
+    dtype: Dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, pooled: jnp.ndarray) -> jnp.ndarray:
+        x = pooled.astype(self.dtype)
+        for i in range(1, self.convs + 1):
+            x = nn.Conv(self.channels, (3, 3), padding=[(1, 1), (1, 1)],
+                        use_bias=False, dtype=self.dtype,
+                        param_dtype=jnp.float32, name=f"conv{i}")(x)
+            x = nn.relu(make_norm(self.norm, self.channels, self.dtype,
+                                  f"conv{i}_norm")(x))
+        x = x.reshape(x.shape[0], -1)
+        return nn.relu(nn.Dense(self.width, dtype=self.dtype,
+                                param_dtype=jnp.float32, name="fc1")(x))
+
+
 class MaskHead(nn.Module):
     """Mask branch (He et al., Mask R-CNN): 4x conv 3x3 → deconv x2 → 1x1.
 
     Input (R, 14, 14, 256) → per-class logits (R, 28, 28, num_classes).
+    With a ``norm`` (ViTDet: "layer") each 3x3 conv is bias-free and
+    normed before its ReLU.
     """
 
     num_classes: int = 81
     channels: int = 256
+    norm: Optional[str] = None
     dtype: Dtype = jnp.bfloat16
 
     @nn.compact
     def __call__(self, pooled: jnp.ndarray) -> jnp.ndarray:
         x = pooled.astype(self.dtype)
         for i in range(4):
-            x = nn.relu(nn.Conv(self.channels, (3, 3),
-                                padding=[(1, 1), (1, 1)], dtype=self.dtype,
-                                param_dtype=jnp.float32,
-                                name=f"mask_conv{i}")(x))
+            x = nn.Conv(self.channels, (3, 3), padding=[(1, 1), (1, 1)],
+                        use_bias=self.norm is None, dtype=self.dtype,
+                        param_dtype=jnp.float32, name=f"mask_conv{i}")(x)
+            if self.norm is not None:
+                x = make_norm(self.norm, self.channels, self.dtype,
+                              f"mask_conv{i}_norm")(x)
+            x = nn.relu(x)
         x = nn.relu(nn.ConvTranspose(self.channels, (2, 2), strides=(2, 2),
                                      dtype=self.dtype,
                                      param_dtype=jnp.float32,
@@ -184,6 +215,8 @@ class FPNFasterRCNN(nn.Module):
     freeze_at: int = 2
     dtype: Dtype = jnp.bfloat16
     remat: bool = False
+    # ROIAlign without the half-pixel shift (the FPN recipe's)
+    roi_aligned: bool = False
 
     def setup(self):
         self.features = ResNetStages(depth=self.depth,
@@ -303,7 +336,15 @@ def apply_rpn_head_packed(rpn_head, pyramid: Dict[int, jnp.ndarray]):
             "network.fpn_packed_rpn_head)")
     tensors = [pyramid[lv] for lv in RPN_LEVELS]
     canvas, places = pack_levels(tensors, gap=int(radius))
-    cls_c, box_c = rpn_head(canvas)
+    keep = None
+    if rpn_head.convs > 1:
+        # a second conv would read the first's output in the gaps
+        keep = np.zeros((1,) + canvas.shape[1:3] + (1,), np.float32)
+        for y, x, h, w in places:
+            keep[:, y:y + h, x:x + w] = 1.0
+        keep = jnp.asarray(keep)
+    cls_c, box_c = (rpn_head(canvas) if keep is None
+                    else rpn_head(canvas, keep))
     out = {}
     for lv, (y, x, h, w) in zip(RPN_LEVELS, places):
         out[lv] = (cls_c[:, y:y + h, x:x + w, :],
@@ -547,6 +588,7 @@ def pyramid_roi_align(
     pool_size: int,
     windows: jnp.ndarray = None,
     impl: str = "auto",
+    aligned: bool = False,
 ) -> jnp.ndarray:
     """(B, R, 4) rois → (B·R, P, P, C) pooled from each roi's FPN level.
 
@@ -570,7 +612,8 @@ def pyramid_roi_align(
     the ``roi_align`` scope. ``impl`` as ``ops/roi_align.py::pooling_impl``.
     The rois carry no gradient (proposals and ground truth, stopped here
     as upstream), so neither form gives the weights a cotangent.
-    The rois stay grouped by image (ops/roi_align.py).
+    The rois stay grouped by image (ops/roi_align.py). ``aligned``: the
+    half-pixel shift (Detectron2's ROIAlignV2; ViTDet's), in both forms.
 
     graftcanvas: on a packed batch the pyramid holds PLANES, I images each
     in row order (ops/canvas.py::rois_by_plane), and `windows` (B, 4)
@@ -590,15 +633,15 @@ def pyramid_roi_align(
             canvas.dtype))
         if impl != "xla":
             out = pool_taps_dispatch(canvas, _canvas_taps(
-                grouped, win, levels, live, places, pool_size, (hc, wc)),
-                impl)
+                grouped, win, levels, live, places, pool_size, (hc, wc),
+                aligned), impl)
             return out.reshape(b * r, *out.shape[2:])
         wy = wx = 0.0
         whole = ((0, 0),) * 3  # (B, R, P): only the map's axis is padded
         for lv, (y, x, h, w) in zip(ROI_LEVELS, places):
             ly, lx = roi_align_weights(grouped, (h, w), pool_size,
                                        1.0 / (2 ** lv), ROI_SAMPLES,
-                                       windows=win)
+                                       aligned, windows=win)
             on = ((levels == lv) & live)[..., None, None].astype(ly.dtype)
             wy = wy + jnp.pad(ly * on, whole + ((y, hc - y - h),))
             wx = wx + jnp.pad(lx * on, whole + ((x, wc - x - w),))
@@ -606,7 +649,8 @@ def pyramid_roi_align(
         return out.reshape(b * r, *out.shape[2:])
 
 
-def _canvas_taps(grouped, win, levels, live, places, pool_size, extent):
+def _canvas_taps(grouped, win, levels, live, places, pool_size, extent,
+                 aligned=False):
     """Each roi's taps against its Eq. 1 level, in canvas cells: the
     level's own ``roi_align_taps`` moved to its rows and columns of the
     canvas, weights zero for an invalid roi."""
@@ -614,7 +658,7 @@ def _canvas_taps(grouped, win, levels, live, places, pool_size, extent):
     for lv, (y, x, h, w) in zip(ROI_LEVELS, places):
         ya, wy, xa, wx = roi_align_taps(grouped, (h, w), pool_size,
                                         1.0 / (2 ** lv), ROI_SAMPLES,
-                                        windows=win)
+                                        aligned, windows=win)
         here = (ya + y, wy, xa + x, wx)
         at = levels == lv
         taps = here if taps is None else tuple(
@@ -631,8 +675,14 @@ def _canvas_taps(grouped, win, levels, live, places, pool_size, extent):
 
 
 def _pyramid_rpn(model: FPNFasterRCNN, params, images, cfg: Config,
-                 masks=None):
-    pyramid = model.apply(params, images, masks, method="extract")
+                 masks=None, drop_key=None):
+    """``drop_key``: the step's key of a trunk with stochastic depth
+    (ViTDet in training); None elsewhere."""
+    if drop_key is not None and getattr(model, "drop_path", 0.0) > 0.0:
+        pyramid = model.apply(params, images, masks, drop_key,
+                              method="extract")
+    else:
+        pyramid = model.apply(params, images, masks, method="extract")
     rpn_method = ("rpn_forward_packed" if cfg.network.fpn_packed_rpn_head
                   else "rpn_forward")
     with stage("rpn_head"):
@@ -693,7 +743,8 @@ def mask_branch(model, params, pyramid, samples, gt_boxes, gt_masks,
     live = (samples.valid & samples.fg_mask)[:, :n]
     with stage("mask_align"):
         pooled = pyramid_roi_align(pyramid, rois, live, model.mask_pool_size,
-                                   windows=windows)
+                                   windows=windows,
+                                   aligned=model.roi_aligned)
     with stage("mask_head"):
         logits = model.apply(params, pooled, method="mask_forward")
     m_res = logits.shape[1]
@@ -766,12 +817,12 @@ def forward_train(
         gt_valid, gt_masks = batch["gt_valid"], batch.get("gt_masks")
         b = images.shape[0]
 
+    k_anchor, k_sample, k_drop = jax.random.split(rng, 3)
     pyramid, rpn_out, anchors = _pyramid_rpn(model, params, images, cfg,
-                                             masks)
+                                             masks, k_drop)
     anchors_cat = jnp.asarray(
         np.concatenate([anchors[lv] for lv in RPN_LEVELS], axis=0))
 
-    k_anchor, k_sample, k_dummy = jax.random.split(rng, 3)
     rpn_t = _assign_anchors_batch(anchors_cat, gt_boxes, gt_valid, im_info,
                                   k_anchor, cfg)
 
@@ -815,7 +866,8 @@ def forward_train(
 
     r = cfg.train.batch_rois
     pooled = pyramid_roi_align(pyramid, samples.rois, samples.valid,
-                               model.roi_pool_size, windows=windows)
+                               model.roi_pool_size, windows=windows,
+                               aligned=model.roi_aligned)
     with stage("box_head"):
         cls_logits, bbox_deltas = model.apply(params, pooled,
                                               method="box_head")
@@ -897,7 +949,8 @@ def forward_test(
     rois, roi_valid, _ = fpn_proposals(rpn_out, anchors, im_info, cfg,
                                        train=False)
     b, r = rois.shape[0], rois.shape[1]
-    pooled = pyramid_roi_align(pyramid, rois, roi_valid, model.roi_pool_size)
+    pooled = pyramid_roi_align(pyramid, rois, roi_valid, model.roi_pool_size,
+                               aligned=model.roi_aligned)
     cls_logits, bbox_deltas = model.apply(params, pooled,
                                           method="box_head")
     scores = jax.nn.softmax(cls_logits, axis=-1).reshape(b, r, -1)
@@ -930,7 +983,7 @@ def forward_test_masks(
     pyramid = model.apply(params, images, method="extract")
     b, d = det_boxes.shape[0], det_boxes.shape[1]
     pooled = pyramid_roi_align(pyramid, det_boxes, det_valid,
-                               model.mask_pool_size)
+                               model.mask_pool_size, aligned=model.roi_aligned)
     logits = model.apply(params, pooled, method="mask_forward")
     m = logits.shape[1]
     cls_sel = jnp.maximum(det_classes.reshape(-1), 0)

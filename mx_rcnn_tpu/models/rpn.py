@@ -21,8 +21,9 @@ from mx_rcnn_tpu.train.precision import island
 
 
 class RPNHead(nn.Module):
-    #: Spatial receptive radius (px on the feature grid) of the head's
-    #: conv stack — one 3x3 conv reaches 1 px; the 1x1 siblings add 0.
+    #: Spatial receptive radius (px on the feature grid) of ONE of the
+    #: head's 3x3 convs; the 1x1 siblings add 0. Between two stacked convs
+    #: the caller's ``keep`` re-zeroes what is not the map.
     #: models/fpn.py::apply_rpn_head_packed sizes its inter-level canvas
     #: gap from this so activations cannot leak across packed levels; a
     #: deeper head MUST raise it (and gets a loud failure if the
@@ -31,15 +32,26 @@ class RPNHead(nn.Module):
 
     num_anchors: int = 9
     channels: int = 512
+    # stacked 3x3 convs, each with its ReLU (ViTDet's head has 2)
+    convs: int = 1
     dtype: Any = jnp.bfloat16
 
     @nn.compact
-    def __call__(self, feat: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    def __call__(self, feat: jnp.ndarray,
+                 keep=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
+        """``keep``: (1, H, W, 1) where the map is, re-applied between two
+        convs (a packed canvas's gap cells read zero, as a lone level's
+        SAME padding does); unused with one conv."""
         a = self.num_anchors
-        x = nn.Conv(self.channels, (3, 3), padding=[(1, 1), (1, 1)],
-                    dtype=self.dtype, param_dtype=jnp.float32,
-                    name="rpn_conv")(feat.astype(self.dtype))
-        x = nn.relu(x)
+        x = feat.astype(self.dtype)
+        for i in range(self.convs):
+            if i and keep is not None:
+                x = x * keep.astype(x.dtype)
+            x = nn.relu(nn.Conv(self.channels, (3, 3),
+                                padding=[(1, 1), (1, 1)], dtype=self.dtype,
+                                param_dtype=jnp.float32,
+                                name="rpn_conv" + (str(i + 1) if i else ""))(
+                                    x))
         cls_logits = nn.Conv(2 * a, (1, 1), dtype=self.dtype,
                              param_dtype=jnp.float32,
                              kernel_init=nn.initializers.normal(0.01),

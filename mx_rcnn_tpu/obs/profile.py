@@ -40,8 +40,8 @@ import threading
 from typing import Any, Dict, Optional
 
 #: The step program's stages, in program order: the one closed list of
-#: ``jax.named_scope`` names (``stage`` refuses any other). FPN alone has a
-#: ``neck``; ViTDet's trunk and DETR carry none yet.
+#: ``jax.named_scope`` names (``stage`` refuses any other). FPN and ViTDet
+#: have a ``neck``; DETR carries none yet.
 STAGES = ("backbone", "neck", "rpn_head", "rpn_targets", "rpn_loss",
           "proposal", "roi_sample", "roi_align", "box_head", "rcnn_loss",
           "update")
@@ -61,14 +61,27 @@ BRANCH_STAGES = ("mask_align", "mask_head", "mask_targets", "mask_loss")
 _BRANCH_RX = re.compile(
     r"(?<![\w.\-])(" + "|".join(BRANCH_STAGES) + r")(?![\w.\-])")
 
+#: The attention trunk's scopes (models/vit.py), nested inside
+#: ``backbone``: a block's windowed attention (norm1 through the window
+#: unpartition), its global attention (norm1 through proj) and its MLP
+#: (norm2 through mlp2). A third closed list, beside the other two and part
+#: of neither, so ``stage_of`` still sees the whole trunk as ``backbone``;
+#: ``vit_of`` reads them.
+VIT_STAGES = ("window_attn", "global_attn", "vit_mlp")
+
+_VIT_RX = re.compile(
+    r"(?<![\w.\-])(" + "|".join(VIT_STAGES) + r")(?![\w.\-])")
+
 
 def stage(name: str):
-    """``jax.named_scope`` for one of ``STAGES`` or ``BRANCH_STAGES``:
-    trace-time metadata on the ops traced under it, forward and (through
-    ``jvp``/``transpose``) backward. Changes no instruction."""
-    if name not in STAGES and name not in BRANCH_STAGES:
-        raise ValueError(f"{name!r} is not one of STAGES {STAGES} or "
-                         f"BRANCH_STAGES {BRANCH_STAGES}")
+    """``jax.named_scope`` for one of ``STAGES``, ``BRANCH_STAGES`` or
+    ``VIT_STAGES``: trace-time metadata on the ops traced under it,
+    forward and (through ``jvp``/``transpose``) backward. Changes no
+    instruction."""
+    if name not in STAGES + BRANCH_STAGES + VIT_STAGES:
+        raise ValueError(f"{name!r} is not one of STAGES {STAGES}, "
+                         f"BRANCH_STAGES {BRANCH_STAGES} or VIT_STAGES "
+                         f"{VIT_STAGES}")
     import jax
 
     return jax.named_scope(name)
@@ -84,6 +97,12 @@ def branch_of(path: str) -> Optional[str]:
     """The innermost of ``BRANCH_STAGES`` in an op's scope path, or None:
     asked beside ``stage_of``, not in its place."""
     hits = _BRANCH_RX.findall(path)
+    return hits[-1] if hits else None
+
+
+def vit_of(path: str) -> Optional[str]:
+    """The innermost of ``VIT_STAGES`` in an op's scope path, or None."""
+    hits = _VIT_RX.findall(path)
     return hits[-1] if hits else None
 
 

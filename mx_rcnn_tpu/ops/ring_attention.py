@@ -29,6 +29,12 @@ over all P key/value blocks, permuting (k, v) one step around the ring per
 iteration. The final output acc / l is bitwise-independent of the block
 order up to float addition reordering, so it matches dense softmax
 attention to numerical tolerance (tests/test_ring_attention.py).
+
+Every form takes an optional DECOMPOSED bias ``rel = (rel_h, rel_w)``, the
+relative-position bias of ViTDet's blocks (models/vit.py): (..., h, S_q,
+gh) and (..., h, S_q, gw) float32 for keys on a row-major (gh, gw) grid,
+so the score of query q and key kh·gw + kw gains rel_h[.., q, kh] +
+rel_w[.., q, kw]. Each form adds it to the scores of the keys it holds.
 """
 
 from __future__ import annotations
@@ -43,17 +49,39 @@ from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def _block_attn_update(carry, kv, q, scale, key_mask=None):
+def _rel_of_keys(rel, keys):
+    """The decomposed bias of the keys at flat grid positions ``keys``
+    (block,): (..., h, q, block). Positions past the grid (a padded tail)
+    read its last row; the caller masks them."""
+    rel_h, rel_w = rel
+    gw = rel_w.shape[-1]
+    return (jnp.take(rel_h, keys // gw, axis=-1, mode="clip")
+            + jnp.take(rel_w, keys % gw, axis=-1, mode="clip"))
+
+
+def _with_rel(s, rel):
+    """(..., h, q, S) scores over every key of the grid plus the
+    decomposed bias."""
+    rel_h, rel_w = rel
+    shape = s.shape
+    s = s.reshape(shape[:-1] + (rel_h.shape[-1], rel_w.shape[-1]))
+    return (s + rel_h[..., :, None] + rel_w[..., None, :]).reshape(shape)
+
+
+def _block_attn_update(carry, kv, q, scale, key_mask=None, bias=None):
     """One streaming-softmax update with a (k, v) block.
 
     key_mask: optional (block,) bool — False keys are excluded (their
     scores forced to −inf before the max/exp), used for the padded tail
-    block of streaming_attention.
+    block of streaming_attention. bias: optional (..., h, q, block) added
+    to the block's scores.
     """
     acc, m, l = carry
     k, v = kv
     s = jnp.einsum("...qhd,...khd->...hqk", q, k,
                    preferred_element_type=jnp.float32) * scale
+    if bias is not None:
+        s = s + bias
     if key_mask is not None:
         s = jnp.where(key_mask, s, -jnp.inf)
     m_blk = jnp.max(s, axis=-1)  # (..., h, q)
@@ -95,7 +123,7 @@ def _streaming_finalize(acc, l, dtype):
 
 
 def ring_attention_sharded(q, k, v, axis_name: str, scale=None,
-                           vary_axes=None):
+                           vary_axes=None, rel=None):
     """Attention with the SEQUENCE axis sharded over `axis_name`.
 
     To be called inside shard_map (or pmapped code): q/k/v are the LOCAL
@@ -106,15 +134,27 @@ def ring_attention_sharded(q, k, v, axis_name: str, scale=None,
     the ring axis). When the caller also shards the batch dim over another
     axis (DP×SP), that axis must be included so the fori_loop carry's
     varying-axes type matches the loop body's output.
+
+    rel: the decomposed bias of the LOCAL queries over the whole grid;
+    after i rotations device j holds the key block of device j − i.
     """
     p_size = lax.psum(1, axis_name)
     vary = tuple(vary_axes) if vary_axes is not None else (axis_name,)
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     acc, m, l = _streaming_init(q, vary)
+    s_local = k.shape[-3]
+    me = lax.axis_index(axis_name)
+
+    def bias_at(i):
+        if rel is None:
+            return None
+        start = ((me - i) % p_size) * s_local
+        return _rel_of_keys(rel, start + jnp.arange(s_local))
 
     def body(i, carry):
         acc, m, l, k_cur, v_cur = carry
-        acc, m, l = _block_attn_update((acc, m, l), (k_cur, v_cur), q, scale)
+        acc, m, l = _block_attn_update((acc, m, l), (k_cur, v_cur), q, scale,
+                                       bias=bias_at(i))
         perm = [(j, (j + 1) % p_size) for j in range(p_size)]
         k_nxt = lax.ppermute(k_cur, axis_name, perm)
         v_nxt = lax.ppermute(v_cur, axis_name, perm)
@@ -125,7 +165,8 @@ def ring_attention_sharded(q, k, v, axis_name: str, scale=None,
     # of ICI traffic saved per call).
     acc, m, l, k_last, v_last = lax.fori_loop(
         0, p_size - 1, body, (acc, m, l, k, v))
-    acc, m, l = _block_attn_update((acc, m, l), (k_last, v_last), q, scale)
+    acc, m, l = _block_attn_update((acc, m, l), (k_last, v_last), q, scale,
+                                   bias=bias_at(p_size - 1))
     return _streaming_finalize(acc, l, q.dtype)
 
 
@@ -149,25 +190,32 @@ def _sp_layout(q, mesh: Mesh, axis: str):
     return spec, vary
 
 
-def _sp_entry(make_sharded_fn, q, k, v, mesh: Mesh, axis: str):
+def _sp_entry(make_sharded_fn, q, k, v, mesh: Mesh, axis: str, rel=None):
     """Shared full-array entry: shard the sequence axis over `mesh[axis]`,
     run the per-shard attention under shard_map, return the full array.
+    ``rel``'s query axis is sharded as q's sequence.
 
     make_sharded_fn(vary) -> the per-shard callable; the layout (spec and
     varying axes) is computed ONCE here so the two can't diverge."""
     spec, vary = _sp_layout(q, mesh, axis)
-    fn = shard_map(
-        make_sharded_fn(vary),
-        mesh=mesh,
-        in_specs=(spec, spec, spec),
-        out_specs=spec,
-    )
+    sharded = make_sharded_fn(vary)
+    if rel is None:
+        fn = shard_map(sharded, mesh=mesh, in_specs=(spec, spec, spec),
+                       out_specs=spec)
+    else:
+        rel_spec = P(spec[0], None, axis, None)
+        fn = partial(shard_map(
+            lambda rel_h, rel_w, q, k, v: sharded(q, k, v,
+                                                  rel=(rel_h, rel_w)),
+            mesh=mesh, in_specs=(rel_spec, rel_spec, spec, spec, spec),
+            out_specs=spec), *rel)
     sh = NamedSharding(mesh, spec)
     return fn(jax.device_put(q, sh), jax.device_put(k, sh),
               jax.device_put(v, sh))
 
 
-def ring_attention(q, k, v, mesh: Mesh, axis: str = "data", scale=None):
+def ring_attention(q, k, v, mesh: Mesh, axis: str = "data", scale=None,
+                   rel=None):
     """Full-array entry point: shards the sequence axis over `mesh[axis]`.
 
     q/k/v: (B, S, H, D) with S divisible by the axis size. Output (B, S, H,
@@ -177,10 +225,11 @@ def ring_attention(q, k, v, mesh: Mesh, axis: str = "data", scale=None):
     return _sp_entry(
         lambda vary: partial(ring_attention_sharded, axis_name=axis,
                              scale=scale, vary_axes=vary),
-        q, k, v, mesh, axis)
+        q, k, v, mesh, axis, rel)
 
 
-def streaming_attention(q, k, v, scale=None, kv_chunk=1024, vary_axes=()):
+def streaming_attention(q, k, v, scale=None, kv_chunk=1024, vary_axes=(),
+                        rel=None):
     """Exact attention with flash-style streaming softmax over key blocks.
 
     (B, S, H, D) layout, same contract as dense_attention, but the score
@@ -201,7 +250,7 @@ def streaming_attention(q, k, v, scale=None, kv_chunk=1024, vary_axes=()):
     if n <= 1:
         # One block: the streaming pass degenerates to a single (S, S)
         # score buffer anyway — dense is the same memory, fewer ops.
-        return dense_attention(q, k, v, scale=scale)
+        return dense_attention(q, k, v, scale=scale, rel=rel)
     h, d = q.shape[-2], q.shape[-1]
     batch_shape = q.shape[:-3]
     pad = n * c - s
@@ -214,25 +263,33 @@ def streaming_attention(q, k, v, scale=None, kv_chunk=1024, vary_axes=()):
     nd = k.ndim
     km = jnp.moveaxis(k.reshape(batch_shape + (n, c, h, d)), nd - 3, 0)
     vm = jnp.moveaxis(v.reshape(batch_shape + (n, c, h, d)), nd - 3, 0)
+    keys = jnp.arange(n * c).reshape(n, c)
+
+    def bias_of(keys_blk):
+        return None if rel is None else _rel_of_keys(rel, keys_blk)
 
     def body(carry, xs):
-        return _block_attn_update(carry, xs, q, scale), None
+        k_blk, v_blk, keys_blk = xs
+        return _block_attn_update(carry, (k_blk, v_blk), q, scale,
+                                  bias=bias_of(keys_blk)), None
 
     if pad:
         # Only the final block holds padded keys: scan the full blocks
         # unmasked (no per-block where in the hot path), then one masked
         # tail update.
-        (acc, m, l), _ = lax.scan(body, (acc, m, l), (km[:-1], vm[:-1]))
+        (acc, m, l), _ = lax.scan(body, (acc, m, l),
+                                  (km[:-1], vm[:-1], keys[:-1]))
         tail_mask = jnp.arange(c) < (c - pad)
         acc, m, l = _block_attn_update((acc, m, l), (km[-1], vm[-1]), q,
-                                       scale, key_mask=tail_mask)
+                                       scale, key_mask=tail_mask,
+                                       bias=bias_of(keys[-1]))
     else:
-        (acc, m, l), _ = lax.scan(body, (acc, m, l), (km, vm))
+        (acc, m, l), _ = lax.scan(body, (acc, m, l), (km, vm, keys))
     return _streaming_finalize(acc, l, q.dtype)
 
 
 def ulysses_attention_sharded(q, k, v, axis_name: str, scale=None,
-                              vary_axes=None, kv_chunk=1024):
+                              vary_axes=None, kv_chunk=1024, rel=None):
     """All-to-all sequence parallelism (DeepSpeed-Ulysses layout).
 
     Local shards (..., s_local, h, d) with the SEQUENCE sharded over
@@ -258,16 +315,21 @@ def ulysses_attention_sharded(q, k, v, axis_name: str, scale=None,
         lax.all_to_all(x, axis_name, split_axis=x.ndim - 2,
                        concat_axis=x.ndim - 3, tiled=True)
         for x in (q, k, v))
+    if rel is not None:
+        # (..., h, s_local, g) -> (..., h/P, s_full, g), as q's heads.
+        rel = tuple(lax.all_to_all(x, axis_name, split_axis=x.ndim - 3,
+                                   concat_axis=x.ndim - 2, tiled=True)
+                    for x in rel)
     vary = tuple(vary_axes) if vary_axes is not None else (axis_name,)
     out = streaming_attention(q, k, v, scale=scale, vary_axes=vary,
-                              kv_chunk=kv_chunk)
+                              kv_chunk=kv_chunk, rel=rel)
     # (..., s_full, h/P, d) -> (..., s_local, h, d).
     return lax.all_to_all(out, axis_name, split_axis=out.ndim - 3,
                           concat_axis=out.ndim - 2, tiled=True)
 
 
 def ulysses_attention(q, k, v, mesh: Mesh, axis: str = "data", scale=None,
-                      kv_chunk=1024):
+                      kv_chunk=1024, rel=None):
     """Full-array entry point for the all-to-all SP formulation; same
     contract as ring_attention (q/k/v (B, S, H, D), S divisible by the
     axis size, plus H divisible by the axis size). kv_chunk sets the local
@@ -276,15 +338,18 @@ def ulysses_attention(q, k, v, mesh: Mesh, axis: str = "data", scale=None,
         lambda vary: partial(ulysses_attention_sharded, axis_name=axis,
                              scale=scale, vary_axes=vary,
                              kv_chunk=kv_chunk),
-        q, k, v, mesh, axis)
+        q, k, v, mesh, axis, rel)
 
 
-def dense_attention(q, k, v, scale=None):
+def dense_attention(q, k, v, scale=None, rel=None):
     """Reference dense softmax attention, (B, S, H, D) layout — the oracle
-    for the ring formulation and the single-device fallback."""
+    for the ring formulation and the single-device fallback; the scores
+    and the weighted sum in float32."""
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     s = jnp.einsum("...qhd,...khd->...hqk", q, k,
                    preferred_element_type=jnp.float32) * scale
+    if rel is not None:
+        s = _with_rel(s, rel)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("...hqk,...khd->...qhd", p, v,
                      preferred_element_type=jnp.float32)

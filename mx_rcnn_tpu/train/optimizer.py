@@ -17,6 +17,12 @@ Mapping:
 - freezing: a boolean mask — frozen leaves receive zero updates AND no weight
   decay (MXNet's fixed_param_names are simply absent from the executor's
   grad list).
+
+AdamW (the transformer families) adds two per-leaf rules of ViTDet's
+recipe (Detectron2 ``get_default_optimizer_params`` with
+``weight_decay_norm=0`` and ``get_vit_lr_decay_rate``): weight decay skips
+the ``train.no_decay`` leaves, and each leaf's update is scaled by its
+layer's rate (``layer_rates``).
 """
 
 from __future__ import annotations
@@ -24,6 +30,7 @@ from __future__ import annotations
 from typing import Sequence
 
 import jax
+import numpy as np
 import optax
 
 from mx_rcnn_tpu.config import Config
@@ -95,6 +102,56 @@ def effective_fixed_patterns(cfg: Config) -> tuple:
     return pats
 
 
+def _path_keys(path) -> list:
+    return [str(getattr(p, "key", getattr(p, "name", p))) for p in path]
+
+
+def decay_mask(params, no_decay: Sequence[str]):
+    """True where AdamW's weight decay applies. ``no_decay`` names leaves
+    (``pos_embed``) or, as "norm", every leaf of a norm layer (LayerNorm,
+    GroupNorm: the modules whose leaves are ``scale`` and ``bias``)."""
+    norm_modules = set()
+    for path, _ in jax.tree_util.tree_flatten_with_path(params)[0]:
+        keys = _path_keys(path)
+        if keys[-1] == "scale":
+            norm_modules.add(tuple(keys[:-1]))
+
+    def decide(path, _) -> bool:
+        keys = _path_keys(path)
+        if keys[-1] in no_decay:
+            return False
+        return not ("norm" in no_decay and tuple(keys[:-1]) in norm_modules)
+
+    return jax.tree_util.tree_map_with_path(decide, params)
+
+
+def vit_layer(keys: Sequence[str], depth: int) -> int:
+    """ViTDet's layer of a leaf (``get_vit_lr_decay_rate``): the patch
+    embedding and absolute positions 0, trunk block i at i + 1, every
+    other leaf (pyramid, heads) depth + 1."""
+    if "features" in keys:
+        at = keys.index("features") + 1
+        name = keys[at] if at < len(keys) else ""
+        if name in ("patch_embed", "pos_embed"):
+            return 0
+        if name.startswith("block") and name[5:].isdigit():
+            return int(name[5:]) + 1
+    return depth + 1
+
+
+def scale_by_layer(decay: float, depth: int) -> optax.GradientTransformation:
+    """Each leaf's update times its layer's learning-rate factor,
+    ``decay ** (depth + 1 - vit_layer)``, read off the leaf's path."""
+
+    def scale(updates, _):
+        return jax.tree_util.tree_map_with_path(
+            lambda path, u: u * np.float32(
+                decay ** (depth + 1 - vit_layer(_path_keys(path), depth))),
+            updates)
+
+    return optax.stateless(scale)
+
+
 def build_optimizer(cfg: Config, params, steps_per_epoch: int = 1000,
                     begin_step: int = 0):
     mask = trainable_mask(params, effective_fixed_patterns(cfg))
@@ -104,13 +161,20 @@ def build_optimizer(cfg: Config, params, steps_per_epoch: int = 1000,
     slot_dtype = (None if cfg.train.opt_state_dtype == "float32"
                   else cfg.train.opt_state_dtype)
     if cfg.train.optimizer == "adamw":
-        # Transformer families (DETR/ViTDet): AdamW + global-norm clip,
-        # per their papers. Weight decay is decoupled (inside adamw).
-        inner = optax.chain(
-            optax.clip_by_global_norm(cfg.train.clip_gradient),
-            optax.adamw(learning_rate=sched, weight_decay=cfg.train.wd,
-                        mu_dtype=slot_dtype),
-        )
+        # Transformer families (DETR/ViTDet): AdamW, per their papers,
+        # with a global-norm clip where one is set (DETR 0.1; ViTDet has
+        # none: inf). Weight decay is decoupled (inside adamw).
+        t = cfg.train
+        parts = ([optax.clip_by_global_norm(t.clip_gradient)]
+                 if np.isfinite(t.clip_gradient) else [])
+        parts.append(optax.adamw(
+            learning_rate=sched, weight_decay=t.wd, mu_dtype=slot_dtype,
+            mask=((lambda p: decay_mask(p, t.no_decay)) if t.no_decay
+                  else None)))
+        if t.layer_decay != 1.0:
+            parts.append(scale_by_layer(t.layer_decay,
+                                        cfg.network.vit_depth))
+        inner = optax.chain(*parts)
     elif cfg.train.optimizer == "sgd":
         inner = optax.chain(
             optax.clip(cfg.train.clip_gradient),

@@ -74,6 +74,30 @@ def _prune_run_tmp(tmp_path_factory):
     shutil.rmtree(base, ignore_errors=True)
 
 
+@pytest.fixture(scope="module", autouse=True)
+def _release_module_memory(request):
+    """After each module of whole-model compiles (the chip-compile files,
+    and those marked ``compile_heavy``: the rehearsals, the train steps):
+    drop JAX's compiled-function caches and hand freed heap back to the
+    system. A worker runs many modules in one process, and those compiles
+    otherwise leave each worker holding several GB for the rest of the
+    run; six such workers outgrew the machine's memory. Light modules
+    keep their caches."""
+    yield
+    if (request.node.get_closest_marker("compile_heavy") is None
+            and not request.module.__name__.startswith("test_chip_compile")):
+        return
+    import ctypes
+    import gc
+
+    jax.clear_caches()
+    gc.collect()
+    try:
+        ctypes.CDLL("libc.so.6").malloc_trim(0)
+    except OSError:  # not glibc: nothing to trim
+        pass
+
+
 @pytest.fixture
 def rng():
     return np.random.RandomState(0)

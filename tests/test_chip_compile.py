@@ -1,8 +1,5 @@
-"""Compiles for the chip, without the chip.
-
-The TPU's compiler is installed here and compiles for a v5e that is
-DESCRIBED, not attached (on-chip-measurement guide §2.3) — so what only the
-chip's compiler can refuse is held by tier-1 at no chip time:
+"""Compiles for the chip, without the chip: the Pallas NMS kernel and the
+tiny C4 train steps (``tests/chip_compile.py`` describes the v5e).
 
 - the Pallas NMS kernel (ops/nms_pallas.py) at every N the presets reach —
   6000 / 12000 (C4 test / train), 20000 (alternate training's
@@ -21,60 +18,26 @@ it, not its jnp stand-in; the train steps also hold it under the name a
 trace shows it by (``nms_pallas.KERNEL_NAME``), inside the ``proposal``
 stage, on one chip and under the ``shard_map`` alike. Nothing runs — a
 compile that passes is not a chip run. The interpret-mode parity tests are
-tests/test_nms.py.
-
-Ground rules of this file (guide §2): the topology is described inside a
-module-scoped fixture that skips when it cannot be, never while a module is
-imported; all of these tests stay in this ONE file (only one process at a
-time may load the TPU's library — a second file could land on another
-worker); no child processes; the compile cache is off around the compiles
-(an executable compiled for a described device cannot be read back).
+tests/test_nms.py. The pyramid's, the mask cell's, the evaluation
+forward's and ViTDet's compiles are in the ``test_chip_compile_*.py``
+files beside this one.
 """
 
-import functools
-import os
 import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
-                          SingleDeviceSharding)
+from jax.sharding import NamedSharding, PartitionSpec as P
 
+from chip_compile import (KERNEL, _H_CONTRACTION, _ROI_ALIGN_OUT,  # noqa: F401
+                          _assert_kernel_named_in_its_stage, _roi_align_ops,
+                          _tiny_step_hlo,
+                          assert_labelling_moves_no_row_an_anchor, data_mesh,
+                          one_chip, one_chip_mesh, topo)
 from mx_rcnn_tpu.ops import nms_pallas
 from mx_rcnn_tpu.ops.nms import nms_dispatch
-
-KERNEL = 'custom_call_target="tpu_custom_call"'
-
-
-@pytest.fixture(scope="module")
-def topo():
-    from jax.experimental import topologies
-    from jax.experimental.compilation_cache import compilation_cache
-
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs to /tmp
-    try:
-        desc = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001  # graftlint: disable=broad-except — whatever keeps libtpu from describing the chip becomes a skip that says so
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    was_on = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield desc
-    jax.config.update("jax_enable_compilation_cache", was_on)
-    compilation_cache.reset_cache()
-
-
-@pytest.fixture(scope="module")
-def one_chip(topo):
-    return SingleDeviceSharding(topo.devices[0])
-
-
-@pytest.fixture(scope="module")
-def data_mesh(topo):
-    return Mesh(np.asarray(topo.devices).reshape(4, 1), ("data", "model"))
 
 
 def _nms_args(batch, n, sharding):
@@ -150,37 +113,6 @@ def test_kernel_partitions_over_a_data_mesh(data_mesh):
         bare.lower(*_nms_args(4, 12000, sharded)).compile()
 
 
-@functools.lru_cache(maxsize=None)
-def _tiny_step_hlo(mesh, n_images):
-    """The compiled step's text; one compile per (mesh, batch) for the
-    tests that read it. Call it with ``jax.default_backend`` patched to
-    "tpu", as every caller here does."""
-    from mx_rcnn_tpu.config import generate_config
-    from mx_rcnn_tpu.models.faster_rcnn import build_model
-    from mx_rcnn_tpu.train.step import abstract_step_inputs, make_train_step
-
-    cfg = generate_config("resnet50", "synthetic", **{
-        "train.rpn_pre_nms_top_n": 256, "train.rpn_post_nms_top_n": 64,
-        "train.batch_rois": 32, "train.max_gt_boxes": 8,
-        "network.anchor_scales": (2, 4, 8), "image.pad_shape": (128, 128)})
-    model = build_model(cfg)
-    return make_train_step(model, cfg, mesh=mesh).lower(
-        *abstract_step_inputs(model, cfg, mesh, n_images)).compile().as_text()
-
-
-def _assert_kernel_named_in_its_stage(hlo):
-    """The program's Mosaic calls are instructions named after the kernel,
-    and their scope paths (``op_name``) lie in the ``proposal`` stage."""
-    from mx_rcnn_tpu.obs.profile import stage_of
-
-    paths = re.findall(
-        rf'%{nms_pallas.KERNEL_NAME}[\w.]* = [^\n]*{KERNEL}[^\n]*'
-        r'op_name="([^"]*)"', hlo)
-    assert paths, "no Mosaic call named after the kernel"
-    assert all(f"/{nms_pallas.KERNEL_NAME}/" in p
-               and stage_of(p) == "proposal" for p in paths), paths
-
-
 def test_data_parallel_train_step_compiles_with_the_kernel(data_mesh,
                                                            monkeypatch):
     """make_train_step over the 4-device mesh, as fit_detector builds it
@@ -194,12 +126,6 @@ def test_data_parallel_train_step_compiles_with_the_kernel(data_mesh,
     _assert_kernel_named_in_its_stage(hlo)
 
 
-@pytest.fixture(scope="module")
-def one_chip_mesh(topo):
-    return Mesh(np.asarray(topo.devices[:1]).reshape(1, 1),
-                ("data", "model"))
-
-
 def test_one_chip_train_step_names_the_kernel(one_chip_mesh, monkeypatch):
     """The same step on a mesh of one described chip (no ``shard_map``
     around the kernel, no collective): the trace will show the kernel as
@@ -210,96 +136,20 @@ def test_one_chip_train_step_names_the_kernel(one_chip_mesh, monkeypatch):
     _assert_kernel_named_in_its_stage(hlo)
 
 
-@functools.lru_cache(maxsize=None)
-def _tiny_pyramid_step_hlo(mesh, image=(128, 192), images=2):
-    """The FPN step as ``make_train_step`` builds it by default (no
-    ``forward_fn``: the family dispatcher), exact top-k, ``images`` images
-    on a canvas of ``image`` pixels. Call it with ``jax.default_backend``
-    patched to "tpu"."""
-    from mx_rcnn_tpu.config import generate_config
-    from mx_rcnn_tpu.models.zoo import build_model
-    from mx_rcnn_tpu.train.step import abstract_step_inputs, make_train_step
-
-    cfg = generate_config("resnet50_fpn", "synthetic", **{
-        "network.proposal_topk": "exact",
-        "train.fpn_rpn_pre_nms_per_level": 256,
-        "train.rpn_post_nms_top_n": 64, "train.batch_rois": 32,
-        "train.max_gt_boxes": 8, "image.scales": (image,),
-        "image.pad_shape": image})
-    model = build_model(cfg)
-    return make_train_step(model, cfg, mesh=mesh).lower(
-        *abstract_step_inputs(model, cfg, mesh, images)).compile().as_text()
-
-
-def test_pyramid_step_launches_the_kernel_once_a_level(one_chip_mesh,
-                                                        monkeypatch):
-    """The pyramid step compiles for one described chip, and its five
-    per-level NMS are five Mosaic calls named after the kernel, each
-    (images, 1, that level's candidates padded to 128): 256 of P2's 4608,
-    P3's 1152 and P4's 288 anchors, all 72 of P5's and all 18 of P6's."""
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    hlo = _tiny_pyramid_step_hlo(one_chip_mesh)
-    shapes = re.findall(
-        rf'%{nms_pallas.KERNEL_NAME}[\w.]* = f32\[(\d+),1,(\d+)\][^\n]*'
-        + KERNEL, hlo)
-    assert sorted(int(n) for _, n in shapes) == [128, 128, 256, 256, 256]
-    assert {int(b) for b, _ in shapes} == {2}
-    _assert_kernel_named_in_its_stage(hlo)
-    assert "all-reduce" not in hlo
-
-
-def _moved_over_every_anchor(hlo, stage_name, images, anchors):
-    """The gathers and scatters scoped in ``stage_name`` that move a row an
-    anchor: a gather's result, a scatter's updates, with the per-image or
-    the flat anchor count among their dimensions. (A scatter of the 128
-    kept positives' targets INTO a zero (anchors, 4) moves 128 rows.) The
-    compiler leaves some of them without an ``op_name``: those count for
-    the stages their fused computation's other instructions name."""
-    from mx_rcnn_tpu.obs.profile import stage_of
-
-    dims_of = {name: [int(d) for d in dims.split(",") if d]
-               for name, dims in re.findall(
-                   r"^\s*(?:ROOT )?(%[\w.-]+) = \w+\[([\d,]*)\]", hlo, re.M)}
-    found = []
-    for body in re.findall(r"^%[\w.-]+ [^\n]*\{\n(.*?)^\}", hlo, re.S | re.M):
-        around = {stage_of(p) for p in re.findall(r'op_name="([^"]*)"', body)}
-        for name, opcode, operands, rest in re.findall(
-                r"^\s*(?:ROOT )?(%[\w.-]+) = \S+ (gather|scatter)\(([^)]*)\)"
-                r"([^\n]*)", body, re.M):
-            own = re.search(r'op_name="([^"]*)"', rest)
-            if stage_name not in ({stage_of(own.group(1))} if own else around):
-                continue
-            moved = (dims_of[name] if opcode == "gather" else
-                     dims_of[re.findall(r"%[\w.-]+", operands)[-1]])
-            if {anchors, images * anchors} & set(moved):
-                found.append((name, opcode, moved))
-    return found
-
-
-@pytest.mark.parametrize("family", ["c4", "pyramid"])
+@pytest.mark.parametrize("family", ["c4"])
 def test_anchor_labelling_moves_no_row_an_anchor(one_chip_mesh, monkeypatch,
                                                  family):
     """``targets/rpn_targets.py`` as the chip's compiler leaves it, in the
-    tiny C4 step (8x8 cells x 9 = 576 anchors an image) and the pyramid step
-    (6138 over P2-P6): the overlaps are walked a gt slot at a time by a
-    ``while`` scoped in the stage, and no gather or scatter there moves a
-    row for every anchor — the ranking's inverse permutation and the
-    matched box of every anchor (13 ms each at 279,279 anchors x 8 images,
-    PERF.md section 6, PR 33) are gone. ``rpn_loss`` picks the label's
-    log-probability by a dense select, no gather either."""
-    from mx_rcnn_tpu.obs.profile import stage_of
-
+    tiny C4 step (8x8 cells x 9 = 576 anchors an image): the overlaps are
+    walked a gt slot at a time by a ``while`` scoped in the stage, and no
+    gather or scatter there moves a row for every anchor — the ranking's
+    inverse permutation and the matched box of every anchor (13 ms each at
+    279,279 anchors x 8 images, PERF.md section 6) are gone.
+    ``rpn_loss`` picks the label's log-probability by a dense select, no
+    gather either. The pyramid case is ``test_chip_compile_pyramid.py``'s."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    hlo, anchors = ((_tiny_step_hlo(one_chip_mesh, 2), 8 * 8 * 9)
-                    if family == "c4" else
-                    (_tiny_pyramid_step_hlo(one_chip_mesh),
-                     3 * (32 * 48 + 16 * 24 + 8 * 12 + 4 * 6 + 2 * 3)))
-    for stage_name in ("rpn_targets", "rpn_loss"):
-        assert not _moved_over_every_anchor(hlo, stage_name, 2, anchors)
-    loops = [path for path in re.findall(
-        r'^\s*%[\w.-]+ = [^\n]*? while\([^\n]*op_name="([^"]*)"', hlo, re.M)
-        if stage_of(path) == "rpn_targets"]
-    assert loops, "no while loop scoped in rpn_targets"
+    assert_labelling_moves_no_row_an_anchor(_tiny_step_hlo(one_chip_mesh, 2),
+                                            8 * 8 * 9)
 
 
 def test_labelling_over_a_data_mesh_exchanges_one_scalar(data_mesh,
@@ -362,20 +212,6 @@ def test_update_rides_in_the_weight_gradient_convolutions(one_chip_mesh,
 # map of 1024 channels. Per image, the elements each contraction of
 # ops/roi_align.py puts out, forward or backward: (r,p,w,c), (r,p,q,c)
 # and the map's own (h,w,c).
-_ROI_ALIGN_OUT = {32 * 14 * 8 * 1024, 32 * 14 * 14 * 1024, 8 * 8 * 1024}
-_H_CONTRACTION = "/jvp(roi_align)/brph,bhwc->brpwc/dot_general"
-
-
-def _roi_align_ops(hlo):
-    """(opcode, elements put out, op_name) of every instruction, fused or
-    not, whose scope path lies in the ``roi_align`` stage."""
-    from mx_rcnn_tpu.obs.profile import stage_of
-
-    ops = re.findall(r'^\s*(?:ROOT )?%[\w.-]+ = \w+\[([\d,]*)\]\S* '
-                     r'([\w-]+)\([^\n]*op_name="([^"]*)"', hlo, re.M)
-    return [(opcode, int(np.prod([int(d) for d in dims.split(",") if d])),
-             path) for dims, opcode, path in ops
-            if stage_of(path) == "roi_align"]
 
 
 def test_roi_align_partitions_over_data_with_no_exchange(data_mesh,
@@ -405,179 +241,3 @@ def test_one_chip_roi_align_is_one_batched_contraction(one_chip_mesh,
     assert forward_h == [2 * 32 * 14 * 8 * 1024], forward_h
     assert {n for o, n, _ in ops if o == "convolution"} <= {
         2 * n for n in _ROI_ALIGN_OUT}
-
-
-def _taps_kernels(hlo):
-    """(name, result, op_name) of each launch of the taps kernels
-    (ops/roi_align_pallas.py), forward or backward."""
-    from mx_rcnn_tpu.ops import roi_align_pallas as rap
-
-    names = f"(?:{rap.KERNEL_NAME_GRAD}|{rap.KERNEL_NAME})"
-    return re.findall(rf'%({names})[\w.]* = (\S+) [^\n]*{KERNEL}[^\n]*'
-                      r'op_name="([^"]*)"', hlo)
-
-
-def test_pyramid_step_pools_each_roi_once_from_the_canvas(one_chip_mesh,
-                                                          monkeypatch):
-    """The tiny pyramid step (2 images of 128x192, 32 rois each, 7 bins,
-    256 channels): P2's 32x48 over a shelf of P3, P4, P5 is a 48x48
-    canvas, whose 2,304 cells cost less than a roi's 784 taps, so the
-    form is dense (``ops/roi_align.py::pooling_form``; the taps form's case
-    is the mesh's, below): the ``roi_align`` stage holds ONE forward
-    contraction over H, canvas-wide, where pooling level by level held four, and no
-    launch of the taps kernels; no array in the program is
-    ``(B, R, P, W_l, C)`` at P3's, P4's or P5's own width."""
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    hlo = _tiny_pyramid_step_hlo(one_chip_mesh)
-    ops = _roi_align_ops(hlo)
-    forward_h = [n for o, n, path in ops if o == "convolution"
-                 and path.endswith(_H_CONTRACTION)]
-    assert forward_h == [2 * 32 * 7 * 48 * 256], forward_h
-    a_level_wide = {2 * 32 * 7 * w * 256 for w in (24, 12, 6)}
-    assert not [(o, n) for o, n, _ in ops if n in a_level_wide]
-    assert not re.findall(r"\w+\[2,32,7,(?:24|12|6),256\]", hlo)
-    assert re.findall(r"bf16\[2,48,48,256\]", hlo)
-    assert not _taps_kernels(hlo)
-
-
-def test_pyramid_taps_run_under_shard_map_with_no_exchange(data_mesh,
-                                                           monkeypatch):
-    """Four images of 800x1024 on the 4-device data mesh: P2's 200x256 over
-    a shelf of P3, P4, P5 is a 300x256 canvas, wide enough that a roi's
-    taps cost less than its area, so the box pooling takes the taps form
-    (``pooling_form``): ONE forward and ONE backward
-    launch of the kernels, each in a ``shard_map`` over ``data`` (one image
-    a device: the launch is one image wide) inside the ``roi_align`` stage;
-    no collective moves a map or a roi in the stage, and no array is
-    ``(B, R, P, W, C)`` at the canvas's width."""
-    from mx_rcnn_tpu.obs.profile import stage_of
-    from mx_rcnn_tpu.ops import roi_align_pallas as rap
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    hlo = _tiny_pyramid_step_hlo(data_mesh, (800, 1024), 4)
-    launches = _taps_kernels(hlo)
-    assert sorted(name for name, _, _ in launches) == [
-        rap.KERNEL_NAME, rap.KERNEL_NAME_GRAD], launches
-    assert all(stage_of(path) == "roi_align" and "shard_map" in path
-               for _, _, path in launches), launches
-    shapes = {name: shape for name, shape, _ in launches}
-    assert shapes[rap.KERNEL_NAME].startswith("bf16[1,32,7,7,256]")
-    assert shapes[rap.KERNEL_NAME_GRAD].startswith(
-        f"bf16[1,{300 * 256},256]")
-    ops = _roi_align_ops(hlo)
-    moved = [(o, path) for o, _, path in ops if o.startswith(
-        ("all-gather", "all-to-all", "collective-permute", "all-reduce"))]
-    assert not moved, moved
-    assert not re.findall(r"\w+\[\d+,32,7,256,256\]", hlo)
-
-
-def test_mask_step_at_the_cells_sizes_fits_one_chip(one_chip_mesh,
-                                                    monkeypatch):
-    """Mask R-CNN on ResNet-101-FPN at the sizes the cell
-    ``mask_r101_train`` runs (benchmarks/configs/mask_r101_fpn_coco.json: 8
-    images of 832x1344, 512 rois an image, no remat) lowers from
-    ``abstract_step_inputs`` - ``gt_masks`` among them - and compiles for
-    one described v5e under the 15.75 GB its compiler allows. The mask
-    branch runs over the sampler's foreground block, 128 of the 512 slots:
-    no array is (8, 512, 14 bins, ...) - over every slot P2's intermediate
-    of the pooling alone is 9.19 GB and the step is refused (PERF.md section
-    6, PR 34) - and the loss picks its class's map without a gather. Both
-    poolings contract against the 312x336 canvas of the four levels, once:
-    their intermediates are P2's width, 336, and none is 168, 84 or 42
-    wide (PR 36); since PR 38 the box pooling's is not there at all: it is
-    the taps kernels, launched once forward and once backward, while the
-    branch's 14x14 stays dense (its 3,136 taps a roi cost more than the
-    canvas). The one full-size compile of this file: about two minutes."""
-    from benchmarks import manifest
-    from benchmarks.drivers.train import _program_config
-    from mx_rcnn_tpu.models.zoo import build_model
-    from mx_rcnn_tpu.obs.profile import BRANCH_STAGES, branch_of
-    from mx_rcnn_tpu.train.step import abstract_step_inputs, make_train_step
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cfg = _program_config(manifest.load_json("configs", "mask_r101_fpn_coco"))
-    assert cfg.network.use_mask and not cfg.network.remat
-    images = cfg.train.batch_images
-    model = build_model(cfg, mesh=one_chip_mesh)
-    args = abstract_step_inputs(model, cfg, one_chip_mesh, images)
-    m = cfg.train.mask_gt_resolution
-    assert args[1]["gt_masks"].shape == (images, cfg.train.max_gt_boxes, m, m)
-    assert args[1]["gt_masks"].dtype == jnp.uint8
-    compiled = make_train_step(model, cfg, mesh=one_chip_mesh).lower(
-        *args).compile()
-    mem = compiled.memory_analysis()
-    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
-    assert total < 15.75e9, total
-    hlo = compiled.as_text()
-    slots, block = cfg.train.batch_rois, round(
-        cfg.train.fg_fraction * cfg.train.batch_rois)
-    bins = model.mask_pool_size
-    assert (images, slots, block, bins) == (8, 512, 128, 14)
-    # (but for the box pooling's taps, (8, 512, 14, 1 or 2) and nothing
-    # after: 7 bins x 2 samples of a roi, each one cell or a pair, no map)
-    assert not {a for a in re.findall(
-        rf"\w+\[{images},{slots},{bins},([\d,]*)\]", hlo)} - {"1", "2"}
-    assert not re.findall(rf"\w+\[{images * slots},{bins},{bins},\d+\]", hlo)
-    assert re.findall(rf"bf16\[{images},{block},{bins},{bins},256\]", hlo)
-    # the box head's pooling by taps (ops/roi_align_pallas.py): one
-    # forward and one backward launch, no (B, R, 7, W, C) intermediate;
-    # the branch's 14x14 pooling stays dense at this canvas
-    # (ops/roi_align.py::pooling_form): its intermediate is P2's width
-    from mx_rcnn_tpu.ops import roi_align_pallas as rap
-
-    launches = sorted((name, shape.split("{")[0])
-                      for name, shape, _ in _taps_kernels(hlo))
-    assert launches == [
-        (rap.KERNEL_NAME, f"bf16[{images},{slots},7,7,256]"),
-        (rap.KERNEL_NAME_GRAD, f"bf16[{images},{312 * 336},256]")], launches
-    pooled_at = rf"\w+\[{images},(?:{block},{bins}|{slots},7),(\d+),256\]"
-    assert {int(w) for w in re.findall(pooled_at, hlo)} - {7, bins} == {336}
-    assert not re.findall(rf"\w+\[{images},{slots},7,336,256\]", hlo)
-    assert re.findall(rf"bf16\[{images},312,336,256\]", hlo)
-    paths = set(re.findall(r'op_name="([^"]*)"', hlo))
-    assert {branch_of(p) for p in paths} - {None} == set(BRANCH_STAGES)
-    moved = [ln for ln in hlo.splitlines()
-             if re.search(r" (gather|scatter)\(", ln)
-             and branch_of(" ".join(re.findall(r'op_name="([^"]*)"', ln)))
-             == "mask_loss"]
-    assert not moved, moved[:2]
-
-
-@pytest.mark.parametrize("rois", [300, 1000])
-def test_pyramid_eval_forward_takes_the_kernel(one_chip_mesh, monkeypatch,
-                                               rois):
-    """The FPN evaluation forward (``forward_test``; ViTDet's pyramid heads
-    pool through the same ``pyramid_roi_align``) at the pyramid cell's test
-    shape: one image of 832x1344 and ``rois`` proposals (the config's 300,
-    Detectron's 1000: 60 and 50 rois a grid step, whose scalar blocks are
-    padded to whole tiles) compiles for one described v5e with ONE launch
-    of the taps kernel, forward only, its result the pooled
-    ``bf16[1,R,7,7,256]``, inside the ``roi_align`` stage; no array is
-    ``(1, R, 7, W, 256)`` at a canvas's width."""
-    from benchmarks import manifest
-    from benchmarks.drivers.train import _program_config
-    from mx_rcnn_tpu.models.fpn import forward_test
-    from mx_rcnn_tpu.models.zoo import build_model, init_params
-    from mx_rcnn_tpu.obs.profile import stage_of
-    from mx_rcnn_tpu.ops import roi_align_pallas as rap
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cfg = _program_config(manifest.load_json("configs", "fpn_r101_coco"),
-                          {"test.rpn_post_nms_top_n": rois})
-    model = build_model(cfg, mesh=one_chip_mesh)
-    repl = NamedSharding(one_chip_mesh, P())
-    params = jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=repl),
-        jax.eval_shape(lambda k: init_params(model, cfg, k),
-                       jax.random.PRNGKey(0)))
-    h, w = cfg.image.pad_shape
-    images = jax.ShapeDtypeStruct((1, h, w, 3), jnp.float32, sharding=repl)
-    im_info = jax.ShapeDtypeStruct((1, 3), jnp.float32, sharding=repl)
-    hlo = jax.jit(lambda p, i, ii: forward_test(model, p, i, ii, cfg)).lower(
-        params, images, im_info).compile().as_text()
-    launches = _taps_kernels(hlo)
-    assert [(name, shape.split("{")[0]) for name, shape, _ in launches] == [
-        (rap.KERNEL_NAME, f"bf16[1,{rois},7,7,256]")], launches
-    assert all(stage_of(path) == "roi_align" for _, _, path in launches)
-    assert not re.findall(rf"\w+\[1,{rois},7,\d\d+,256\]", hlo)

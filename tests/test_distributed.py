@@ -234,6 +234,7 @@ cfg = generate_config("vitdet_b", "synthetic", **{
     "network.vit_window": 4,
     "train.compute_dtype": "f32",
     "network.pp_stages": 2,
+    "network.vit_drop_path": 0.0,  # the staged backbone runs none
     "network.anchor_scales": (2, 4),
     "train.fpn_rpn_pre_nms_per_level": 64,
     "train.rpn_post_nms_top_n": 32,

@@ -13,6 +13,7 @@ from functools import partial
 
 import jax
 import numpy as np
+import optax
 import pytest
 
 pytestmark = pytest.mark.compile_heavy
@@ -40,6 +41,17 @@ def _vit_cfg(**overrides):
         "network.vit_window": 4,
         "train.compute_dtype": "f32",
         "network.tensor_parallel": True,
+        # the replicated and the sharded step are held to each other after
+        # an update: by SGD at lr 0.01, which moves the parameters by ~1e-3
+        # and keeps the two sides' rounding at its own size; the preset's
+        # AdamW turns a gradient's rounding into a whole lr wherever the
+        # gradient is near nought (the second step's loss 1e-4 to 6e-4
+        # apart, params a step apart; AdamW under TP is DETR's test below),
+        # and no stochastic depth
+        "network.vit_drop_path": 0.0,
+        "train.optimizer": "sgd",
+        "train.lr": 0.01,
+        "train.clip_gradient": 5.0,
         "train.fpn_rpn_pre_nms_per_level": 64,
         "train.rpn_post_nms_top_n": 64,
         "train.batch_rois": 32,
@@ -78,11 +90,21 @@ def test_spec_rules_match_expected_leaves():
     assert specs["params/features/block0/attn/proj/kernel"] == P("model", None)
     assert specs["params/features/block0/mlp1/kernel"] == P(None, "model")
     assert specs["params/features/block0/mlp2/kernel"] == P("model", None)
-    # The paired FC box head is split; everything conv-ish is replicated.
-    assert specs["params/head/fc6/kernel"] == P(None, "model")
-    assert specs["params/head/fc7/kernel"] == P("model", None)
+    # ViTDet's box head is 4conv1fc: its one FC layer has no partner to
+    # split against, so it stays replicated, as everything conv-ish does;
+    # the paired FC head (fc6/fc7) of the FPN presets is split.
+    assert specs["params/head/fc1/kernel"] == P()
+    assert specs["params/head/conv1/kernel"] == P()
     assert specs["params/features/patch_embed/kernel"] == P()
     assert specs["params/cls_score/kernel"] == P()
+    fpn_cfg = generate_config("resnet50_fpn", "synthetic",
+                              **{"image.pad_shape": (128, 128)})
+    fpn_model = zoo.build_model(fpn_cfg)
+    fpn_specs = _flat(tp_param_specs(jax.eval_shape(
+        lambda k: zoo.init_params(fpn_model, fpn_cfg, k),
+        jax.random.PRNGKey(0))))
+    assert fpn_specs["params/head/fc6/kernel"] == P(None, "model")
+    assert fpn_specs["params/head/fc7/kernel"] == P("model", None)
 
 
 def test_shard_params_places_on_model_axis():
@@ -135,7 +157,7 @@ def _run_steps(cfg, params, batch, mesh=None, tp=False, n_steps=2):
         b = shard_batch(batch, mesh) if mesh is not None else batch
         state, metrics = step(state, b, jax.random.PRNGKey(7 + i))
         losses.append(float(metrics["TotalLoss"]))
-    return losses, jax.device_get(state.params)
+    return losses, jax.device_get(state)
 
 
 def test_vitdet_tp_step_matches_replicated(rng):
@@ -148,17 +170,88 @@ def test_vitdet_tp_step_matches_replicated(rng):
     params = zoo.init_params(model, cfg, jax.random.PRNGKey(0))
     batch = _batch(rng)
 
-    ref_losses, ref_params = _run_steps(cfg, params, batch)
+    ref_losses, ref_state = _run_steps(cfg, params, batch)
     mesh = create_mesh("2x2")
-    tp_losses, tp_params = _run_steps(cfg, params, batch, mesh=mesh, tp=True)
+    tp_losses, tp_state = _run_steps(cfg, params, batch, mesh=mesh, tp=True)
 
     np.testing.assert_allclose(tp_losses, ref_losses, rtol=2e-4)
-    ref_flat, tp_flat = _flat(ref_params), _flat(tp_params)
+    ref_flat, tp_flat = _flat(ref_state.params), _flat(tp_state.params)
     for name in ("params/features/block0/mlp1/kernel",
-                 "params/head/fc6/kernel",
+                 "params/head/fc1/kernel",
                  "params/features/patch_embed/kernel"):
         np.testing.assert_allclose(tp_flat[name], ref_flat[name],
                                    rtol=1e-3, atol=1e-5, err_msg=name)
+
+
+def _first_moment(opt_state) -> dict:
+    """AdamW's mu, flat by parameter path (frozen leaves left out)."""
+    adam = [s for s in jax.tree_util.tree_leaves(
+        opt_state, is_leaf=lambda x: isinstance(x, optax.ScaleByAdamState))
+        if isinstance(s, optax.ScaleByAdamState)]
+    assert len(adam) == 1
+    return {k: v for k, v in _flat(adam[0].mu).items()
+            if isinstance(v, np.ndarray)}
+
+
+def test_vitdet_tp_adamw_step_matches_replicated(rng):
+    """The ViT preset's own update under DP x TP (2x2 mesh): AdamW with
+    layer-wise decay and the no-decay set, one step, compared where it is
+    well conditioned. The losses (2e-4, as above). The first moment
+    mu = (1 - b1) g, the gradient as each side sums it, to 2e-2 of its
+    norm: the two sides read 0.5-0.75 % apart on every trunk leaf at
+    float32, one upstream difference of summation order carried through
+    the whole backward (a lost reduction reads 50 % or more). And the step
+    itself on entries whose gradient stands clear of that difference
+    (above 5e-2 of the leaf's largest, so no sign flips), where AdamW's
+    first step is lr · rate · (sign(g) + wd · w): the two sides, and that
+    formula with the layer's rate 0.7 ** (depth + 1 - layer) and no decay
+    on norms and pos_embed, to 1e-3 (the step is read as w0 - w1, whose
+    float32 rounding is up to 1e-4 of a 3e-5 step)."""
+    if jax.device_count() < 4:
+        pytest.skip("needs 4 devices")
+    preset = generate_config("vitdet_b", "synthetic").train
+    cfg = _vit_cfg(**{"train.optimizer": preset.optimizer,
+                      "train.lr": preset.lr,
+                      "train.clip_gradient": preset.clip_gradient})
+    t = cfg.train
+    assert (t.optimizer, t.layer_decay, t.no_decay) == (
+        "adamw", 0.7, ("norm", "pos_embed"))
+    model = zoo.build_model(cfg)
+    params = zoo.init_params(model, cfg, jax.random.PRNGKey(0))
+    batch = _batch(rng)
+    ref_losses, ref = _run_steps(cfg, params, batch, n_steps=1)
+    mesh = create_mesh("2x2")
+    tp_losses, tp = _run_steps(cfg, params, batch, mesh=mesh, tp=True,
+                               n_steps=1)
+    np.testing.assert_allclose(tp_losses, ref_losses, rtol=2e-4)
+    w0 = _flat(jax.device_get(params))
+    ref_mu, tp_mu = _first_moment(ref.opt_state), _first_moment(tp.opt_state)
+    ref_w, tp_w = _flat(ref.params), _flat(tp.params)
+    depth = cfg.network.vit_depth
+    checked = {
+        # path: (layer, decayed)
+        "params/features/pos_embed": (0, False),
+        "params/features/patch_embed/kernel": (0, True),
+        "params/features/block0/mlp1/kernel": (1, True),
+        "params/features/block0/norm1/scale": (1, False),
+        "params/features/block1/attn/qkv/kernel": (2, True),
+        "params/features/block1/attn/rel_pos_h": (2, True),
+        "params/head/fc1/kernel": (depth + 1, True),
+        "params/neck/out2_3_ln/bias": (depth + 1, False),
+    }
+    for name, (layer, decayed) in checked.items():
+        g = ref_mu[name]
+        assert (np.linalg.norm(tp_mu[name] - g)
+                < 2e-2 * np.linalg.norm(g)), name
+        big = np.abs(g) > 5e-2 * np.abs(g).max()
+        assert big.sum() >= 8, name
+        step_ref = (w0[name] - ref_w[name])[big]
+        step_tp = (w0[name] - tp_w[name])[big]
+        np.testing.assert_allclose(step_tp, step_ref, rtol=1e-3, err_msg=name)
+        rate = t.layer_decay ** (depth + 1 - layer)
+        want = t.lr * rate * (np.sign(g[big])
+                              + (t.wd * w0[name][big] if decayed else 0.0))
+        np.testing.assert_allclose(step_ref, want, rtol=1e-3, err_msg=name)
 
 
 def _detr_tp_cfg(**overrides):

@@ -118,6 +118,8 @@ def _vit_pp_cfg(pp_stages=2, **overrides):
         "network.vit_window": 4,
         "train.compute_dtype": "f32",
         "network.pp_stages": pp_stages,
+        # the staged backbone runs no stochastic depth (models/vit.py)
+        "network.vit_drop_path": 0.0,
         "train.fpn_rpn_pre_nms_per_level": 64,
         "train.rpn_post_nms_top_n": 64,
         "train.batch_rois": 32,
@@ -289,11 +291,16 @@ def test_sequential_to_staged_rejects_mismatched_layout(rng, seq_vit8):
             sequential_to_staged(params_seq, 4), 4)
     with pytest.raises(ValueError, match="staged-backbone"):
         staged_to_sequential(params_seq)
-    # Hand-built stages_n=8/per=1 staged tree over depth 8: Block shapes
-    # would LOAD cleanly into the sequential model — the converter must
-    # reject on architecture (alternating placement), not shape.
+    # Hand-built stages_n=8/per=1 staged tree over depth 8, every block's
+    # relative-position tables given block 0's (window) shape so the
+    # blocks stack: the converter must reject on architecture
+    # (alternating placement), not shape.
     feats = params_seq["params"]["features"]
-    blocks = [feats[f"block{i}"] for i in range(8)]
+    tables = {k: feats["block0"]["attn"][k] for k in ("rel_pos_h",
+                                                      "rel_pos_w")}
+    blocks = [{**feats[f"block{i}"],
+               "attn": {**feats[f"block{i}"]["attn"], **tables}}
+              for i in range(8)]
     bad = {
         **params_seq,
         "params": {

@@ -190,3 +190,46 @@ def test_ulysses_streaming_under_sp(rng):
     np.testing.assert_allclose(np.asarray(out2),
                                np.asarray(dense_attention(q2, k2, v2)),
                                rtol=2e-5, atol=2e-5)
+
+
+def _rel(rng, b, h, s, grid):
+    """Decomposed bias factors for (b, s, h, d) queries on a grid."""
+    gh, gw = grid
+    assert gh * gw == s
+    return (jnp.asarray(rng.randn(b, h, s, gh), jnp.float32),
+            jnp.asarray(rng.randn(b, h, s, gw), jnp.float32))
+
+
+@pytest.mark.parametrize("form", ["ring", "ulysses", "streaming"])
+def test_decomposed_bias_in_every_form(rng, form):
+    """Each form adds the decomposed bias to the scores of the keys it
+    holds, forward and gradient, as dense attention does: ring and Ulysses
+    over 4 devices (each holds a quarter of the grid's rows of keys), the
+    streaming form over key blocks of 12 (a padded tail). 2e-5 forward
+    and 5e-4 gradient, the module's own tolerances for these forms."""
+    from mx_rcnn_tpu.ops.ring_attention import (streaming_attention,
+                                                ulysses_attention)
+    if form != "streaming" and jax.device_count() < 4:
+        pytest.skip("needs 4 devices")
+    q, k, v = _qkv(rng, b=2, s=32, h=4, d=8)
+    rel = _rel(rng, 2, 4, 32, (4, 8))
+    mesh = create_mesh("4")
+    fn = {"ring": lambda *a, rel: ring_attention(*a, mesh, rel=rel),
+          "ulysses": lambda *a, rel: ulysses_attention(*a, mesh, rel=rel),
+          "streaming": lambda *a, rel: streaming_attention(
+              *a, kv_chunk=12, rel=rel)}[form]
+    want = dense_attention(q, k, v, rel=rel)
+    assert np.abs(np.asarray(want)
+                  - np.asarray(dense_attention(q, k, v))).max() > 1e-2
+    np.testing.assert_allclose(np.asarray(fn(q, k, v, rel=rel)),
+                               np.asarray(want), rtol=2e-5, atol=2e-5)
+
+    def loss(f):
+        return lambda a, b, c, r: jnp.sum(f(a, b, c, rel=r) ** 2)
+
+    g = jax.grad(loss(fn), argnums=(0, 1, 2, 3))(q, k, v, rel)
+    g_dense = jax.grad(loss(dense_attention), argnums=(0, 1, 2, 3))(
+        q, k, v, rel)
+    for got, ref in zip(jax.tree.leaves(g), jax.tree.leaves(g_dense)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   rtol=5e-4, atol=5e-4)

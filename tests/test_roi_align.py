@@ -314,14 +314,14 @@ def test_taps_carry_the_dense_weights_entry_for_entry(name, bins):
         assert (np.asarray(taps.keep)[0, 0] == 0).any()
 
 
-def _pooled(pyr, rois, valid, bins, windows, impl):
+def _pooled(pyr, rois, valid, bins, windows, impl, aligned=False):
     """Pooled values and the gradient to every level's map of a weighed sum,
     float32."""
     from mx_rcnn_tpu.models import fpn as F
 
     def weighed(p):
         out = F.pyramid_roi_align(p, rois, valid, bins, windows=windows,
-                                  impl=impl)
+                                  impl=impl, aligned=aligned)
         wave = jnp.cos(jnp.arange(out.size, dtype=jnp.float32))
         return jnp.sum(out.astype(jnp.float32) * wave.reshape(out.shape)), out
 
@@ -381,6 +381,39 @@ def test_taps_kernel_takes_several_grid_steps_a_plane():
     assert dead.any() and not got[0][dead].any()
     for g, w in zip(got, want):
         _equal_to_a_sum_order(g, w)
+
+
+@pytest.mark.parametrize("name", ["p2", "p3", "p5", "clamped_at_window"])
+def test_aligned_pooling_is_the_same_in_both_forms(name):
+    """ROIAlignV2 (``aligned``: the half-pixel shift, ViTDet's) through the
+    pyramid: the kernel pair (the Pallas interpreter) against
+    ``contract_weights`` on the same canvas, the pooled values and the
+    canvas's VJP equal up to the order of float32 sums; and for a roi inside
+    its map (the gather zeroes a sample a cell beyond the border where the
+    program clamps it), float32, against the per-point gather of the
+    assigned level with ``aligned`` (the CUDA kernel's form) to 1e-5 of the
+    largest value, the shift moving them: the unshifted pooling is off by
+    more than 1e-2."""
+    from mx_rcnn_tpu.models import fpn as F
+
+    rois, windows, valid = _tap_case(name, seed=5)
+    pyr = _tap_pyramid(seed=6)
+    got = _pooled(pyr, rois, valid, 7, windows, "pallas_interpret", True)
+    want = _pooled(pyr, rois, valid, 7, windows, "xla", True)
+    for g, w in zip(got, want):
+        _equal_to_a_sum_order(g, w)
+    if name not in ("p2", "p3"):
+        return
+    f32 = {lv: p.astype(jnp.float32) for lv, p in pyr.items()}
+    dense = np.asarray(F.pyramid_roi_align(f32, rois, valid, 7,
+                                           impl="xla", aligned=True))
+    level = int(np.asarray(F.roi_levels(rois))[0, 0])
+    oracle = np.asarray(_oracle(f32[level], rois, 7, 1.0 / 2 ** level,
+                                aligned=True)).reshape(dense.shape)
+    np.testing.assert_allclose(dense, oracle, rtol=0,
+                               atol=1e-5 * np.abs(oracle).max())
+    plain = np.asarray(F.pyramid_roi_align(f32, rois, valid, 7, impl="xla"))
+    assert np.abs(plain - dense).max() > 1e-2 * np.abs(oracle).max()
 
 
 @pytest.mark.parametrize("bins", [7, 14])

@@ -274,3 +274,271 @@ def test_attn_impl_streaming_superseded_by_sp_warns(caplog):
     with caplog.at_level(logging.WARNING, logger="mx_rcnn_tpu"):
         zoo.build_model(cfg, mesh=mesh)
     assert any("superseded" in r.getMessage() for r in caplog.records)
+
+
+def _explicit_attention(q, k, v, rel_h_table, rel_w_table, gh, gw):
+    """Attention with the decomposed bias built as an explicit N x N matrix
+    from the tables, loop by loop: bias[(ih, iw), (jh, jw)] = q_i .
+    R_h[ih - jh + gh - 1] + q_i . R_w[iw - jw + gw - 1]. numpy, float64."""
+    q, k, v = (np.asarray(t, np.float64) for t in (q, k, v))
+    rh, rw = (np.asarray(t, np.float64) for t in (rel_h_table, rel_w_table))
+    b, n, h, d = q.shape
+    out = np.zeros_like(q)
+    for bi in range(b):
+        for hi in range(h):
+            bias = np.zeros((n, n))
+            for i in range(n):
+                for j in range(n):
+                    ih, iw, jh, jw = i // gw, i % gw, j // gw, j % gw
+                    bias[i, j] = (q[bi, i, hi] @ rh[ih - jh + gh - 1]
+                                  + q[bi, i, hi] @ rw[iw - jw + gw - 1])
+            s = q[bi, :, hi] @ k[bi, :, hi].T / np.sqrt(d) + bias
+            p = np.exp(s - s.max(-1, keepdims=True))
+            p /= p.sum(-1, keepdims=True)
+            out[bi, :, hi] = p @ v[bi, :, hi]
+    return out
+
+
+@pytest.mark.parametrize("grid", [(4, 4), (3, 5)])
+def test_decomposed_bias_is_the_explicit_one(grid, rng):
+    """Dense attention with the decomposed relative-position bias, and the
+    streaming form over key blocks of 4 (grid 3x5: a padded tail), against
+    the explicit N x N bias of the tables: 1e-5 (float32 against
+    float64)."""
+    from mx_rcnn_tpu.models.vit import decomposed_rel_pos
+    from mx_rcnn_tpu.ops.ring_attention import (dense_attention,
+                                                streaming_attention)
+
+    gh, gw = grid
+    q, k, v = (jnp.asarray(rng.randn(2, gh * gw, 2, 8), jnp.float32)
+               for _ in range(3))
+    th = jnp.asarray(rng.randn(2 * gh - 1, 8), jnp.float32)
+    tw = jnp.asarray(rng.randn(2 * gw - 1, 8), jnp.float32)
+    want = _explicit_attention(q, k, v, th, tw, gh, gw)
+    rel = decomposed_rel_pos(q, th, tw, grid)
+    for got in (dense_attention(q, k, v, rel=rel),
+                streaming_attention(q, k, v, kv_chunk=4, rel=rel)):
+        np.testing.assert_allclose(np.asarray(got), want, rtol=0, atol=1e-5)
+    plain = dense_attention(q, k, v)
+    assert np.abs(np.asarray(plain) - want).max() > 1e-2
+
+
+def _vitdet_abstract(network="vitdet_b_mask"):
+    cfg = generate_config(network, "coco")
+    model = zoo.build_model(cfg)
+    return cfg, jax.eval_shape(lambda k: zoo.init_params(model, cfg, k),
+                               jax.random.PRNGKey(0))
+
+
+def _paths(tree):
+    return ["/".join(str(getattr(p, "key", p)) for p in path)
+            for path, _ in jax.tree_util.tree_flatten_with_path(tree)[0]]
+
+
+def test_layer_rates_follow_the_formula():
+    """``train/optimizer.py::scale_by_layer`` at ViT-B's depth, leaf by
+    leaf, against ViTDet's get_vit_lr_decay_rate: patch_embed and pos_embed
+    0.7^13, block i 0.7^(12 - i), the pyramid and the heads 1."""
+    from mx_rcnn_tpu.train.optimizer import scale_by_layer
+
+    cfg, abstract = _vitdet_abstract()
+    assert (cfg.train.layer_decay, cfg.network.vit_depth) == (0.7, 12)
+    ones = jax.tree.map(lambda a: jnp.ones((), jnp.float32), abstract)
+    got, _ = scale_by_layer(0.7, 12).update(ones, None)
+    seen = set()
+    for path, leaf in zip(_paths(abstract), jax.tree.leaves(got)):
+        parts = path.split("/")
+        if parts[1:3] in (["features", "patch_embed"],
+                          ["features", "pos_embed"]):
+            layer = 0
+        elif parts[1] == "features":
+            layer = int(parts[2][len("block"):]) + 1
+        else:
+            layer = 13
+        seen.add(layer)
+        assert float(leaf) == pytest.approx(0.7 ** (13 - layer), rel=1e-6), \
+            path
+    assert seen == set(range(14))
+
+
+def test_the_no_decay_set():
+    """AdamW's weight decay skips exactly the norm layers' leaves (every
+    LayerNorm's scale and bias: 41 norms) and pos_embed; the relative-
+    position tables, every kernel and every other bias decay."""
+    from mx_rcnn_tpu.train.optimizer import decay_mask
+
+    cfg, abstract = _vitdet_abstract()
+    assert cfg.train.no_decay == ("norm", "pos_embed")
+    mask = dict(zip(_paths(abstract),
+                    jax.tree.leaves(decay_mask(abstract, cfg.train.no_decay))))
+    skipped = sorted(p for p, m in mask.items() if not m)
+    norms = {p.rsplit("/", 1)[0] for p in mask if p.endswith("/scale")}
+    assert len(norms) == 41
+    assert skipped == sorted([f"{n}/{leaf}" for n in norms
+                              for leaf in ("scale", "bias")]
+                             + ["params/features/pos_embed"])
+    assert mask["params/features/block2/attn/rel_pos_h"]
+    assert mask["params/rpn/rpn_conv2/bias"]
+
+
+@pytest.mark.parametrize("network", ["resnet101_fpn", "resnet101_fpn_mask"])
+def test_the_pyramid_presets_keep_their_heads_and_update(network, rng):
+    """The FPN and Mask presets read none of ViTDet's head options: the
+    parameter tree is the two-FC head's, the one-conv RPN head's and the
+    unnormed mask head's, the pooling unshifted; and two steps of their
+    optimizer equal, bit for bit, the chain they always built (elementwise
+    clip, coupled decay, SGD momentum, frozen leaves zeroed)."""
+    import optax
+
+    from mx_rcnn_tpu.train.optimizer import (build_optimizer,
+                                             effective_fixed_patterns,
+                                             lr_schedule, trainable_mask)
+
+    cfg = generate_config(network, "coco", **{
+        "network.depth": 50, "image.pad_shape": (128, 128)})
+    model = zoo.build_model(cfg)
+    assert not model.roi_aligned
+    abstract = jax.eval_shape(lambda k: zoo.init_params(model, cfg, k),
+                              jax.random.PRNGKey(0))
+    paths = _paths(abstract)
+    assert "params/head/fc7/kernel" in paths
+    assert not [p for p in paths if "rpn_conv2" in p or "_norm/" in p
+                or p.endswith("/scale")]
+    if cfg.network.use_mask:
+        assert "params/mask_head/mask_conv0/bias" in paths
+    params = jax.tree.map(
+        lambda a: jnp.asarray(rng.randn(*a.shape) * 0.1, jnp.float32),
+        abstract)
+    grads = [jax.tree.map(
+        lambda a: jnp.asarray(rng.randn(*a.shape) * 7.0, jnp.float32),
+        abstract) for _ in range(2)]
+    labels = jax.tree.map(lambda t: "train" if t else "frozen",
+                          trainable_mask(params,
+                                         effective_fixed_patterns(cfg)))
+    old = optax.multi_transform({
+        "train": optax.chain(
+            optax.clip(cfg.train.clip_gradient),
+            optax.add_decayed_weights(cfg.train.wd),
+            optax.sgd(learning_rate=lr_schedule(cfg, 1000),
+                      momentum=cfg.train.momentum)),
+        "frozen": optax.set_to_zero()}, labels)
+    new = build_optimizer(cfg, params)
+    s_old, s_new = old.init(params), new.init(params)
+    for g in grads:
+        u_old, s_old = jax.jit(old.update)(g, s_old, params)
+        u_new, s_new = jax.jit(new.update)(g, s_new, params)
+        for a, b in zip(jax.tree.leaves(u_old), jax.tree.leaves(u_new)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_the_vitdet_presets_are_the_recipe():
+    """``vitdet_b`` and ``vitdet_b_mask``: window 14 with the global blocks
+    2, 5, 8, 11, LN heads, a two-conv RPN head, 4conv1fc, exact top-k, the
+    1024x1024 canvas with MAE's normalisation, AdamW at lr 1e-4 (the
+    recipe's 64 images), weight decay 0.1, layer decay 0.7, drop path 0.1,
+    no clip; no final norm after the last block; ROIAlignV2."""
+    from mx_rcnn_tpu.models.vit import _global_block_indices
+
+    for name in ("vitdet_b", "vitdet_b_mask"):
+        cfg, abstract = _vitdet_abstract(name)
+        n = cfg.network
+        assert (n.vit_window, n.norm, n.rpn_convs, n.box_head_convs,
+                n.proposal_topk, n.vit_drop_path) == (
+                    14, "layer", 2, 4, "exact", 0.1)
+        assert _global_block_indices(n.vit_depth) == {2, 5, 8, 11}
+        assert cfg.image.pad_shape == (1024, 1024)
+        assert cfg.image.pixel_stds == (58.395, 57.12, 57.375)
+        t = cfg.train
+        assert (t.optimizer, t.lr, t.wd, t.layer_decay) == (
+            "adamw", 1e-4, 0.1, 0.7)
+        assert t.clip_gradient == float("inf")
+        paths = _paths(abstract)
+        assert not [p for p in paths if p.startswith("params/features/norm")]
+        assert "params/features/block2/attn/rel_pos_h" in paths
+        assert zoo.build_model(cfg).roi_aligned
+
+
+@pytest.mark.parametrize("size", [1, 4, 14])
+def test_rel_pos_table_reads_each_offsets_row(size, rng):
+    """Entry (i, j) of ``rel_pos_table`` is the table's row for the offset
+    i - j, row i - j + size - 1 (Detectron2's ``get_rel_pos``)."""
+    from mx_rcnn_tpu.models.vit import rel_pos_table
+
+    table = jnp.asarray(rng.randn(2 * size - 1, 5), jnp.float32)
+    got = np.asarray(rel_pos_table(table, size))
+    assert got.shape == (size, size, 5)
+    for i in range(size):
+        for j in range(size):
+            np.testing.assert_array_equal(got[i, j], table[i - j + size - 1])
+
+
+def test_rel_pos_table_resizes_a_table_of_another_length(rng):
+    """A table made for 8 positions read at 4 is resampled to 7 rows first
+    (what the init pass at a smaller grid meets); one of the right length
+    is taken as it is."""
+    from mx_rcnn_tpu.models.vit import rel_pos_table
+
+    table = jnp.asarray(rng.randn(15, 3), jnp.float32)
+    assert rel_pos_table(table, 4).shape == (4, 4, 3)
+    np.testing.assert_array_equal(np.asarray(rel_pos_table(table, 8))[7, 7],
+                                  table[7])
+
+
+@pytest.mark.parametrize("rate", [0.0, 0.1, 0.5])
+def test_drop_path_keep_factors(rate):
+    """The factors of ``drop_path_keep``: block 0 never drops (its rate is
+    0), every factor is 0 or 1/(1 - its block's rate), and at rate 0 all
+    are 1."""
+    from mx_rcnn_tpu.models.vit import drop_path_keep
+
+    keep = np.asarray(drop_path_keep(jax.random.PRNGKey(5), 64, 12, rate))
+    assert keep.shape == (12, 2, 64)
+    assert (keep[0] == 1.0).all()
+    scale = 1.0 / (1.0 - np.linspace(0.0, rate, 12))[:, None, None]
+    assert np.all((keep == 0.0) | np.isclose(keep, scale))
+    assert (keep == 0.0).any() == (rate > 0.0)
+
+
+@pytest.mark.parametrize("path,layer", [
+    ("params/features/patch_embed/kernel", 0),
+    ("params/features/pos_embed", 0),
+    ("params/features/block0/attn/qkv/kernel", 1),
+    ("params/features/block11/mlp2/bias", 12),
+    ("params/neck/out2_1/kernel", 13),
+    ("params/rpn/rpn_conv2/kernel", 13),
+    ("params/mask_head/mask_logits/bias", 13)])
+def test_vit_layer_of_each_kind_of_leaf(path, layer):
+    """``get_vit_lr_decay_rate``'s layer ids at ViT-B's depth: the patch
+    embedding and positions 0, block i at i + 1, the rest 13."""
+    from mx_rcnn_tpu.train.optimizer import vit_layer
+
+    assert vit_layer(path.split("/"), 12) == layer
+
+
+def test_decay_mask_on_a_small_tree():
+    """``decay_mask`` by module: a LayerNorm's and a GroupNorm's leaves
+    (``scale`` beside ``bias``) skip the decay under "norm", a named leaf
+    skips it by name, a dense layer's bias and kernel decay."""
+    from mx_rcnn_tpu.train.optimizer import decay_mask
+
+    tree = {"params": {"ln": {"scale": 1.0, "bias": 0.0},
+                       "gn": {"scale": 1.0, "bias": 0.0},
+                       "fc": {"kernel": 1.0, "bias": 0.0},
+                       "pos_embed": 0.0}}
+    got = decay_mask(tree, ("norm", "pos_embed"))["params"]
+    assert not any([got["ln"]["scale"], got["ln"]["bias"],
+                    got["gn"]["scale"], got["gn"]["bias"], got["pos_embed"]])
+    assert got["fc"]["kernel"] and got["fc"]["bias"]
+    assert all(jax.tree.leaves(decay_mask(tree, ())))
+
+
+def test_scale_by_layer_at_decay_one_is_the_identity(rng):
+    """At decay 1.0 every layer's factor is 1: the updates pass through."""
+    from mx_rcnn_tpu.train.optimizer import scale_by_layer
+
+    u = {"params": {"features": {"block3": {"mlp1": {"kernel": jnp.asarray(
+        rng.randn(4, 4), jnp.float32)}}}, "head": {"fc1": {"bias": jnp.ones(
+            (3,))}}}}
+    got, _ = scale_by_layer(1.0, 12).update(u, None)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(u)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
